@@ -17,11 +17,14 @@ serialized per-handler `ollama.generate` (`FastAPI/app.py:85-90`).
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import json
 import sys
 
 from ..history import SQLiteHistory
 from ..serve import EngineBackend, FakeBackend, GenerationService
 from ..sql import default_backend
+from ..utils.jaxenv import force_cpu, place_compile_cache
 from .api import create_api_app
 from .config import AppConfig
 from .web import create_web_app
@@ -199,7 +202,21 @@ def make_oracle_service() -> GenerationService:
     return svc
 
 
-def make_checkpoint_service(args, max_new_tokens: int) -> GenerationService:
+def load_checkpoint_file(path: str, mesh, **quantize):
+    """The default weight source: an HF directory or a GGUF blob."""
+    from ..checkpoint import (
+        load_and_quantize,
+        load_gguf_checkpoint,
+        load_hf_checkpoint,
+    )
+
+    raw = load_gguf_checkpoint if path.endswith(".gguf") else load_hf_checkpoint
+    return load_and_quantize(lambda m: raw(path, mesh=m), mesh, **quantize)
+
+
+def make_checkpoint_service(args, max_new_tokens: int,
+                            load_weights=load_checkpoint_file,
+                            ) -> GenerationService:
     """Real deployment: load duckdb-nsql (NL→SQL) and llama3.2 (error
     analysis) from HF directories or GGUF blobs onto one mesh.
 
@@ -207,7 +224,13 @@ def make_checkpoint_service(args, max_new_tokens: int) -> GenerationService:
     continuous-batching scheduler: concurrent HTTP requests share one decode
     batch on the device instead of serializing on a per-backend lock — the
     capability gap vs the reference's one-`ollama.generate`-at-a-time
-    handlers (reference `FastAPI/app.py:85-90`)."""
+    handlers (reference `FastAPI/app.py:85-90`).
+
+    `load_weights(path, mesh, quantize_int8=, quantize_int4=,
+    quantize_unembed8=) -> (cfg, params)` is where the weights named by a
+    `--*-model-path` come from. `chip_smoke.py` passes one that builds
+    seeded weights at a `REGISTRY` shape, so the machine without a
+    checkpoint still runs this assembly and no other."""
     from ..parallel import make_mesh
     from ..serve import EngineBackend
     from ..serve.scheduler import SchedulerBackend
@@ -303,12 +326,21 @@ def make_checkpoint_service(args, max_new_tokens: int) -> GenerationService:
             sys.exit(f"{path}: GGUF blobs carry no tokenizer.json — pass "
                      "PATH.gguf:TOKDIR")
         tok = HFTokenizer(tok_dir or path)
+
+        def load(m, **quantize):
+            cfg, params = load_weights(path, m, **quantize)
+            if args.max_seq:
+                cfg = dataclasses.replace(
+                    cfg, max_seq_len=min(cfg.max_seq_len, args.max_seq))
+            return cfg, params
+
         if args.scheduler:
             supervise = getattr(args, "supervise", True)
             if len(scheduler_meshes) == 1:
                 common = dict(mesh=scheduler_meshes[0],
                               max_new_tokens=max_new_tokens,
                               add_bos=add_bos, num_slots=args.slots,
+                              prompt_bucket=args.prompt_bucket,
                               kv_quant=kv_quant,
                               max_queue_depth=app_cfg.max_queue_depth,
                               deadline_s=app_cfg.deadline_s or None,
@@ -329,20 +361,19 @@ def make_checkpoint_service(args, max_new_tokens: int) -> GenerationService:
                 common["kv_spill"] = app_cfg.kv_spill
                 common["kv_watermark_low"] = app_cfg.kv_watermark_low
                 common["kv_watermark_high"] = app_cfg.kv_watermark_high
-                common["quantize_int8"] = args.int8
-                common["quantize_int4"] = int4
-                common["quantize_unembed8"] = getattr(args, "int8_unembed",
-                                                      False)
-                if path.endswith(".gguf"):
-                    return SchedulerBackend.from_gguf(path, tok, **common)
-                return SchedulerBackend.from_hf_checkpoint(
-                    path, tok, **common
-                )
-            # dp replicas: load the checkpoint ONCE host-side (and quantize
-            # host-side, so only the int8 tree ever ships — the same order
-            # SchedulerBackend.from_hf_checkpoint uses), then place per
-            # submesh. One disk read for any dp.
-            from ..checkpoint import load_gguf_checkpoint, load_hf_checkpoint
+                return SchedulerBackend.from_loader(
+                    lambda m: load(
+                        m, quantize_int8=args.int8, quantize_int4=int4,
+                        quantize_unembed8=getattr(args, "int8_unembed",
+                                                  False)),
+                    tok, name=src, **common)
+            # dp replicas: load the checkpoint ONCE (quantized before it
+            # ships, like the single-mesh path), park the tree on the HOST,
+            # then place per submesh — an unplaced jax tree sits on device
+            # 0, which would then hold a second copy beside its own
+            # replica's. One disk read for any dp.
+            import jax
+
             from ..serve.backends import resolve_stop_ids
             from ..serve.scheduler import (
                 ContinuousBatchingScheduler,
@@ -367,14 +398,8 @@ def make_checkpoint_service(args, max_new_tokens: int) -> GenerationService:
                          "needs --kv-layout=paged (the prefill→decode "
                          "handoff ships KV pool pages)")
 
-            if path.endswith(".gguf"):
-                cfg, params = load_gguf_checkpoint(path, mesh=None)
-            else:
-                cfg, params = load_hf_checkpoint(path, mesh=None)
-            if args.int8:
-                from ..ops.quant import quantize_params
-
-                params = quantize_params(params)
+            cfg, params = load(None, quantize_int8=args.int8)
+            params = jax.device_get(params)
             # Remote replicas (ISSUE 15, LSOT_POOL_REMOTE
             # "1=host:port"): those pool slots become SocketTransports
             # to `python -m …serve.remote` workers — the per-replica
@@ -404,8 +429,9 @@ def make_checkpoint_service(args, max_new_tokens: int) -> GenerationService:
                     from ..serve.remote import SocketTransport
 
                     return SocketTransport(remote_map[i], label=f"r{i}")
-                return ContinuousBatchingScheduler(
+                sched = ContinuousBatchingScheduler(
                     cfg, params, num_slots=args.slots,
+                    prompt_bucket=args.prompt_bucket,
                     stop_ids=resolve_stop_ids(cfg, tok),
                     mesh=scheduler_meshes[i],
                     kv_quant=kv_quant,
@@ -422,6 +448,8 @@ def make_checkpoint_service(args, max_new_tokens: int) -> GenerationService:
                     max_queue_depth=app_cfg.max_queue_depth,
                     phase_role=phase_roles[i],
                 )
+                sched.warmup()  # before its loop starts: warmup()'s note
+                return sched
 
             from ..serve.scheduler import parse_replica_weights
 
@@ -499,6 +527,8 @@ def make_checkpoint_service(args, max_new_tokens: int) -> GenerationService:
                     drain_deadline_s=app_cfg.drain_deadline_s,
                 ).run()
             return backend
+        if load_weights is not load_checkpoint_file:
+            sys.exit("--no-scheduler reads checkpoint files only")
         # Deadline-clamp s/token seed (ROADMAP PR-3 follow-up): an
         # explicit LSOT_STOK_SEED wins; otherwise the last bench
         # artifact's headline converts to a per-step wall. Unseeded, the
@@ -647,7 +677,7 @@ def _make_multimodel_checkpoint_service(args, specs, max_new_tokens,
     return svc
 
 
-def main(argv=None) -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(prog="llm_based_apache_spark_optimization_tpu.app")
     ap.add_argument("--api", action="store_true", help="headless JSON API instead of the web UI")
     ap.add_argument("--backend", choices=("tiny", "fake", "checkpoint"),
@@ -711,6 +741,18 @@ def main(argv=None) -> None:
                          "--no-scheduler restores lock-serialized engines)")
     ap.add_argument("--slots", type=int, default=8,
                     help="scheduler sequence slots (concurrent decode lanes)")
+    ap.add_argument("--max-seq", type=int, default=0, metavar="TOKENS",
+                    help="serve a context window of at most TOKENS (0 = "
+                         "the model's own). Prefill works on whole-window "
+                         "row views, so the window — with --slots — sets "
+                         "how much HBM a 7B model leaves the KV pool on "
+                         "one chip")
+    ap.add_argument("--prompt-bucket", type=int, default=128,
+                    metavar="TOKENS",
+                    help="largest prefill chunk; the scheduler compiles "
+                         "one program per power-of-two bucket up to it "
+                         "and per admission-group size, all before it "
+                         "serves")
     ap.add_argument("--supervise", action=argparse.BooleanOptionalAction,
                     default=True,
                     help="crash supervision for scheduler backends (default "
@@ -724,18 +766,15 @@ def main(argv=None) -> None:
     ap.add_argument("--port", type=int, default=None)
     ap.add_argument("--cpu", action="store_true",
                     help="force the CPU jax platform (hermetic demo)")
-    args = ap.parse_args(argv)
+    return ap
 
-    if args.cpu:
-        import jax
 
-        jax.config.update("jax_platforms", "cpu")
-
-    cfg = AppConfig.from_env()
-    if args.host:
-        cfg = type(cfg)(**{**cfg.__dict__, "host": args.host})
-    if args.port:
-        cfg = type(cfg)(**{**cfg.__dict__, "port": args.port})
+def build_app(args, cfg: AppConfig, load_weights=load_checkpoint_file):
+    """The whole assembly behind `main`: configuration seams, the
+    generation service for `args.backend`, history, and the WSGI app.
+    Returns `(app, service)`. `chip_smoke.py` builds its server through
+    this function too (with seeded `load_weights`), so what it proves on
+    the chip is this assembly."""
     cfg.ensure_dirs()
     # Observability wiring (README "Observability"): trace sampling +
     # export, the flight-recorder ring size, and request-log sampling all
@@ -784,8 +823,9 @@ def main(argv=None) -> None:
 
     if args.backend == "checkpoint":
         if not args.sql_model_path:
-            ap.error("--backend checkpoint requires --sql-model-path")
-        service = make_checkpoint_service(args, args.max_new_tokens)
+            sys.exit("--backend checkpoint requires --sql-model-path")
+        service = make_checkpoint_service(args, args.max_new_tokens,
+                                          load_weights)
     elif cfg.models and args.backend == "tiny":
         # Multi-model tiny fleet (ISSUE 16, LSOT_MODELS with tiny
         # sources): co-resident random-weight checkpoints in one
@@ -820,6 +860,29 @@ def main(argv=None) -> None:
     # Pass the backend factory, not an instance: each request gets an
     # isolated SQL session (own connection + temp_view).
     app = factory(service, default_backend, history, cfg)
+    # Once, at start: what every automatic kernel choice resolved to, per
+    # model — an interpreted kernel or an einsum fallback is then on the
+    # record instead of passing for the device path.
+    for model, stats in service.backend_stats().items():
+        perf = stats.get("perf") or {}
+        for ledger in perf.get("replicas", [perf]):
+            if ledger.get("kernels"):
+                print(f"kernels {model}/{ledger.get('replica')}: "
+                      f"{json.dumps(ledger['kernels'])}", file=sys.stderr)
+    return app, service
+
+
+def main(argv=None) -> None:
+    args = build_parser().parse_args(argv)
+    if args.cpu:
+        force_cpu()
+    place_compile_cache()
+    cfg = AppConfig.from_env()
+    if args.host:
+        cfg = dataclasses.replace(cfg, host=args.host)
+    if args.port:
+        cfg = dataclasses.replace(cfg, port=args.port)
+    app, service = build_app(args, cfg)
     kind = "JSON API" if args.api else "web UI"
     print(f"serving {kind} on http://{cfg.host}:{cfg.port} "
           f"(backend={args.backend})", file=sys.stderr)

@@ -3,11 +3,11 @@
 The reference paper's whole pitch is NL → SQL → *execute on Spark* → on
 error, *diagnose and retry* — this module is that loop as a first-class
 serving workload. A failed execution is classified into a typed SQL-error
-taxonomy, then fed back — error text + original question + schema —
+classification, then fed back — error text + original question + schema —
 through the SAME grammar-constrained decoder that produced it (optionally
 a tenant-pinned repair model), re-executed, and bounded:
 
-- **Taxonomy** (`classify_sql_error`): syntax / schema
+- **Classification** (`classify_sql_error`): syntax / schema
   (unknown-column-or-table) / type (type-mismatch) / resource /
   transient. Classification drives policy: resource errors are not
   fixable by rewriting SQL (degrade immediately); everything else earns
@@ -61,7 +61,7 @@ __all__ = [
     "build_repair_prompt",
 ]
 
-#: The typed SQL-error taxonomy (ISSUE 20). Fixed vocabulary — every
+#: The typed SQL-error classification (ISSUE 20). Fixed vocabulary — every
 #: per-class counter/label is bounded by these five values.
 REPAIR_CLASSES = ("syntax", "schema", "type", "resource", "transient")
 
@@ -97,7 +97,7 @@ _CLASS_PATTERNS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
 
 
 def classify_sql_error(e: BaseException) -> str:
-    """Classify an execution failure into the repair taxonomy.
+    """Classify an execution failure into the repair classification.
 
     Injected per-class sites (utils/faults.SQL_FAULT_ERRORS) classify by
     their site name — the deterministic chaos anchor; infra-shaped

@@ -112,14 +112,15 @@ def main(argv=None) -> None:
         ))
         return
 
+    from ..utils.jaxenv import force_cpu, place_compile_cache
+
     if args.virtual_devices:
         from .report import force_virtual_devices
 
         force_virtual_devices(args.virtual_devices)
     elif args.cpu:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
+        force_cpu()
+    place_compile_cache()
 
     from ..app.__main__ import (
         make_fake_service,

@@ -1690,7 +1690,7 @@ def _run_repair_stage(seed: int) -> Dict:
        on EVERY execute (p=1, the unrepairable worst case): every
        request must terminate TYPED (diagnosed error + explain fallback,
        never a hang or an escape) within LSOT_REPAIR_MAX_ROUNDS rounds,
-       with the right taxonomy class counted.
+       with the right error class counted.
     C. **LSOT_REPAIR=0 off-switch** — the same broken-SQL traffic with
        repair disabled must reproduce the pre-repair failure path bit
        for bit: the raw engine error + explainer answer, exactly one SQL
@@ -1820,7 +1820,7 @@ def _run_repair_stage(seed: int) -> Dict:
                 f"LSOT_REPAIR_MAX_ROUNDS=2"
             )
             assert d.get(f"diagnosed_{cls_name}", 0) >= 1, (
-                f"{site}: taxonomy counted {d} — no diagnosed_{cls_name}"
+                f"{site}: classification counted {d} — no diagnosed_{cls_name}"
             )
             per_class[cls_name] = {
                 "terminal_typed": terminal_typed,

@@ -17,7 +17,7 @@ Two suites:
 - **injected** — every case's FIRST execution raises a representative
   engine error from one of the per-class fault sites
   (`utils/faults.SQL_FAULT_ERRORS`, cycling syntax/schema/transient), so
-  every taxonomy branch is exercised deterministically and k=0 is 0% by
+  every error-class branch is exercised deterministically and k=0 is 0% by
   construction — the suite where k=2 strictly exceeding one-shot is an
   acceptance gate, not a hope.
 """
@@ -34,7 +34,7 @@ from ..utils.faults import SQL_FAULT_ERRORS
 from .spider import SPIDER_SMOKE, SpiderCase
 
 #: Injected-suite fault rotation: one representative engine error per
-#: repairable taxonomy branch (type-mismatch has no injection site —
+#: repairable error-class branch (type-mismatch has no injection site —
 #: sqlite coerces rather than erroring, so its branch is exercised by
 #: classifier tests instead).
 INJECT_CYCLE = ("sql:syntax", "sql:schema", "sql:transient")

@@ -539,7 +539,7 @@ def forward(
                             new_cache["kp"], k, positions, ptab, l, q_lens)
                         new_cache["vp"] = paged_write_reference(
                             new_cache["vp"], v, positions, ptab, l, q_lens)
-                if impl == "pallas":  # ragged windows (T·G bound validated
+                if impl == "pallas":  # ragged windows (T·N bound validated
                                       # in the kernel wrapper)
                     if quant_paged:
                         from ..ops.pallas import (

@@ -26,6 +26,7 @@ _SOURCES = ("bpe.cpp", "gguf.cpp", "csvscan.cpp")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
+_built_here = False
 
 
 def _build() -> bool:
@@ -39,10 +40,18 @@ def _build() -> bool:
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, _LIB_PATH)
+        global _built_here
+        _built_here = True
         return True
     except (subprocess.SubprocessError, FileNotFoundError, OSError):
         tmp.unlink(missing_ok=True)
         return False
+
+
+def built_in_this_process() -> bool:
+    """Whether `load_native` had to compile the library (a fresh checkout:
+    `lib/` is git-ignored) rather than find one built earlier."""
+    return _built_here
 
 
 def load_native() -> Optional[ctypes.CDLL]:

@@ -1,45 +1,6 @@
-"""Shared numerical constants and small compat shims for ops kernels."""
+"""Shared numerical constants for ops kernels."""
 
 # Large-negative instead of -inf for masking: keeps softmax NaN-free on
 # fully-masked rows and is safely representable in f32. Shared by attention
 # masking and sampler logit masking so the semantics can't diverge.
 NEG_INF = -1e30
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """`jax.shard_map` across the rename/move: newer jax exposes it at the
-    top level with a `check_vma` flag; on this build it still lives at
-    `jax.experimental.shard_map.shard_map` with the older `check_rep`
-    spelling of the same replication-checker switch (the same compat-alias
-    recipe as pltpu.CompilerParams in pallas/attention.py). One shim so
-    every sharded wrapper (ring attention, flash kernels, int4 matmul)
-    runs on both."""
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a named mesh axis from inside a shard_map body:
-    `jax.lax.axis_size` on jax builds that have it, else the older
-    `jax.core.axis_frame` (which returns the size directly on this
-    build). Static-int either way — ring attention builds its ppermute
-    schedule from it at trace time."""
-    import jax
-
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    from jax.core import axis_frame
-
-    frame = axis_frame(axis_name)
-    return int(getattr(frame, "size", frame))

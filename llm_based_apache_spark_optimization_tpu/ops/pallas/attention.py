@@ -55,16 +55,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common import NEG_INF, shard_map as _shard_map
+from ..common import NEG_INF
+from .dispatch import resolve_interpret
 
 _LANES = 128  # VMEM lane width: scratch row-stats are kept lane-broadcast
-
-# jax renamed pltpu.TPUCompilerParams -> CompilerParams; accept both so the
-# kernels (and their interpret-mode CPU tests) run on either side of the
-# rename.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
 
 
 def _flash_block_update(
@@ -319,7 +313,7 @@ def _run_decode_grid(kernel, q, streams, q_positions, kv_lens,
         # Batch cells are independent -> megacore can split them; the S
         # axis carries the online-softmax accumulators and must run in
         # order on one core.
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -357,8 +351,7 @@ def flash_gqa_attention(
     g = n // kh
     gt = g * t
 
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+    interpret = resolve_interpret(interpret)
     if not interpret and s % 8:
         raise ValueError(
             f"flash kernel needs sublane-aligned S (multiple of 8) on TPU, "
@@ -436,7 +429,7 @@ def flash_gqa_attention(
         # them; the q-block axis reuses the scratch accumulators (marked
         # arbitrary so one core sweeps a q-block's S-blocks in order), and
         # the S axis carries the online-softmax state.
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary"),
         ),
@@ -475,8 +468,7 @@ def flash_gqa_attention_quantized(
         raise ValueError(f"quantized flash kernel is decode-only (T=1), got T={t}")
     kh, s = k8.shape[1], k8.shape[2]
 
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+    interpret = resolve_interpret(interpret)
     if not interpret and s % 8:
         raise ValueError(
             f"flash kernel needs sublane-aligned S (multiple of 8) on TPU, "
@@ -517,7 +509,7 @@ def sharded_flash_gqa_attention_quantized(
     )
     if kv_lens is None:
         kv_lens = jnp.max(q_positions.astype(jnp.int32), axis=1) + 1
-    return _shard_map(
+    return jax.shard_map(
         lambda q_, k_, ks_, v_, vs_, p_, l_: body(
             q_, k_, ks_, v_, vs_, p_, kv_lens=l_
         ),
@@ -567,7 +559,7 @@ def sharded_flash_gqa_attention(
     )
     if kv_lens is None:
         kv_lens = jnp.max(q_positions.astype(jnp.int32), axis=1) + 1
-    return _shard_map(
+    return jax.shard_map(
         lambda q_, k_, v_, p_, l_: body(q_, k_, v_, p_, kv_lens=l_),
         mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, P("dp", None), P("dp")),

@@ -2,7 +2,7 @@
 
 Modes:
 - "xla"    — always the einsum reference path (`ops.attention.gqa_attention`).
-- "pallas" — always the flash kernel (interpreted off-TPU).
+- "pallas" — always the flash kernel (interpreted off-TPU: the CPU tests).
 - "auto"   — (default) flash kernel on TPU, einsum otherwise. Under a mesh
   the kernel runs per-device through the `shard_map` wrapper
   (`ops.pallas.attention.sharded_flash_gqa_attention`) over the tp-sharded
@@ -44,12 +44,26 @@ def _resolve_mode() -> str:
     return mode
 
 
+def on_tpu() -> bool:
+    """The one platform test behind every automatic choice in this
+    package: kernel or einsum, compiled or interpreted."""
+    return jax.devices()[0].platform == "tpu"
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """`interpret=None` on a kernel wrapper: compiled by Mosaic on a TPU,
+    the Pallas interpreter anywhere else (the CPU tests). Servers print
+    the outcome once at start (the scheduler's `kernel_modes`), so an
+    interpreted kernel can never pass for a device run."""
+    return not on_tpu() if interpret is None else interpret
+
+
 def attention_impl(mesh=None) -> str:
     """Resolve to 'xla' or 'pallas' for the current trace."""
     mode = _resolve_mode()
     if mode != "auto":
         return mode
-    return "pallas" if jax.devices()[0].platform == "tpu" else "xla"
+    return "pallas" if on_tpu() else "xla"
 
 
 # Auto-mode decode crossover: the flash kernel pays ~0.05 ms/layer of cell
@@ -77,7 +91,7 @@ def decode_attention_impl(mesh=None, cache_bytes_per_device=None) -> str:
     mode = _resolve_mode()
     if mode != "auto":
         return mode
-    if jax.devices()[0].platform != "tpu":
+    if not on_tpu():
         return "xla"
     if (cache_bytes_per_device or 0) >= _PALLAS_DECODE_MIN_CACHE_BYTES:
         return "pallas"

@@ -45,8 +45,8 @@ row's pages into a contiguous view, run the einsum attention) with the
 kernel's exact ragged contract (`q_lens` columns past a row's window
 return zeros): the golden in parity tests and the CPU/interpret fallback
 in `models/llama.forward`. The kernel serves any window with
-T·G <= `_MAX_QROWS` folded rows (the folded query block must stay
-VMEM-resident); larger windows take the reference.
+T·N <= `_MAX_QROWS` folded rows over all heads (the folded query block
+must stay VMEM-resident); larger windows take the reference.
 """
 
 from __future__ import annotations
@@ -59,13 +59,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common import NEG_INF, shard_map as _shard_map
-from .attention import _CompilerParams, _flash_block_update, _LANES
+from ..common import NEG_INF
+from .attention import _flash_block_update, _LANES
+from .dispatch import resolve_interpret
 
-# Upper bound on folded query rows (T·G) the kernel serves: the whole
-# folded query block plus its f32 accumulators must stay VMEM-resident
-# across the page sweep. Windows above it take the XLA reference.
-_MAX_QROWS = 512
+# Upper bound on folded query rows the kernel serves, counted over ALL
+# heads (T·N = K·G·T): the KV-head axis is folded into the cell, so the
+# whole [K, G·T, H] query block plus its f32 accumulators stays
+# VMEM-resident across the page sweep. Set by the compiler, not by
+# argument: Mosaic for v5e accepts every variant (GQA 32/8 and MHA 32/32,
+# H 64/128, pages 16/64, bf16/int8 pools) at 2048 rows and runs out of
+# VMEM at 4096 (tests/test_chip_compile.py holds the bound). The
+# scheduler's largest window, T=32 at N=32, is 1024. Windows above the
+# bound take the XLA reference.
+_MAX_QROWS = 2048
 
 
 def _make_paged_decode_kernel(dequant):
@@ -231,7 +238,7 @@ def _run_paged_grid(kernel, q, streams, page_table, q_positions,
         out_shape=jax.ShapeDtypeStruct((b, kh, gt, h), q.dtype),
         # Batch rows are independent (megacore splits them); the page axis
         # carries the online-softmax accumulators in order on one core.
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -241,23 +248,21 @@ def _run_paged_grid(kernel, q, streams, page_table, q_positions,
     )
 
 
-def _validate_window(q, kh, page_size, interpret, *, quantized=False):
+def _validate_window(q, page_size, interpret, *, quantized=False):
     """One guard for both kernel variants (bf16 and int8): reject query
-    windows whose folded row count T·G exceeds `_MAX_QROWS` with ONE
+    windows whose folded row count T·N exceeds `_MAX_QROWS` with ONE
     consistent message naming the always-correct fallback, and resolve +
     check the TPU sublane-alignment requirement. Returns the resolved
     `interpret` flag."""
     b, t, n, h = q.shape
-    g = n // max(kh, 1)
     suffix = "_quantized" if quantized else ""
-    if t < 1 or t * g > _MAX_QROWS:
+    if t < 1 or t * n > _MAX_QROWS:
         raise ValueError(
             f"ragged_paged_attention{suffix} serves query windows with "
-            f"1 <= T*G <= {_MAX_QROWS} folded rows, got T={t} (G={g}); "
+            f"1 <= T*N <= {_MAX_QROWS} folded rows, got T={t} (N={n}); "
             f"larger windows take paged_attention_reference{suffix}"
         )
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+    interpret = resolve_interpret(interpret)
     if not interpret and page_size % 8:
         raise ValueError(
             f"pool pages must be sublane-aligned (page size multiple of 8) "
@@ -289,8 +294,7 @@ def ragged_paged_attention(
     first `q_lens[b]` window columns (defaults to T; columns past a row's
     q_len return exact zeros). One launch therefore serves T=1 decode
     rows, speculative verify windows, and prefill chunks together."""
-    kh = k_pool.shape[1]
-    interpret = _validate_window(q, kh, k_pool.shape[2], interpret)
+    interpret = _validate_window(q, k_pool.shape[2], interpret)
     h = q.shape[3]
     return _run_paged_grid(
         _paged_decode_kernel, q, [(k_pool, (h,)), (v_pool, (h,))],
@@ -321,9 +325,8 @@ def ragged_paged_attention_quantized(
     VMEM tiles inside the kernel — int8 streaming and per-row ragged
     bounding stacked, the paged twin of
     `attention.flash_gqa_attention_quantized`."""
-    kh = k_pool.shape[1]
     interpret = _validate_window(
-        q, kh, k_pool.shape[2], interpret, quantized=True
+        q, k_pool.shape[2], interpret, quantized=True
     )
     h = q.shape[3]
     ks4 = k_scale.astype(jnp.float32)[..., None]  # [P, K, PS, 1]
@@ -362,7 +365,7 @@ def sharded_ragged_paged_attention(
         kv_lens = jnp.max(q_positions.astype(jnp.int32), axis=1) + 1
     if q_lens is None:
         q_lens = jnp.full((q.shape[0],), q.shape[1], jnp.int32)
-    return _shard_map(
+    return jax.shard_map(
         lambda q_, k_, v_, t_, p_, l_, w_: body(
             q_, k_, v_, t_, p_, kv_lens=l_, q_lens=w_
         ),
@@ -396,7 +399,7 @@ def sharded_ragged_paged_attention_quantized(
         kv_lens = jnp.max(q_positions.astype(jnp.int32), axis=1) + 1
     if q_lens is None:
         q_lens = jnp.full((q.shape[0],), q.shape[1], jnp.int32)
-    return _shard_map(
+    return jax.shard_map(
         lambda q_, k_, ks_, v_, vs_, t_, p_, l_, w_: body(
             q_, k_, ks_, v_, vs_, t_, p_, kv_lens=l_, q_lens=w_
         ),

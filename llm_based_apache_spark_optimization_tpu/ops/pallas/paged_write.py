@@ -12,35 +12,45 @@ it as a scatter op whose operand layout frequently forces a full-pool
 layout-conversion copy per layer (the same pathology
 `models/llama._update_cache_layer`'s docstring measured for the contiguous
 cache), and even the good lowering re-touches whole pages to land a
-[B, T, K, H] sliver. The kernel instead issues ONE bounded DMA per
-(row, token) sliver straight into the page the scalar-prefetched table
-names: HBM traffic is exactly the fresh K/V bytes.
+[B, T, K, H] sliver. The kernel instead moves only the pages the launch
+writes, straight to and from the pool the scalar-prefetched table names.
 
 Kernel design:
 
-- Grid = (B, T). The (page, offset, validity) triples are tiny int math
-  done OUTSIDE the kernel (`_write_coords`) and ride scalar prefetch; the
-  pools live in `ANY` (HBM) memory space and alias their outputs, so
-  nothing of the pool is ever streamed — the kernel's only HBM writes are
-  `pltpu.make_async_copy` slivers [K, H] (values) and [K] (scales).
-- Unmapped / out-of-row positions carry an invalid flag and skip the DMA
-  under `pl.when` — the same drop semantics jax gives the XLA scatter's
-  OOB indices, so parked scheduler slots and prefill padding rows write
-  nothing.
+- Grid = (B, T), one written position per cell, cells in order on one
+  core. The (page, offset, validity) triples are tiny int math done
+  OUTSIDE the kernel (`_coords`) and ride scalar prefetch; the pools alias
+  their outputs, so the pages a launch does not touch never move.
+- Each pool rides the Pallas pipeline as WHOLE-PAGE blocks
+  `[1, 1, K, PS(, H)]` picked by the table. A one-position sliver cannot
+  move alone: Mosaic DMAs only slices aligned to the pool's (8, 128)
+  tiling on its minor dims (the first design's sliver DMA passed every
+  interpret-mode test and was refused by the chip's compiler, as was a
+  `[K, PS]` scale slab, whose lane dim the HBM layout pads to 128). The
+  cell overwrites its position's row of the page block in VMEM with a
+  select; consecutive cells on one page share a single fetch and a single
+  write-back (the out block stays resident while its index does not
+  change), so a T-token window costs one page in and out per page it
+  touches, not per token.
+- Unmapped / out-of-row positions carry an invalid flag and write nothing
+  — the same drop semantics jax gives the XLA scatter's OOB indices, so
+  parked scheduler slots and prefill padding rows are inert. A dropped
+  cell still has to map SOME block: it maps its live neighbour's
+  (`_block_coords`), never a block of its own, because a block fetched
+  while its page is still being written back would carry stale rows.
 - K and V land in one kernel launch per layer (the "fused" half: the XLA
   path dispatched two scatters per layer); the quantizing variant also
   computes the per-position absmax scale over H on the VPU and writes
-  int8 values + f32 scales in the same launch — four DMAs, zero extra
-  passes over the sliver.
-- Writes within a grid cell target that row's OWN exclusive pages (the
-  scheduler's copy-on-write sweep guarantees no shared page sits in a
-  write range), so cells never race on a page; the grid is declared
-  "arbitrary" anyway since DMA issue order is irrelevant for disjoint
-  destinations.
+  int8 values + f32 scales in the same launch.
+- Contract: a row's written positions ascend, and rows own their write
+  pages exclusively (the scheduler's copy-on-write sweep guarantees no
+  shared page sits in a write range) — so no page is reopened after its
+  write-back started. The reference scatter has no such condition; every
+  caller meets it.
 
 `paged_write_reference` / `paged_write_reference_quantized` are the XLA
-goldens: bit-identical on CPU (interpret-mode parity tests) and the
-always-correct path `models/llama.forward` keeps for the einsum impl —
+goldens: bit-identical in interpret mode (parity tests) and compiled on a
+v5e (`chip_smoke.py`, both variants), and the always-correct path `models/llama.forward` keeps for the einsum impl —
 bf16 paged serving off-TPU is byte-for-byte what it was before this
 kernel existed.
 """
@@ -54,6 +64,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..quant import KV_SCALE_STEP
+from .dispatch import resolve_interpret
 
 
 def _coords(positions, page_table, page_size, num_pages, q_lens=None):
@@ -89,66 +102,133 @@ def _coords(positions, page_table, page_size, num_pages, q_lens=None):
     return pages, offs
 
 
-def _bf16_write_kernel(pages_ref, offs_ref, knew_ref, vnew_ref,
-                       _kp_any, _vp_any, okp, ovp, ksem, vsem, *,
-                       layer: int, num_pages: int):
-    b, t = pl.program_id(0), pl.program_id(1)
-    pg, off = pages_ref[b, t], offs_ref[b, t]
+def _block_coords(pages, num_pages):
+    """(blk [B, T], first [B, T]) for the kernels' page-block pipeline.
 
-    @pl.when(pg < num_pages)
+    Every grid cell maps one pool page as its in/out block, dropped cells
+    included. `blk` gives a dropped cell the page of the nearest live cell
+    before it in grid order (or, ahead of the first live cell, that
+    cell's), so a dropped cell never opens a block of its own: a block
+    fetched while its page is still being written back would return stale
+    rows over the fresh ones. `first` flags the cells where the block
+    changes — there the kernel seeds the out block from the in block.
+    With no live cell at all, every cell maps the last page and writes it
+    back unchanged."""
+    flat = pages.reshape(-1)
+    live = flat < num_pages
+    idx = jnp.arange(flat.shape[0], dtype=jnp.int32)
+    prev_live = jax.lax.cummax(jnp.where(live, idx, -1))
+    src = jnp.where(prev_live >= 0, prev_live, jnp.argmax(live))
+    blk = jnp.minimum(flat[src], num_pages - 1)
+    first = jnp.concatenate(
+        [jnp.ones((1,), jnp.int32), (blk[1:] != blk[:-1]).astype(jnp.int32)])
+    return blk.reshape(pages.shape), first.reshape(pages.shape)
+
+
+def _put_row(out_ref, new, row):
+    """Overwrite in-page position `row` of a [1, 1, K, PS, H] page block
+    with `new` [K, H]: a select over the block, widened to 32 bits (a
+    dynamic one-row store into a packed bf16/int8 tile does not lower,
+    nor does broadcasting a packed sliver narrower than a lane tile; the
+    round trip through f32/i32 is exact)."""
+    page = out_ref[0, 0]
+    wide = jnp.int32 if page.dtype == jnp.int8 else jnp.float32
+    rows = jax.lax.broadcasted_iota(jnp.int32, page.shape, 1)
+    out_ref[0, 0] = jnp.where(
+        rows == row, new.astype(wide)[:, None, :], page.astype(wide)
+    ).astype(page.dtype)
+
+
+def _put_col(out_ref, new, col):
+    """The [1, 1, K, PS] scale-block twin of `_put_row`."""
+    page = out_ref[0, 0]
+    cols = jax.lax.broadcasted_iota(jnp.int32, page.shape, 1)
+    out_ref[0, 0] = jnp.where(cols == col, new[:, None], page)
+
+
+def _bf16_write_kernel(blk_ref, first_ref, pages_ref, offs_ref,
+                       knew_ref, vnew_ref, kp_ref, vp_ref, okp, ovp, *,
+                       num_pages: int):
+    b, t = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(first_ref[b, t] == 1)
     def _():
-        kcp = pltpu.make_async_copy(
-            knew_ref.at[b, t],
-            okp.at[layer, pg, :, pl.ds(off, 1), :].at[:, 0], ksem,
-        )
-        vcp = pltpu.make_async_copy(
-            vnew_ref.at[b, t],
-            ovp.at[layer, pg, :, pl.ds(off, 1), :].at[:, 0], vsem,
-        )
-        kcp.start()
-        vcp.start()
-        kcp.wait()
-        vcp.wait()
+        okp[...] = kp_ref[...]
+        ovp[...] = vp_ref[...]
+
+    @pl.when(pages_ref[b, t] < num_pages)
+    def _():
+        _put_row(okp, knew_ref[0, 0], offs_ref[b, t])
+        _put_row(ovp, vnew_ref[0, 0], offs_ref[b, t])
 
 
-def _quant_write_kernel(pages_ref, offs_ref, knew_ref, vnew_ref,
-                        _kp, _ks, _vp, _vs, okp, oks, ovp, ovs,
-                        kq_scr, ks_scr, vq_scr, vs_scr,
-                        ksem, kssem, vsem, vssem, *,
-                        layer: int, num_pages: int):
+def _quant_write_kernel(blk_ref, first_ref, pages_ref, offs_ref,
+                        knew_ref, vnew_ref, kp_ref, ks_ref, vp_ref, vs_ref,
+                        okp, oks, ovp, ovs, *, num_pages: int):
     b, t = pl.program_id(0), pl.program_id(1)
-    pg, off = pages_ref[b, t], offs_ref[b, t]
 
     def quantize(x):
         x = x.astype(jnp.float32)
-        s = jnp.max(jnp.abs(x), axis=-1) / 127.0          # [K]
+        s = jnp.max(jnp.abs(x), axis=-1) * KV_SCALE_STEP  # [K]
         s = jnp.where(s == 0.0, 1.0, s)
-        q8 = jnp.clip(jnp.round(x / s[:, None]), -127, 127).astype(jnp.int8)
-        return q8, s
+        return jnp.clip(jnp.round(x / s[:, None]), -127, 127), s
 
-    kq, ks = quantize(knew_ref[b, t])
-    vq, vs = quantize(vnew_ref[b, t])
-    kq_scr[...], ks_scr[...] = kq, ks
-    vq_scr[...], vs_scr[...] = vq, vs
-
-    @pl.when(pg < num_pages)
+    @pl.when(first_ref[b, t] == 1)
     def _():
-        cps = (
-            pltpu.make_async_copy(
-                kq_scr, okp.at[layer, pg, :, pl.ds(off, 1), :].at[:, 0],
-                ksem),
-            pltpu.make_async_copy(
-                ks_scr, oks.at[layer, pg, :, pl.ds(off, 1)].at[:, 0], kssem),
-            pltpu.make_async_copy(
-                vq_scr, ovp.at[layer, pg, :, pl.ds(off, 1), :].at[:, 0],
-                vsem),
-            pltpu.make_async_copy(
-                vs_scr, ovs.at[layer, pg, :, pl.ds(off, 1)].at[:, 0], vssem),
+        for src, dst in ((kp_ref, okp), (ks_ref, oks),
+                         (vp_ref, ovp), (vs_ref, ovs)):
+            dst[...] = src[...]
+
+    @pl.when(pages_ref[b, t] < num_pages)
+    def _():
+        for new_ref, oq, os_ in ((knew_ref, okp, oks), (vnew_ref, ovp, ovs)):
+            q, s = quantize(new_ref[0, 0])
+            _put_row(oq, q, offs_ref[b, t])
+            _put_col(os_, s, offs_ref[b, t])
+
+
+def _run_write(kernel, pools, k_new, v_new, positions, page_table, layer,
+               q_lens, interpret, name):
+    """Grid (B, T), one written position per cell, cells in order on one
+    core. Each pool rides the pipeline as whole-page blocks picked by the
+    scalar-prefetched `blk` table and aliases its output: consecutive
+    cells on one page share a single fetch and write-back, and the pages
+    a launch does not touch never move. Rows own their write pages
+    exclusively and a row's positions ascend, so no page is reopened
+    after its write-back started."""
+    num_pages, kh, ps = pools[0].shape[1:4]
+    h = k_new.shape[-1]
+    interpret = resolve_interpret(interpret)
+    pages, offs = _coords(positions, page_table, ps, num_pages, q_lens)
+    blk, first = _block_coords(pages, num_pages)
+    b, t = pages.shape
+    sliver = pl.BlockSpec((1, 1, kh, h), lambda bi, ti, *_: (bi, ti, 0, 0))
+
+    def page_spec(pool):
+        tail = pool.shape[2:]
+        return pl.BlockSpec(
+            (1, 1) + tail,
+            lambda bi, ti, blk_, *_: (layer, blk_[bi, ti]) + (0,) * len(tail),
         )
-        for cp in cps:
-            cp.start()
-        for cp in cps:
-            cp.wait()
+
+    n_prefetch = 4
+    return pl.pallas_call(
+        functools.partial(kernel, num_pages=num_pages),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_prefetch,
+            grid=(b, t),
+            in_specs=[sliver, sliver] + [page_spec(p) for p in pools],
+            out_specs=[page_spec(p) for p in pools],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
+        # args: prefetch + (k_new, v_new), then the pools in output order.
+        input_output_aliases={n_prefetch + 2 + i: i
+                              for i in range(len(pools))},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        name=name,
+        interpret=interpret,
+    )(blk, first, pages, offs, k_new, v_new, *pools)
 
 
 @functools.partial(jax.jit, static_argnums=(6,),
@@ -168,36 +248,11 @@ def fused_page_write(
     """Write K and V slivers through per-row page tables at a static layer
     index, in one kernel launch (the Pallas twin of
     `paged_write_reference`, which remains the XLA/CPU golden). Both
-    pools alias their outputs: HBM traffic is the slivers alone."""
-    num_pages = kp.shape[1]
-    ps = kp.shape[3]
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
-    pages, offs = _coords(positions, page_table, ps, num_pages, q_lens)
-    b, t = pages.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, t),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.VMEM),   # k_new
-            pl.BlockSpec(memory_space=pltpu.VMEM),   # v_new
-            pl.BlockSpec(memory_space=pltpu.ANY),    # kp (aliased)
-            pl.BlockSpec(memory_space=pltpu.ANY),    # vp (aliased)
-        ],
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY)],
-        scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
-    )
-    return pl.pallas_call(
-        functools.partial(_bf16_write_kernel, layer=layer,
-                          num_pages=num_pages),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(kp.shape, kp.dtype),
-                   jax.ShapeDtypeStruct(vp.shape, vp.dtype)],
-        # args: 2 prefetch + (k_new, v_new, kp, vp) -> kp is arg 4, vp 5.
-        input_output_aliases={4: 0, 5: 1},
-        interpret=interpret,
-    )(pages, offs, k_new.astype(kp.dtype), v_new.astype(vp.dtype), kp, vp)
+    pools alias their outputs: HBM traffic is the touched pages alone."""
+    return _run_write(
+        _bf16_write_kernel, (kp, vp), k_new.astype(kp.dtype),
+        v_new.astype(vp.dtype), positions, page_table, layer, q_lens,
+        interpret, "fused_page_write")
 
 
 @functools.partial(jax.jit, static_argnums=(8,),
@@ -219,43 +274,10 @@ def fused_page_write_quantized(
     """The int8-quantizing fused write: absmax-over-H scales computed on
     the VPU inside the kernel (ops/quant.quantize_kv's exact math —
     parity-tested against `paged_write_reference_quantized`), int8 values
-    + f32 scales written in the same launch as four sliver DMAs."""
-    num_pages = kp.shape[1]
-    ps = kp.shape[3]
-    kh, h = kp.shape[2], kp.shape[4]
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
-    pages, offs = _coords(positions, page_table, ps, num_pages, q_lens)
-    b, t = pages.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, t),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.VMEM),   # k_new
-            pl.BlockSpec(memory_space=pltpu.VMEM),   # v_new
-            pl.BlockSpec(memory_space=pltpu.ANY),    # kp (aliased)
-            pl.BlockSpec(memory_space=pltpu.ANY),    # kps (aliased)
-            pl.BlockSpec(memory_space=pltpu.ANY),    # vp (aliased)
-            pl.BlockSpec(memory_space=pltpu.ANY),    # vps (aliased)
-        ],
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY) for _ in range(4)],
-        scratch_shapes=[
-            pltpu.VMEM((kh, h), jnp.int8), pltpu.VMEM((kh,), jnp.float32),
-            pltpu.VMEM((kh, h), jnp.int8), pltpu.VMEM((kh,), jnp.float32),
-            pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA,
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_quant_write_kernel, layer=layer,
-                          num_pages=num_pages),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype)
-                   for a in (kp, kps, vp, vps)],
-        # args: 2 prefetch + (k_new, v_new, kp, kps, vp, vps).
-        input_output_aliases={4: 0, 5: 1, 6: 2, 7: 3},
-        interpret=interpret,
-    )(pages, offs, k_new, v_new, kp, kps, vp, vps)
+    + f32 scales written in the same launch."""
+    return _run_write(
+        _quant_write_kernel, (kp, kps, vp, vps), k_new, v_new, positions,
+        page_table, layer, q_lens, interpret, "fused_page_write_quantized")
 
 
 def paged_write_reference(

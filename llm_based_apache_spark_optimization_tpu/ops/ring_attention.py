@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .common import NEG_INF, axis_size, shard_map
+from .common import NEG_INF
 
 
 def _block_scores(q5: jnp.ndarray, k: jnp.ndarray, scale: float) -> jnp.ndarray:
@@ -55,7 +55,7 @@ def _ring_attention_sharded(
     axis_name: str,
     sliding_window: Optional[int] = None,
 ) -> jnp.ndarray:
-    sp = axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     b, tq, n, h = q.shape
     tk = k.shape[1]
@@ -156,7 +156,7 @@ def ring_gqa_attention(
     fn = functools.partial(
         _ring_attention_sharded, axis_name=sp_axis, sliding_window=sliding_window
     )
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, pos_spec),

@@ -304,10 +304,11 @@ def main(argv=None) -> None:
         # means; negatives would silently slice from the end.
         sys.exit("runbook: --limit-cases must be >= 1")
 
-    if args.cpu:
-        import jax
+    from .utils.jaxenv import force_cpu, place_compile_cache
 
-        jax.config.update("jax_platforms", "cpu")
+    if args.cpu:
+        force_cpu()
+    place_compile_cache()
 
     import datetime
 

@@ -199,37 +199,14 @@ class EngineBackend:
         copy-heavy workload is its sweet spot)."""
         import jax.numpy as jnp
 
-        from ..checkpoint import load_hf_checkpoint
+        from ..checkpoint import load_and_quantize, load_hf_checkpoint
 
-        if quantize_int8 and quantize_int4:
-            raise ValueError("pick one of quantize_int8 / quantize_int4")
-        if quantize_int8 or quantize_int4 or quantize_unembed8:
-            from ..ops.quant import (
-                quantize_params,
-                quantize_params_int4,
-                quantize_unembed,
-            )
-            from ..parallel.sharding import shard_params
-
-            # Load host-side, quantize, then place: the quantized tree is
-            # what ships to devices, not the full-precision one.
-            cfg, params = load_hf_checkpoint(
-                ckpt_dir, dtype=dtype or jnp.bfloat16, mesh=None
-            )
-            if quantize_int4:
-                params = quantize_params_int4(params)
-            elif quantize_int8:
-                params = quantize_params(params)
-            if quantize_unembed8:
-                # Per-row int8 embed/unembed tables (composes with either
-                # block quantization — or none).
-                params = quantize_unembed(params)
-            if mesh is not None:
-                params = shard_params(params, cfg, mesh)
-        else:
-            cfg, params = load_hf_checkpoint(
-                ckpt_dir, dtype=dtype or jnp.bfloat16, mesh=mesh
-            )
+        cfg, params = load_and_quantize(
+            lambda m: load_hf_checkpoint(
+                ckpt_dir, dtype=dtype or jnp.bfloat16, mesh=m),
+            mesh, quantize_int8=quantize_int8, quantize_int4=quantize_int4,
+            quantize_unembed8=quantize_unembed8,
+        )
         engine = InferenceEngine(
             cfg, params, mesh=mesh, prompt_bucket=prompt_bucket,
             stop_ids=stop_ids if stop_ids is not None
@@ -261,33 +238,14 @@ class EngineBackend:
         blob's own quantization to the compute dtype; `quantize_int8` /
         `quantize_int4` then re-quantize into the in-tree serving formats
         (a Q4 blob served with quantize_int4 stays 4-bit end to end)."""
-        from ..checkpoint import load_gguf_checkpoint
+        from ..checkpoint import load_and_quantize, load_gguf_checkpoint
 
-        if quantize_int8 and quantize_int4:
-            raise ValueError("pick one of quantize_int8 / quantize_int4")
-        if quantize_int8 or quantize_int4 or quantize_unembed8:
-            from ..ops.quant import (
-                quantize_params,
-                quantize_params_int4,
-                quantize_unembed,
-            )
-            from ..parallel.sharding import shard_params
-
-            cfg, params = load_gguf_checkpoint(
-                gguf_path, cfg=cfg, dtype=dtype, mesh=None
-            )
-            if quantize_int4:
-                params = quantize_params_int4(params)
-            elif quantize_int8:
-                params = quantize_params(params)
-            if quantize_unembed8:
-                params = quantize_unembed(params)
-            if mesh is not None:
-                params = shard_params(params, cfg, mesh)
-        else:
-            cfg, params = load_gguf_checkpoint(
-                gguf_path, cfg=cfg, dtype=dtype, mesh=mesh
-            )
+        cfg, params = load_and_quantize(
+            lambda m: load_gguf_checkpoint(
+                gguf_path, cfg=cfg, dtype=dtype, mesh=m),
+            mesh, quantize_int8=quantize_int8, quantize_int4=quantize_int4,
+            quantize_unembed8=quantize_unembed8,
+        )
         engine = InferenceEngine(
             cfg, params, mesh=mesh, prompt_bucket=prompt_bucket,
             speculative_draft=speculative_draft, kv_quant=kv_quant,

@@ -146,7 +146,7 @@ class SchedulerCrashed(RuntimeError):
 class SchedulerStalled(SchedulerCrashed):
     """The decode loop stopped making progress — its heartbeat went stale
     past the watchdog's stall threshold while work was in flight (hung XLA
-    dispatch, wedged device tunnel). A wedge never *raises*, so the
+    dispatch, wedged device transport). A wedge never *raises*, so the
     watchdog (serve/watchdog.py + SupervisedScheduler's monitor thread)
     escalates it to this SYNTHETIC crash: subclassing `SchedulerCrashed`
     means the existing restart/journal/replay machinery recovers hung

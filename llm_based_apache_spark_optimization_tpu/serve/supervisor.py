@@ -42,7 +42,7 @@ that supervisor, wrapped around `ContinuousBatchingScheduler` (or a
   `scheduler-restart` records each crash/recovery so the per-dependency
   breaker view in `/metrics` includes the engine itself.
 - **Liveness (the watchdog).** Everything above only fires when a failure
-  *raises*. A WEDGED loop — hung XLA dispatch, stuck device tunnel — never
+  *raises*. A WEDGED loop — hung XLA dispatch, stuck device transport — never
   raises: without detection, queued requests sit until their deadlines
   burn while `/readyz` keeps saying `ready`. The supervisor runs a monitor
   thread that reads the inner scheduler's `heartbeat` (stamped every event

@@ -133,7 +133,7 @@ class ResilientSQLBackend:
             FAULTS.check("sql:exec")
             # Per-class SQL error sites (ISSUE 20): each raises a
             # REPRESENTATIVE engine error for one branch of the repair
-            # taxonomy — syntax/schema are deterministic engine answers
+            # classification — syntax/schema are deterministic engine answers
             # (no retry, breaker records success), transient is
             # lock-contention-shaped (retried, breaker-counted).
             FAULTS.check("sql:syntax")

@@ -70,8 +70,8 @@ stream deliveries must not perturb the seeded schedule.
 and `sql:transient` fire inside `ResilientSQLBackend.execute` and raise
 a REPRESENTATIVE engine error instead of the generic `InjectedFault` —
 the exact strings a real sqlite engine produces for each class of the
-repair taxonomy (app/repair.classify_sql_error), so chaos stage 10 and
-the unit tests can exercise every taxonomy branch deterministically.
+repair classification (app/repair.classify_sql_error), so chaos stage 10 and
+the unit tests can exercise every error-class branch deterministically.
 `sql:syntax`/`sql:schema` raise `InjectedSQLError` (a plain Exception:
 deterministic engine answers, NEVER retried or breaker-counted);
 `sql:transient` raises `InjectedFault` (a ConnectionError: the retry
@@ -125,7 +125,7 @@ class InjectedFault(ConnectionError):
 class InjectedSQLError(Exception):
     """A deliberately injected DETERMINISTIC engine error (ISSUE 20):
     the message is a representative real-engine string for one class of
-    the repair taxonomy. A plain Exception on purpose — retry ladders
+    the repair classification. A plain Exception on purpose — retry ladders
     and breakers must treat it exactly like the syntax/schema error it
     simulates (no retry, no breaker count), so the only layer that acts
     on it is the repair loop's classifier."""
@@ -138,7 +138,7 @@ class InjectedSQLError(Exception):
 #: Per-class SQL fault sites (ISSUE 20): site → (exception class,
 #: representative engine error string). The messages are the shapes
 #: app/repair.classify_sql_error keys on, so configuring
-#: `sql:syntax:1` drives the exact taxonomy branch a real engine would.
+#: `sql:syntax:1` drives the exact error-class branch a real engine would.
 SQL_FAULT_ERRORS = {
     "sql:syntax": (InjectedSQLError, 'near "FORM": syntax error'),
     "sql:schema": (InjectedSQLError, "no such column: total_amout"),

@@ -396,7 +396,7 @@ resilience = CounterSet()
 
 #: Process-wide self-healing-SQL counters (app/repair.py writes them:
 #: repair_rounds, repaired, unrepairable, breaker_skips, deadline_stops,
-#: plus one diagnosed_<class> counter per taxonomy class — a FIXED
+#: plus one diagnosed_<class> counter per error class — a FIXED
 #: five-entry vocabulary, so cardinality is bounded by construction) —
 #: merged into the /metrics payload under the reserved "repair" key by
 #: GenerationService.metrics_snapshot and rendered as the lsot_repair_*

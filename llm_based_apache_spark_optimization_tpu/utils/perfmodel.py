@@ -9,12 +9,11 @@ bench-time artifact the scheduler could not see. This module is the ONE
 definition both sides now price with:
 
 - `peak_for(device_kind, quant)` — the in-tree chip table (bf16/int8
-  TFLOP/s + HBM GB/s per TPU generation) with a CPU fallback: unknown
-  device kinds get nominal host peaks (LSOT_PEAK_TFLOPS /
-  LSOT_PEAK_HBM_GBS override them), so MFU/HBM-util are ALWAYS defined
-  and the CPU fixture tests exercise the same code path a chip does.
-  The absolute CPU numbers are nominal — the verdict and the
-  round-over-round trend are the signal there, not the magnitude.
+  TFLOP/s + HBM GB/s per TPU generation, published figures). A device
+  kind that is not in the table is an error, not a default. The CPU the
+  tests run on has an explicit `"cpu"` row of nominal host figures so
+  the ledger's arithmetic stays defined there; those are not device
+  measurements and bench.py omits utilization off-chip.
 - per-phase work models (`flops_per_token`, `prefill_flops`,
   `decode_step_bytes`, `kv_bytes`, `draft_bytes`, `verify_flops`) over
   the model config: prefill, decode, draft, verify — bf16/int8 weights
@@ -37,14 +36,12 @@ context.
 
 from __future__ import annotations
 
-import os
 import threading
 from typing import Dict, Optional, Tuple
 
 __all__ = [
     "PEAKS",
     "PerfModel",
-    "cpu_fallback_peaks",
     "decode_step_bytes",
     "draft_bytes",
     "flops_per_token",
@@ -56,45 +53,34 @@ __all__ = [
 
 # Peak specs by TPU generation for MFU / bandwidth accounting:
 # substring of device_kind (lowercased) -> (bf16 TFLOP/s, int8 TOP/s,
-# HBM GB/s). Moved in-tree from bench.py so the serving stack and the
-# bench can never disagree on a chip's roofline.
+# HBM GB/s), from the Google Cloud TPU documentation of each generation.
+# One table for the serving stack and the bench, so they can never
+# disagree on a chip's roofline. A v5e chip reports itself as
+# "TPU v5 lite" (chip run, PR 21).
 PEAKS: Dict[str, Tuple[float, float, float]] = {
     "v6": (918.0, 1836.0, 1640.0),
-    "v5e": (197.0, 394.0, 819.0),
-    "v5 lite": (197.0, 394.0, 819.0),
+    "v5e": (197.0, 393.0, 819.0),
+    "v5 lite": (197.0, 393.0, 819.0),
     "v5p": (459.0, 918.0, 2765.0),
     "v4": (275.0, 275.0, 1228.0),
+    # The CPU the tests run on: nominal host figures, there only so that
+    # the ledger divides by something. Never a device metric.
+    "cpu": (0.2, 0.2, 50.0),
 }
-
-
-def cpu_fallback_peaks() -> Tuple[float, float]:
-    """Nominal host peaks for unknown device kinds (the CPU fixture):
-    (FLOP/s, bytes/s). Overridable via LSOT_PEAK_TFLOPS /
-    LSOT_PEAK_HBM_GBS so an operator benchmarking an unlisted chip can
-    still get honest utilization numbers. Defaults are a generic server
-    host (0.2 TFLOP/s, 50 GB/s) — on the CPU fixture the VERDICT and the
-    trend are the signal, not the absolute MFU."""
-    try:
-        tf = float(os.environ.get("LSOT_PEAK_TFLOPS", "0.2"))
-    except ValueError:
-        tf = 0.2
-    try:
-        bw = float(os.environ.get("LSOT_PEAK_HBM_GBS", "50.0"))
-    except ValueError:
-        bw = 50.0
-    return max(tf, 1e-9) * 1e12, max(bw, 1e-9) * 1e9
 
 
 def peak_for(device_kind: str, quant: str = "") -> Tuple[float, float]:
     """(peak FLOP/s, peak HBM bytes/s) for a device kind; int8 weights
-    ride the int8 TOP/s column. Unknown kinds (CPU, new chips) fall back
-    to `cpu_fallback_peaks()` — never None, so every ledger entry carries
-    a defined MFU/HBM-util."""
-    dk = (device_kind or "").lower()
+    ride the int8 TOP/s column. A kind the table does not know raises:
+    a utilization against invented peaks is worse than none."""
+    dk = device_kind.lower()
     for key, (bf16_tf, int8_tf, bw) in PEAKS.items():
         if key in dk:
             return (int8_tf if quant == "int8" else bf16_tf) * 1e12, bw * 1e9
-    return cpu_fallback_peaks()
+    raise ValueError(
+        f"no peak figures for device kind {device_kind!r}; add its "
+        f"published peaks to utils/perfmodel.PEAKS (known: {sorted(PEAKS)})"
+    )
 
 
 # ------------------------------------------------------------- work models
@@ -211,7 +197,7 @@ class PerfModel:
                  kv_itemsize: int = 2, kv_quant: Optional[str] = None,
                  kv_layout: str = "contiguous",
                  page_size: Optional[int] = None, tp: int = 1,
-                 device_kind: str = ""):
+                 device_kind: str = "cpu"):
         self.cfg = cfg
         self.param_bytes = int(param_bytes)
         self.weight_bits = int(weight_bits)

@@ -420,7 +420,7 @@ def _emit_repair(emit: _Emitter, rep: Dict) -> None:
     """The self-healing-SQL lsot_repair_* families (ISSUE 20). Label
     cardinality is bounded by construction: the only labeled family is
     lsot_repair_errors_total{class=...}, whose classes come from the
-    fixed five-value taxonomy (app/repair.REPAIR_CLASSES); the "recent"
+    fixed five-value classification (app/repair.REPAIR_CLASSES); the "recent"
     flight rows are /metrics JSON only and never become series."""
     for key, name in (
             ("repair_rounds", "lsot_repair_rounds_total"),

@@ -1190,7 +1190,64 @@ def test_fused_page_write_matches_reference(rng):
     refs = paged_write_reference_quantized(
         kq, ksq, vq, vsq, k_new, v_new, positions, tab, layer)
     for o, r in zip(outs, refs):
-        np.testing.assert_allclose(np.asarray(o), np.asarray(r), atol=1e-6)
+        np.testing.assert_array_equal(np.asarray(o), np.asarray(r))
+
+
+@pytest.mark.parametrize("case", ["leading_parked", "dead_columns",
+                                  "all_parked", "page_crossing"])
+def test_fused_page_write_block_runs(rng, case):
+    """The write kernels move whole pages through the pipeline, and every
+    grid cell — dropped ones included — maps a page block (`_block_coords`).
+    The shapes of window that decide whether a dropped cell can clobber a
+    fresh write: a parked row AHEAD of the first live cell, dead columns
+    past `q_lens` between two live rows, a launch with no live cell at
+    all, and consecutive positions that cross from one page into the
+    next. Bit-identical to the reference scatter in each, both variants."""
+    from llm_based_apache_spark_optimization_tpu.ops.pallas import (
+        fused_page_write,
+        fused_page_write_quantized,
+        paged_write_reference,
+        paged_write_reference_quantized,
+    )
+
+    L, P, kh, ps, h, b, t, np_tab = 2, 16, 2, 8, 8, 4, 6, 3
+    # Rows own their write pages exclusively (the scheduler's
+    # copy-on-write sweep; the kernels' contract).
+    tab = rng.permutation(P)[: b * np_tab].reshape(b, np_tab)
+    starts, q_lens = np.array([0, 3, 9, 17]), None
+    if case == "leading_parked":
+        tab[0, :] = P
+    elif case == "dead_columns":
+        q_lens = jnp.asarray([2, 0, 6, 1], jnp.int32)
+    elif case == "all_parked":
+        tab[:, :] = P
+    elif case == "page_crossing":
+        starts = np.array([5, 6, 13, 20])  # 24 = past row 3's last page
+    tab = jnp.asarray(tab, jnp.int32)
+    positions = jnp.asarray(starts[:, None] + np.arange(t), jnp.int32)
+    kp = jnp.asarray(rng.normal(size=(L, P, kh, ps, h)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(L, P, kh, ps, h)), jnp.float32)
+    k_new = jnp.asarray(rng.normal(size=(b, t, kh, h)), jnp.float32)
+    v_new = jnp.asarray(rng.normal(size=(b, t, kh, h)), jnp.float32)
+    got = fused_page_write(kp, vp, k_new, v_new, positions, tab, 1,
+                           q_lens=q_lens)
+    want = (paged_write_reference(kp, k_new, positions, tab, 1, q_lens),
+            paged_write_reference(vp, v_new, positions, tab, 1, q_lens))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    if case == "all_parked":
+        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(kp))
+
+    from llm_based_apache_spark_optimization_tpu.ops.quant import quantize_kv
+
+    k8, v8 = quantize_kv(kp), quantize_kv(vp)
+    pools = (k8["q8"], k8["s"], v8["q8"], v8["s"])
+    got = fused_page_write_quantized(*pools, k_new, v_new, positions, tab, 1,
+                                     q_lens=q_lens)
+    want = paged_write_reference_quantized(*pools, k_new, v_new, positions,
+                                           tab, 1, q_lens)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 def test_engine_paged_int8_tracks_bf16_and_matches_contiguous_int8(tiny):

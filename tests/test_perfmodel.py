@@ -38,19 +38,21 @@ def make_sched(cfg, params, **kw):
 # --------------------------------------------------------- analytic model
 
 
-def test_peak_for_chip_table_and_cpu_fallback(monkeypatch):
+def test_peak_for_chip_table_cpu_row_and_unknown_kind():
     flops, bw = perfmodel.peak_for("TPU v5e chip", "")
     assert flops == 197.0e12 and bw == 819.0e9
     flops8, _ = perfmodel.peak_for("TPU v5e chip", "int8")
-    assert flops8 == 394.0e12  # int8 rides the TOP/s column
-    # Unknown kinds (the CPU fixture) fall back to nominal host peaks —
-    # always defined, env-overridable.
-    flops, bw = perfmodel.peak_for("cpu", "")
-    assert flops > 0 and bw > 0
-    monkeypatch.setenv("LSOT_PEAK_TFLOPS", "2.0")
-    monkeypatch.setenv("LSOT_PEAK_HBM_GBS", "100")
-    flops, bw = perfmodel.peak_for("weird-device", "")
-    assert flops == 2.0e12 and bw == 100.0e9
+    assert flops8 == 393.0e12  # int8 rides the TOP/s column (published)
+    # What a v5e chip reports as its device_kind (chip run, PR 21).
+    assert perfmodel.peak_for("TPU v5 lite", "") == (flops, bw)
+    # The CPU fixture is an explicit row of nominal host figures ...
+    assert perfmodel.peak_for("cpu", "") == (0.2e12, 50.0e9)
+    # ... and a kind the table does not know is an error, not a default:
+    # no catch-all, no environment variable that invents peaks.
+    with pytest.raises(ValueError, match="no peak figures"):
+        perfmodel.peak_for("weird-device", "")
+    with pytest.raises(ValueError, match="no peak figures"):
+        perfmodel.peak_for("", "")
 
 
 def test_flop_and_byte_models_match_bench_formulas(tiny_model_module):

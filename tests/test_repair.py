@@ -1,4 +1,4 @@
-"""Self-healing SQL (ISSUE 20): taxonomy, bounded repair loop, pipeline
+"""Self-healing SQL (ISSUE 20): classification, bounded repair loop, pipeline
 wiring, per-tenant model routing, metrics surfaces, and the evalh
 executable%-after-k leg.
 
@@ -52,7 +52,7 @@ def counters(monkeypatch):
     return fresh
 
 
-# ----------------------------------------------------------- taxonomy
+# ----------------------------------------------------------- classification
 
 
 def test_injected_sites_classify_by_site_name():
@@ -89,7 +89,7 @@ def test_classify_transient_infra():
     assert classify_sql_error(ConnectionError("peer reset")) == "transient"
 
 
-def test_taxonomy_vocabulary_is_fixed():
+def test_error_class_vocabulary_is_fixed():
     assert set(REPAIRABLE_CLASSES) < set(REPAIR_CLASSES)
     assert "resource" not in REPAIRABLE_CLASSES
 
