@@ -29,6 +29,7 @@ from ..serve.qos import normalize_qos
 from ..serve.service import GenerationService
 from ..sql.backend import SQLBackend
 from ..utils import tracing
+from ..utils.observability import StageTimer
 from ..utils.tracing import TRACER
 from .config import AppConfig
 from .health import (
@@ -328,10 +329,16 @@ def create_api_app(
             # no signal to back off on. Nothing useful ever precedes the
             # first chunk, so holding the 200 until it exists costs only
             # what the client was waiting for anyway.
+            # The stream's own spans: each chunk's way to the wire here
+            # (`http.chunk`: the span is open while the response writer
+            # has the chunk), the backend's `stream.detok` beside it;
+            # their sums end in the request's log record.
+            stages = StageTimer(rid=request_id)
             inner = service.generate_stream(
                 model, prompt, system=system, max_new_tokens=max_new,
                 constrain=constrain, deadline_s=deadline_s,
                 request_id=request_id, tenant=tenant, qos=qos,
+                stages=stages,
             )
             try:
                 with tracing.use(trace):
@@ -344,14 +351,16 @@ def create_api_app(
                 try:
                     try:
                         if first is not None:
-                            yield {"model": model, "response": first,
-                                   "done": False}
+                            with stages.stage("http.chunk"):
+                                yield {"model": model, "response": first,
+                                       "done": False}
                         # tracing.stepwise: inner advances under the
                         # trace context, which is never held across our
                         # own yields (the generator/contextvar hazard).
                         for piece in tracing.stepwise(inner, trace):
-                            yield {"model": model, "response": piece,
-                                   "done": False}
+                            with stages.stage("http.chunk"):
+                                yield {"model": model, "response": piece,
+                                       "done": False}
                     except Exception as e:  # mid-stream failure: headers
                         # are already sent, so surface the error as a final
                         # line instead of severing the connection silently.

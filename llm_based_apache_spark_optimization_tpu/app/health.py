@@ -146,11 +146,25 @@ def add_debug_routes(app: App, service: GenerationService) -> None:
       Replica-labeled for fleets; entries carry digests, never token
       ids.
     - `GET /debug/profile[?rounds=N[&model=M]]` — on-demand device
-      profiling: with `rounds`, ARM a bounded jax.profiler capture
-      around the scheduler's next N rounds (409 when a capture is
-      already in flight fleet-wide; the artifact is a Perfetto-loadable
-      trace next to the per-request trace exports); without `rounds`,
-      poll the capture state (armed/capturing/done + artifact list)."""
+      profiling: with `rounds`, take a bounded jax.profiler capture of
+      the scheduler's next N rounds (409 when a capture is already in
+      flight fleet-wide or the profiler will not start). THIS request's
+      thread starts the trace and answers `armed`; the scheduler's
+      worker only counts the rounds, and a writer thread stops the
+      trace — tens of seconds on a TPU, during which the serving loop
+      keeps serving — and lists the artifacts (`*.xplane.pb` and the
+      Perfetto-loadable `*.trace.json.gz`, next to the per-request
+      trace exports). Without `rounds`, poll the capture state: `armed`
+      → `capturing` (rounds left) → `writing` → `idle`, the finished
+      capture under `last` (`done` / `error` / `aborted`, artifact
+      list, `start_s`, `stop_s`). The trace runs from the request on,
+      rounds or no rounds: a capture a minute old on a server with
+      nothing to serve is stopped and reported `aborted` (no round was
+      issued) or `error` (cut short), as is one the scheduler's
+      shutdown or crash cuts short — after the clients have been
+      answered, on the writer thread too. The host's side of the trace
+      is the loop's own spans (`sched.*`, `stream.detok`, `http.chunk`:
+      utils/tracing.py), not the Python tracer, which is off."""
 
     @app.route("/debug/flightrecorder")
     def flightrecorder(req: Request) -> Response:

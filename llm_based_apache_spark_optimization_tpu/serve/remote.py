@@ -93,7 +93,7 @@ import numpy as np
 from ..engine.paged_kv import blob_meta
 from ..ops.sampling import SamplingParams
 from ..utils.faults import FAULTS, InjectedFault
-from ..utils.observability import resilience
+from ..utils.observability import FUTURE_STAMPS, resilience
 from .modelpool import UnknownModel
 from .resilience import (
     CircuitBreaker,
@@ -961,7 +961,7 @@ class LoopbackTransport(_TransportBase):
         def done(f: Future, c=client, tok=token):
             with self._pending_lock:
                 self._pending.pop(tok, None)
-            for a in ("_lsot_queue_wait", "_lsot_replica"):
+            for a in FUTURE_STAMPS:
                 v = getattr(f, a, None)
                 if v is not None:
                     setattr(c, a, v)

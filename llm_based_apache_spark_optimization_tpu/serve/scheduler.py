@@ -157,7 +157,7 @@ from ..ops.sampling import (
 from ..parallel.sharding import shard_params, validate_tp
 from ..utils import traceprof
 from ..utils.faults import FAULTS, InjectedFault
-from ..utils.observability import resilience
+from ..utils.observability import StageTimer, resilience
 from ..utils.perfmodel import PerfModel
 from .flightrecorder import FlightRecorder, merge_snapshots
 from .resilience import (
@@ -180,6 +180,11 @@ _log = logging.getLogger("lsot.scheduler")
 #: phase-aware router sends it migrated requests and keeps fresh prompts
 #: off it.
 PHASE_ROLES = ("mixed", "prefill", "decode")
+
+#: A /debug/profile capture older than this is stopped when the loop next
+#: finds nothing to serve: its trace runs from the request on, rounds or
+#: no rounds (`_expire_profile`).
+_PROFILE_IDLE_LIMIT_S = 60.0
 
 
 def parse_pool_phases(spec: str, replicas: int) -> List[str]:
@@ -448,6 +453,13 @@ class _Request:
     trace: Optional[object] = None
     admitted_at: float = 0.0
     ready_at: float = 0.0
+    # The request's waits up to its first token, cut at the first `emit`
+    # (the request log's `prefill_s` and `first_hold_s`): admission →
+    # prompt ready, and ready → the first token handed to the consumer (a
+    # first token rides the harvest of a decode round). With the queue
+    # wait they add up to the worker-side TTFT.
+    prefill_s: float = 0.0
+    first_hold_s: float = 0.0
     # Multi-model serving (ISSUE 16): the checkpoint this request's KV
     # was (or will be) written by — stamped at submit from the owning
     # scheduler, carried on requeue/extract wire frames so a migrated
@@ -584,6 +596,9 @@ class _Request:
             self.trace = None
 
     def emit(self, tok: int) -> None:
+        if not self.first_hold_s and self.ready_at:
+            self.prefill_s = self.ready_at - self.admitted_at
+            self.first_hold_s = time.perf_counter() - self.ready_at
         if self.on_token is not None:
             try:
                 self.on_token(tok)
@@ -687,6 +702,18 @@ class ContinuousBatchingScheduler:
         # bumped once per harvested round; per-model throughput
         # attribution reads it (pool.model_stats / lsot_model_*).
         self._tokens_emitted_total = 0
+        # The loop's stages on both clocks (ISSUE 26): every `sched.*`
+        # span is summed here on the host's clock — a round's flight
+        # record takes the sums since the last record — and is an event
+        # of the device trace's host plane while a capture runs.
+        self._stages = StageTimer()
+        self._loop_passes = 0
+        # Results of the requests a harvest finishes, held until the
+        # round's record is written (None outside a harvest).
+        self._round_results: Optional[list] = None
+        # Prefill dispatched since the last round record: chunk batches,
+        # real rows in them, prompt tokens they carried.
+        self._round_prefill = [0, 0, 0]
         # Handoff state. `_handoff_pending` holds (slot, req, tok, epoch)
         # for final chunks whose first token is still on device;
         # `_handoff` is the packed-blob queue the pool drains. Counters
@@ -975,14 +1002,17 @@ class ContinuousBatchingScheduler:
             device_kind=jax.devices()[0].device_kind,
         )
         self._last_harvest_t: Optional[float] = None
-        # On-demand device profiling (/debug/profile): armed captures
-        # start at the next issued round on the WORKER thread and stop
-        # after N harvested rounds; the process-wide guard in
+        # On-demand device profiling (/debug/profile): the caller's thread
+        # starts the trace and arms; the WORKER flips armed to capturing
+        # at the next round it issues and counts N harvested rounds; a
+        # writer thread stops the trace. The process-wide guard in
         # utils/traceprof keeps at most one capture in flight fleet-wide.
         self._profile_lock = threading.Lock()
         self._profile_arm: Optional[Dict[str, object]] = None
         self._profile_active: Optional[Dict[str, object]] = None
         self._profile_last: Optional[Dict[str, object]] = None
+        self._profile_writing: Optional[Dict[str, object]] = None
+        self._profile_writer: Optional[threading.Thread] = None
         # The persistent cache is a TUPLE of arrays threaded through every
         # jitted op: (k, v) in bf16 mode, (k8, ks, v8, vs) with int8 KV
         # (values + per-slot scales, ops/quant.quantize_kv), (kp, vp) pool
@@ -1096,8 +1126,9 @@ class ContinuousBatchingScheduler:
         # array or None, firsts list of (slot, req, first_tok device,
         # epoch), issue wall stamp, mixed_meta — the unified ragged
         # round's prefill-side attribution dict, None on alternating
-        # rounds).
-        self._pending: "deque[Tuple[List[Optional[_Request]], List[int], jax.Array, object, list, float, Optional[dict]]]" = deque()
+        # rounds — and the round's number, by which a trace pairs the
+        # span that issued it with the span that harvested it).
+        self._pending: "deque[Tuple[List[Optional[_Request]], List[int], jax.Array, object, list, float, Optional[dict], int]]" = deque()
         self._first_pending: list = []
         self._harvest_lag = 1  # rounds kept in flight before syncing
         (self._park_fn, self._ready_fn, self._retire_fn,
@@ -2147,6 +2178,7 @@ class ContinuousBatchingScheduler:
             rec["kv_pages"] = self._page_alloc.pages_in_use
             rec["kv_pages_free"] = self._page_alloc.pages_free
             rec["kv_pressure"] = self._page_alloc.withheld
+        self._host_columns(rec)
         self.flight.record(**rec)
         self._round_admitted = []
         self._round_retired = []
@@ -2247,15 +2279,22 @@ class ContinuousBatchingScheduler:
 
     def profile_rounds(self, rounds: Optional[int] = None,
                        out_dir: Optional[str] = None) -> Dict[str, object]:
-        """Arm a bounded `jax.profiler` device-trace capture around the
-        next `rounds` scheduler rounds (the /debug/profile seam). The
-        capture starts on the worker thread at the next issued round and
-        stops after N harvested rounds; the artifact (Perfetto-loadable
-        *.trace.json.gz, the same format the per-request trace exports
-        use) lands under `out_dir` — default: next to the tracer's
-        export dir (utils/traceprof.profile_defaults). Raises
-        RuntimeError when ANY capture is already in flight fleet-wide
-        (the process-wide guard)."""
+        """Take a bounded `jax.profiler` device trace of the next `rounds`
+        scheduler rounds (the /debug/profile seam). The trace is started
+        HERE, on the caller's thread, and stopped on a thread of its own,
+        beside the serving loop: stopping one takes half a minute to a
+        minute on a TPU (the profiler collects and converts the device's
+        events) and the loop keeps serving through it. The worker only
+        flips the armed capture to `capturing` at the next round it
+        issues and counts N harvested rounds; the writer thread then
+        stops the trace and lists the artifacts (`*.xplane.pb` and the
+        Perfetto-loadable `*.trace.json.gz`), which land under `out_dir`
+        — default: next to the tracer's export dir
+        (utils/traceprof.profile_defaults). The trace runs from this call
+        on: on a server with nothing to serve it is stopped after
+        `_PROFILE_IDLE_LIMIT_S` (`_expire_profile`). Raises RuntimeError
+        when ANY capture is already in flight fleet-wide (the
+        process-wide guard) or the profiler will not start."""
         import tempfile
 
         d_def, r_def = traceprof.profile_defaults()
@@ -2282,22 +2321,31 @@ class ContinuousBatchingScheduler:
                 os.makedirs(d, exist_ok=True)
             else:
                 d = tempfile.mkdtemp(prefix="lsot_profile_")
-        except OSError:
+            started = time.time()
+            jax.profiler.start_trace(
+                d, profiler_options=traceprof.profile_options())
+        except Exception as e:  # noqa: BLE001 — the guard must not leak
             traceprof.release_capture(owner)
-            raise
+            if isinstance(e, OSError):
+                raise
+            raise RuntimeError(f"the profiler did not start: {e}") from e
+        start_s = round(time.time() - started, 3)
         with self._profile_lock:
             self._profile_arm = {"rounds": n, "dir": d, "owner": owner,
-                                 "armed_at": time.time()}
+                                 "started": started, "start_s": start_s}
         return {"state": "armed", "rounds": n, "dir": d,
-                "replica": self.flight.replica}
+                "start_s": start_s, "replica": self.flight.replica}
 
     def profile_status(self) -> Dict[str, object]:
-        """Live capture state: armed (waiting for the next round) /
-        capturing (rounds left) / the last finished capture's artifact
-        list — what the smoke script polls."""
+        """Live capture state: armed (the trace runs; the worker has not
+        issued a round since) → capturing (rounds left) → writing (the
+        writer thread is stopping the trace) → idle, with the last
+        finished capture under `last` (state done / error / aborted, the
+        artifact list) — what the smoke script and the benchmark poll."""
         with self._profile_lock:
             arm, active, last = (self._profile_arm, self._profile_active,
                                  self._profile_last)
+            writing = self._profile_writing
             out: Dict[str, object] = {"replica": self.flight.replica}
             if active is not None:
                 out.update({"state": "capturing",
@@ -2306,6 +2354,8 @@ class ContinuousBatchingScheduler:
             elif arm is not None:
                 out.update({"state": "armed", "rounds": arm["rounds"],
                             "dir": arm["dir"]})
+            elif writing is not None:
+                out.update({"state": "writing", "dir": writing["dir"]})
             else:
                 out["state"] = "idle"
             if last is not None:
@@ -2313,39 +2363,21 @@ class ContinuousBatchingScheduler:
         return out
 
     def _maybe_start_profile(self) -> None:
-        """Worker-thread start: consume the armed request and open the
-        device trace so the next issued round is inside the capture."""
+        """Worker thread, before it issues a round: flip the armed
+        capture (its trace already runs) to capturing, so that the rounds
+        counted are rounds issued inside the trace."""
         with self._profile_lock:
             arm = self._profile_arm
             if arm is None or self._profile_active is not None:
                 return
             self._profile_arm = None
-        # Starting (and, in _finish_profile, stopping) a device trace
-        # blocks this thread for seconds — a two-round capture of a
-        # 32-layer model held it ~14 s on a v5e, past the watchdog's
-        # floor. It is the operator's request, not a wedge: stamp idle so
-        # the watchdog does not escalate it (and so the gap does not feed
-        # the cadence EWMA); the next loop iteration stamps busy again.
-        self.heartbeat.stamp(busy=False)
-        try:
-            jax.profiler.start_trace(arm["dir"])
-        except Exception as e:  # noqa: BLE001 — profiling must not kill serving
-            traceprof.release_capture(arm["owner"])
-            with self._profile_lock:
-                self._profile_last = {"state": "error",
-                                      "error": str(e)[:200],
-                                      "dir": arm["dir"]}
-            return
-        with self._profile_lock:
             self._profile_active = {
-                "rounds_left": arm["rounds"], "rounds": arm["rounds"],
-                # Rounds already in flight were ISSUED before the trace
-                # started: their harvests must not count toward the
-                # capture, or a lag-deep pipeline under live traffic
-                # brackets only N-1 (or zero) complete in-trace rounds.
+                **arm, "rounds_left": arm["rounds"],
+                # Rounds already in flight were ISSUED before the flip:
+                # their harvests must not count toward the capture, or a
+                # lag-deep pipeline under live traffic brackets only N-1
+                # (or zero) complete in-trace rounds.
                 "skip": len(self._pending),
-                "dir": arm["dir"], "owner": arm["owner"],
-                "started": time.time(),
             }
         self.flight.event("profile_start", rounds=arm["rounds"],
                           dir=arm["dir"])
@@ -2362,11 +2394,27 @@ class ContinuousBatchingScheduler:
             if st["rounds_left"] > 0:
                 return
             self._profile_active = None
-        self._finish_profile(st)
+            self._profile_writing = st
+        # The marker sits right after the last traced round's record and
+        # before the next one's: readers find the traced rounds by it.
+        # Stopping the trace takes tens of seconds on a TPU and is not
+        # the loop's to wait for.
+        self.flight.event("profile_done", rounds=st["rounds"], dir=st["dir"])
+        self._start_profile_writer(st)
+
+    def _start_profile_writer(self, st: Dict[str, object], **outcome) -> None:
+        self._profile_writer = threading.Thread(
+            target=self._finish_profile, args=(st,), kwargs=outcome,
+            name=f"lsot-profile-writer-{self.flight.replica}", daemon=True)
+        self._profile_writer.start()
 
     def _finish_profile(self, st: Dict[str, object],
-                        error: Optional[str] = None) -> None:
-        self.heartbeat.stamp(busy=False)  # see _maybe_start_profile
+                        error: Optional[str] = None,
+                        state: Optional[str] = None) -> None:
+        """The writer thread: stop the trace, list what it wrote, publish
+        the outcome as `profile_status()["last"]` and release the
+        fleet-wide guard."""
+        stopping = time.time()
         try:
             jax.profiler.stop_trace()
         except Exception as e:  # noqa: BLE001 — a failed stop is still a finish
@@ -2380,31 +2428,47 @@ class ContinuousBatchingScheduler:
             "artifact_bytes": sum(
                 os.path.getsize(a) for a in arts if os.path.exists(a)
             ),
+            "start_s": st["start_s"],
+            "stop_s": round(time.time() - stopping, 3),
             "wall_s": round(time.time() - float(st["started"]), 3),
         }
         if error:
             out["error"] = error
-            out["state"] = "error"
+            out["state"] = state or "error"
         with self._profile_lock:
             self._profile_last = out
+            if self._profile_writing is st:
+                self._profile_writing = None
         traceprof.release_capture(str(st["owner"]))
-        self.flight.event("profile_done", state=out["state"],
-                          artifacts=len(arts))
 
     def _abort_profile(self, reason: str) -> None:
-        """Shutdown/crash hygiene: an armed or mid-flight capture must
-        not leak the fleet-wide guard (or a dangling jax trace) past the
-        loop that owned it."""
+        """Shutdown/crash hygiene, and the bound on a capture that sees
+        no rounds: an armed or mid-flight capture must not leak the
+        fleet-wide guard (or a running jax trace) past the loop that
+        owned it. Its trace is stopped on the writer thread like any
+        other — the closing loop does not wait the tens of seconds that
+        takes on a TPU — and the guard goes when it has stopped."""
         with self._profile_lock:
             arm, self._profile_arm = self._profile_arm, None
             active, self._profile_active = self._profile_active, None
-        if arm is not None:
-            traceprof.release_capture(str(arm["owner"]))
-            with self._profile_lock:
-                self._profile_last = {"state": "aborted", "error": reason,
-                                      "dir": arm["dir"]}
-        if active is not None:
-            self._finish_profile(active, error=reason)
+            st = arm or active
+            if st is None:
+                return
+            self._profile_writing = st
+        self._start_profile_writer(
+            st, error=reason, state="aborted" if arm is not None else "error")
+
+    def _expire_profile(self) -> None:
+        """Worker thread, with nothing to serve: a capture armed on a
+        server that issues no round (or whose traffic ended before its
+        N-th) would trace without end. Once it is `_PROFILE_IDLE_LIMIT_S`
+        old it is stopped and reported `aborted` (never flipped) or
+        `error` (cut short)."""
+        st = self._profile_arm or self._profile_active
+        if st is not None and \
+                time.time() - float(st["started"]) > _PROFILE_IDLE_LIMIT_S:
+            self._abort_profile(
+                f"no round to trace within {_PROFILE_IDLE_LIMIT_S:.0f} s")
 
     def _build_prefill(self, t_bucket: int, k: int):
         cfg, impl, mesh = self.cfg, self._impl, self.mesh
@@ -3410,6 +3474,11 @@ class ContinuousBatchingScheduler:
                     timeout,
                 )
             self._thread = None
+        writer = self._profile_writer
+        if writer is not None and timeout is None:
+            # A capture cut short by this shutdown: let its trace stop
+            # before the process may exit under the profiler.
+            writer.join(120.0)
 
     def __enter__(self):
         return self.start()
@@ -4537,7 +4606,7 @@ class ContinuousBatchingScheduler:
             start += min(t, remaining)
         return end
 
-    def _prefill_step(self) -> None:
+    def _prefill_step(self, span) -> None:
         """Run ONE prompt chunk for up to `_prefill_kmax` waiting requests
         in a single batched forward (Sarathi-style chunked prefill, batched
         over admissions): long prompts interleave with decode rounds instead
@@ -4546,7 +4615,8 @@ class ContinuousBatchingScheduler:
         batch instead of paying a full pass per request. The chunk size is
         the smallest power-of-two bucket covering what's left of the prompt;
         only same-bucket entries batch together (one compiled program per
-        (bucket, k-bucket) pair, built lazily)."""
+        (bucket, k-bucket) pair, built lazily). `span` is the caller's open
+        `sched.prefill_dispatch` stage, which learns what was dispatched."""
         group: List[Tuple[int, _Request]] = []
         deferred = []
         t = 0
@@ -4652,6 +4722,7 @@ class ContinuousBatchingScheduler:
         avg_start = sum(starts[: len(group)]) // len(group)
         self.perf.note_prefill(rows=kb, tokens=t,
                                ctx=avg_start + t // 2)
+        self._note_prefill_dispatch(span, len(group), t, sum(chunk_lens))
         nc = len(self._cache)
         self._cache, toks = out[:nc], out[-1]
         if self._spec_draft:
@@ -4730,6 +4801,21 @@ class ContinuousBatchingScheduler:
                 (slot, req, tok, self._slot_epoch[slot])
             )
 
+    def _note_prefill_dispatch(self, span, rows: int, bucket: int,
+                               tokens: int) -> None:
+        """One chunk batch went to the device: the open span's arguments,
+        and the next round record's `prefill_*` columns."""
+        span.set(rows=rows, bucket=bucket, tokens=tokens)
+        self._round_prefill[0] += 1
+        self._round_prefill[1] += rows
+        self._round_prefill[2] += tokens
+
+    def _next_round(self) -> int:
+        """The number the round about to be issued will have in its
+        flight record (`heartbeat.rounds` when it is harvested): rounds
+        are harvested in the order they were issued, one count each."""
+        return self.heartbeat.rounds + len(self._pending) + 1
+
     def _publish_blocks(self, slot: int, req: _Request, chunk_start: int) -> None:
         """Publish the chunk's completed prefix blocks (chunk_start is always
         block-aligned: reuse stops on block boundaries and every non-final
@@ -4792,10 +4878,11 @@ class ContinuousBatchingScheduler:
                 self._prefix_note_evict(old_key, pages=old)
                 self._page_alloc.release(list(old))
 
-    def _issue_decode(self) -> None:
+    def _issue_decode(self, span) -> None:
         """Dispatch one decode round asynchronously: state chains on device,
         nothing syncs here. The round's tokens are harvested `_harvest_lag`
-        rounds later so the transfer round-trip overlaps later compute."""
+        rounds later so the transfer round-trip overlaps later compute.
+        `span` is the caller's open `sched.issue_decode` stage."""
         # Chaos seam (utils/faults.py): a `sched:decode` fault simulates a
         # device/loop failure mid-round — the loop dies, _run wraps it in
         # SchedulerCrashed, and every client future must fail typed, never
@@ -4815,9 +4902,11 @@ class ContinuousBatchingScheduler:
             # fleet chaos stage's targeted-restart trigger. Gated on
             # FAULTS.active so the idle path never builds the site string.
             FAULTS.check(f"sched:wedge_{self.flight.replica}")
+        rnd = self._next_round()
         active = np.asarray(
             [r is not None and r.ready for r in self._slot_req]
         )
+        span.set(round=rnd, occupancy=int(active.sum()))
         issue_reqs = [
             self._slot_req[i] if active[i] else None
             for i in range(self.num_slots)
@@ -4849,10 +4938,10 @@ class ContinuousBatchingScheduler:
             n_emit = None
         self._pending.append((issue_reqs, list(self._slot_epoch), toks,
                               n_emit, self._first_pending,
-                              time.perf_counter(), None))
+                              time.perf_counter(), None, rnd))
         self._first_pending = []
 
-    def _issue_mixed(self) -> bool:
+    def _issue_mixed(self, span) -> bool:
         """LSOT_RAGGED=1 hot path (ISSUE 19): ONE compiled launch admits
         this iteration's prompt chunks AND the decode round — no phase
         alternation, no off-phase idle. Same group selection as
@@ -4861,7 +4950,8 @@ class ContinuousBatchingScheduler:
         plumbing as _issue_decode — the round just carries a mixed_meta
         so harvest attributes both phases' analytic work over one wall.
         Returns False (caller falls back to the alternating path for
-        this iteration) when every queued entry was stale."""
+        this iteration) when every queued entry was stale. `span` is the
+        caller's open `sched.issue_mixed` stage."""
         group: List[Tuple[int, _Request]] = []
         deferred = []
         t = 0
@@ -4982,6 +5072,10 @@ class ContinuousBatchingScheduler:
             "pre_tokens": t,
             "pre_ctx": avg_start + t // 2,
         }
+        rnd = self._next_round()
+        span.set(round=rnd, occupancy=int(active.sum()))
+        self._note_prefill_dispatch(span, len(group), t,
+                                    sum(chunk_lens.values()))
 
         # Host tail for the chunk rows: _prefill_step's, minus the
         # prefill-role handoff branch (ragged requires phase_role=mixed).
@@ -5025,7 +5119,7 @@ class ContinuousBatchingScheduler:
             )
         self._pending.append((issue_reqs, list(self._slot_epoch), toks,
                               n_emit, self._first_pending,
-                              time.perf_counter(), mixed_meta))
+                              time.perf_counter(), mixed_meta, rnd))
         self._first_pending = []
         return True
 
@@ -5035,7 +5129,10 @@ class ContinuousBatchingScheduler:
         sample_runtime's all-greedy fast path for every later round)."""
         self._record_service_time(req)
         self._observe_terminal(req)
-        req.future.set_result(result)
+        if self._round_results is not None:
+            self._round_results.append((req.future, result))
+        else:
+            req.future.set_result(result)
         self._release_slot(slot)
 
     def _fail_slot(self, slot: int, req: _Request, exc: Exception) -> None:
@@ -5064,6 +5161,14 @@ class ContinuousBatchingScheduler:
             # starvation must show up in the queue-wait span + histogram,
             # not vanish because the request never reached a slot.
             req.future._lsot_queue_wait = now - req.submitted_at
+        if req.first_hold_s:
+            # The rest of the worker-side TTFT, and what the prefix cache
+            # spared its prefill (RequestMetrics' fields of these names).
+            req.future._lsot_waits = {
+                "prefill_s": req.prefill_s,
+                "first_hold_s": req.first_hold_s,
+                "prefix_reused_tokens": req.tokens_reused,
+            }
         self._round_retired.append(req.rid)
         with self._submit_lock:
             self._pending_new_tokens = max(
@@ -5129,11 +5234,55 @@ class ContinuousBatchingScheduler:
         # without duplicating delivered tokens (chaos tests assert zero
         # lost, zero double-streamed).
         FAULTS.check("sched:crash")
-        (issue_reqs, epochs, toks_dev, n_emit_dev, firsts, t_issue,
-         mixed_meta) = self._pending.popleft()
-        toks, n_emit, first_vals = jax.device_get(
-            (toks_dev, n_emit_dev, [t for (_, _, t, _) in firsts])
-        )
+        pending = self._pending.popleft()
+        _, _, toks_dev, n_emit_dev, firsts, _, _, rnd = pending
+        # The wait for the device is a span of its own: where it is
+        # near zero the host reached the harvest after the device had
+        # finished, and the host set that round's pace.
+        with self._stages.stage("sched.harvest_wait", round=rnd):
+            fetched = jax.device_get(
+                (toks_dev, n_emit_dev, [t for (_, _, t, _) in firsts])
+            )
+        # A request that ends in this round learns so once the round's
+        # record is written: whoever waits on its future finds the round
+        # that finished it in the flight ring.
+        self._round_results = []
+        try:
+            with self._stages.stage("sched.harvest", round=rnd) as span:
+                rec = self._commit_round(pending, *fetched)
+                span.set(emitted=rec["emitted"])
+            self._host_columns(rec)
+            self.flight.record(**rec)
+        finally:
+            results, self._round_results = self._round_results, None
+            for fut, result in results:
+                fut.set_result(result)
+        self._round_admitted = []
+        self._round_retired = []
+        if self._profile_active is not None:
+            self._profile_round_done()
+
+    def _host_columns(self, rec: Dict[str, object]) -> None:
+        """What the loop did since the last round record, on the host's
+        clock: `host_s` by span name (`self._stages`, taken and cleared),
+        the wait for the device and the wait for work beside it — the
+        three add up to the wall between two records but for the loop's
+        own glue — and the prefill dispatched."""
+        spans = self._stages.take()
+        rec["harvest_wait_s"] = round(spans.pop("sched.harvest_wait", 0.0), 6)
+        rec["idle_s"] = round(spans.pop("sched.idle", 0.0), 6)
+        rec["host_s"] = {k: round(v, 6) for k, v in spans.items()}
+        (rec["prefill_chunks"], rec["prefill_rows"],
+         rec["prefill_tokens"]) = self._round_prefill
+        self._round_prefill = [0, 0, 0]
+
+    def _commit_round(self, pending: tuple, toks, n_emit,
+                      first_vals) -> Dict[str, object]:
+        """The host's work on a fetched round: append and stream its
+        tokens, retire what finished, and build the round's flight
+        record, which it returns."""
+        (issue_reqs, epochs, _, _, firsts, t_issue, mixed_meta,
+         _) = pending
         toks = np.asarray(toks)
         t_harvest = time.perf_counter()
         occupancy = sum(1 for r in issue_reqs if r is not None)
@@ -5392,18 +5541,15 @@ class ContinuousBatchingScheduler:
             rec["handoff_wait_s"] = round(self._mig_wait, 6)
             self._mig_pages = 0
             self._mig_wait = 0.0
-        self.flight.record(**rec)
-        self._round_admitted = []
-        self._round_retired = []
-        if self._profile_active is not None:
-            self._profile_round_done()
+        return rec
 
     def _harvest_firsts(self) -> None:
         """Drain path: ready slots whose first token never rode a round."""
         if not self._first_pending:
             return
         firsts, self._first_pending = self._first_pending, []
-        vals = jax.device_get([t for (_, _, t, _) in firsts])
+        with self._stages.stage("sched.harvest_wait"):  # of no round
+            vals = jax.device_get([t for (_, _, t, _) in firsts])
         for (slot, req, _, fep), fv in zip(firsts, vals):
             self._append_first(slot, req, int(np.asarray(fv)[0]), epoch=fep)
 
@@ -5426,9 +5572,6 @@ class ContinuousBatchingScheduler:
 
     def _close(self, exc: BaseException) -> None:
         """Fail every in-flight and queued request; reject future submits."""
-        # An armed/mid-flight /debug/profile capture must not leak the
-        # fleet-wide guard past the loop that owned it.
-        self._abort_profile(f"scheduler closed: {type(exc).__name__}")
         with self._submit_lock:
             self._closed = True
             self._pending_new_tokens = 0
@@ -5469,6 +5612,10 @@ class ContinuousBatchingScheduler:
                 break
             if req is not None:
                 req.future.set_exception(exc)
+        # Last, with every client answered: an armed/mid-flight
+        # /debug/profile capture must not leak the fleet-wide guard past
+        # the loop that owned it.
+        self._abort_profile(f"scheduler closed: {type(exc).__name__}")
 
     def _busy_now(self) -> bool:
         """Work anywhere in the pipeline: the busy flag the event loop
@@ -5487,37 +5634,52 @@ class ContinuousBatchingScheduler:
 
     def _loop(self) -> None:
         while not self._stop_evt.is_set():
-            # Liveness stamp FIRST, so a wedge anywhere below (a hung XLA
-            # dispatch in prefill/decode, a stuck device_get in harvest)
-            # leaves a stale busy stamp for the watchdog to age. Idle
-            # iterations stamp busy=False every <=50ms (the queue.get
-            # timeout below), so an idle loop never looks wedged.
-            self.heartbeat.stamp(busy=self._busy_now())
-            if self._paged:
-                # Pressure-relief upkeep, every iteration (cheap int
-                # math when nothing is happening): sample the
-                # kv:pressure chaos site, evict prefix pages down to the
-                # high watermark when free pages dip under the low one,
-                # and fail page-starved waiters whose deadline burned
-                # (they would otherwise wait forever while slots stay
-                # busy).
+            # One pass is the profiler's step: a round's issue and its
+            # harvest are `_harvest_lag` passes apart, so a round cannot
+            # be one.
+            self._loop_passes += 1
+            with StageTimer.step("sched.loop", self._loop_passes):
+                self._loop_pass()
+
+    def _loop_pass(self) -> None:
+        """One pass of the serving loop: upkeep, admission, at most one
+        prompt chunk batch, one decode round issued and the oldest one
+        harvested — or, with nothing to issue, a drain and a wait for
+        work. Each stage is a span of `self._stages`."""
+        # Liveness stamp FIRST, so a wedge anywhere below (a hung XLA
+        # dispatch in prefill/decode, a stuck device_get in harvest)
+        # leaves a stale busy stamp for the watchdog to age. Idle
+        # iterations stamp busy=False every <=50ms (the queue.get
+        # timeout below), so an idle loop never looks wedged.
+        self.heartbeat.stamp(busy=self._busy_now())
+        if self._paged:
+            # Pressure-relief upkeep, every iteration (cheap int
+            # math when nothing is happening): sample the
+            # kv:pressure chaos site, evict prefix pages down to the
+            # high watermark when free pages dip under the low one,
+            # and fail page-starved waiters whose deadline burned
+            # (they would otherwise wait forever while slots stay
+            # busy).
+            with self._stages.stage("sched.upkeep"):
                 self._sample_pressure()
                 self._watermark_sweep()
                 self._sweep_page_wait()
-            # Admit pending requests into every free slot, then issue one
-            # prompt chunk and one decode round — all asynchronously — and
-            # harvest the oldest round once the pipeline is `_harvest_lag`
-            # deep. When fully idle, drain and block for work. Requests
-            # whose grammar differs from the installed one wait in
-            # `_constraint_wait` until the constrained slots drain (the
-            # table swap must not move live FSM states between grammars),
-            # then install and admit in arrival order. Fairness: while
-            # waiters exist, NEW constrained requests also queue behind
-            # them (even for the currently installed grammar) — otherwise
-            # a steady same-grammar stream keeps _constrained_busy() true
-            # forever and a different-grammar waiter starves. Waiters
-            # matching the installed grammar admit immediately (no drain
-            # needed); unconstrained traffic always flows directly.
+        # Admit pending requests into every free slot, then issue one
+        # prompt chunk and one decode round — all asynchronously — and
+        # harvest the oldest round once the pipeline is `_harvest_lag`
+        # deep. When fully idle, drain and block for work. Requests
+        # whose grammar differs from the installed one wait in
+        # `_constraint_wait` until the constrained slots drain (the
+        # table swap must not move live FSM states between grammars),
+        # then install and admit in arrival order. Fairness: while
+        # waiters exist, NEW constrained requests also queue behind
+        # them (even for the currently installed grammar) — otherwise
+        # a steady same-grammar stream keeps _constrained_busy() true
+        # forever and a different-grammar waiter starves. Waiters
+        # matching the installed grammar admit immediately (no drain
+        # needed); unconstrained traffic always flows directly.
+        with self._stages.stage("sched.admit") as span:
+            admitted = len(self._round_admitted)
             while self._free_slots():
                 wait = self._constraint_wait
                 if wait and self._grammar_matches(wait[0].constraint):
@@ -5565,62 +5727,71 @@ class ContinuousBatchingScheduler:
                     # moving and will free pages.
                     self._page_wait.appendleft(req)
                     break
-            # Unified ragged round (LSOT_RAGGED=1, ISSUE 19): fold this
-            # iteration's prompt chunks INTO the decode launch — one
-            # compiled program, no phase alternation, the off-phase
-            # never idles. Falls through to the alternating path when
-            # every queued prefill entry was stale, so decode never
-            # stalls behind an empty mix.
-            if self._ragged and self._prefill_q:
-                if self._profile_arm is not None:
-                    self._maybe_start_profile()
-                if self._issue_mixed():
-                    if len(self._pending) > self._harvest_lag:
-                        self._harvest_round()
-                    continue
-            # Fair interleave: at most one prompt chunk per decode round —
-            # admission work is bounded, so active slots never wait longer
-            # than one prompt_bucket forward.
-            if self._prefill_q:
-                self._prefill_step()
-            if self._handoff_pending:
-                # Prefill-role terminal step: commit first tokens, pack
-                # blobs, wake the pool's placement pump (mixed/decode
-                # replicas never queue anything here).
-                self._pack_handoffs()
-            if any(r is not None and r.ready for r in self._slot_req):
-                if self._profile_arm is not None:
-                    # Armed /debug/profile capture: start the device trace
-                    # on THIS thread, bracketing the next N rounds.
-                    self._maybe_start_profile()
-                self._issue_decode()
+            span.set(admitted=len(self._round_admitted) - admitted,
+                     queued=self._queue.qsize())
+        # Unified ragged round (LSOT_RAGGED=1, ISSUE 19): fold this
+        # iteration's prompt chunks INTO the decode launch — one
+        # compiled program, no phase alternation, the off-phase
+        # never idles. Falls through to the alternating path when
+        # every queued prefill entry was stale, so decode never
+        # stalls behind an empty mix.
+        if self._ragged and self._prefill_q:
+            if self._profile_arm is not None:
+                self._maybe_start_profile()
+            with self._stages.stage("sched.issue_mixed") as span:
+                issued = self._issue_mixed(span)
+            if issued:
                 if len(self._pending) > self._harvest_lag:
                     self._harvest_round()
-            elif not self._prefill_q:
-                # Nothing left to issue: drain in-flight rounds and any
-                # unridden first tokens, then wait for new requests.
-                while self._pending:
-                    self._harvest_round()
-                self._harvest_firsts()
-                if self._prefill_q or self._constraint_wait or any(
-                    r is not None for r in self._slot_req
-                ) or (self._paged and self._page_wait) or self._ready:
-                    continue  # harvests freed work — go admit/issue again
-                try:
+                return
+        # Fair interleave: at most one prompt chunk per decode round —
+        # admission work is bounded, so active slots never wait longer
+        # than one prompt_bucket forward.
+        if self._prefill_q:
+            with self._stages.stage("sched.prefill_dispatch") as span:
+                self._prefill_step(span)
+        if self._handoff_pending:
+            # Prefill-role terminal step: commit first tokens, pack
+            # blobs, wake the pool's placement pump (mixed/decode
+            # replicas never queue anything here).
+            self._pack_handoffs()
+        if any(r is not None and r.ready for r in self._slot_req):
+            if self._profile_arm is not None:
+                # Armed /debug/profile capture: count the next N rounds.
+                self._maybe_start_profile()
+            with self._stages.stage("sched.issue_decode") as span:
+                self._issue_decode(span)
+            if len(self._pending) > self._harvest_lag:
+                self._harvest_round()
+        elif not self._prefill_q:
+            # Nothing left to issue: drain in-flight rounds and any
+            # unridden first tokens, then wait for new requests.
+            while self._pending:
+                self._harvest_round()
+            self._harvest_firsts()
+            if self._prefill_q or self._constraint_wait or any(
+                r is not None for r in self._slot_req
+            ) or (self._paged and self._page_wait) or self._ready:
+                return  # harvests freed work — go admit/issue again
+            try:
+                with self._stages.stage("sched.idle"):
                     req = self._queue.get(timeout=0.05)
-                    if req is not None:
-                        # Fully idle here (no slots, no waiters), so a new
-                        # grammar can install immediately.
-                        c = req.constraint
-                        if c is not None and not self._grammar_matches(c):
-                            self._install_constraint(c)
-                        if not self._admit(self._free_slots()[0], req):
-                            # Paged + fully idle: can only mean the pool
-                            # itself is smaller than one request envelope
-                            # after eviction — park it like the loop does.
-                            self._page_wait.appendleft(req)
-                except queue.Empty:
-                    pass
+            except queue.Empty:
+                if self._profile_arm or self._profile_active:
+                    self._expire_profile()
+                return
+            if req is not None:
+                with self._stages.stage("sched.admit", admitted=1, queued=0):
+                    # Fully idle here (no slots, no waiters), so a new
+                    # grammar can install immediately.
+                    c = req.constraint
+                    if c is not None and not self._grammar_matches(c):
+                        self._install_constraint(c)
+                    if not self._admit(self._free_slots()[0], req):
+                        # Paged + fully idle: can only mean the pool
+                        # itself is smaller than one request envelope
+                        # after eviction — park it like the loop does.
+                        self._page_wait.appendleft(req)
 
 
 @dataclasses.dataclass
@@ -8180,7 +8351,8 @@ class SchedulerBackend:
                         stats_out: Optional[dict] = None,
                         constrain=None,
                         deadline_s: Optional[float] = None,
-                        tenant: str = "", qos: str = ""):
+                        tenant: str = "", qos: str = "",
+                        stages: Optional[StageTimer] = None):
         """Stream the completion as text chunks while it decodes — the
         capability Ollama's `stream=true` API exposes and the reference
         never used. Token ids arrive from the scheduler's per-request
@@ -8194,7 +8366,12 @@ class SchedulerBackend:
         completion) ON PURPOSE: prefix-decode is not compositional for
         BPE/sentencepiece boundaries, the cost is host-side microseconds
         per token against human-reading-rate output, and exactness vs the
-        blocking path is the contract the tests pin."""
+        blocking path is the contract the tests pin. What it does cost is
+        the `stream.detok` span of `stages`, the stream's StageTimer (the
+        caller's, which reads the sum and may add spans of its own).
+        `stats_out` also gets `stream_lag_p90_s`: per token,
+        from the worker's `emit` — which stamps the time beside the token
+        — to the piece leaving this generator."""
         from ..utils import tracing
         from .backends import trim_stop_texts
 
@@ -8204,10 +8381,13 @@ class SchedulerBackend:
             # prompt is tokenized here anyway, and chunk counts are not
             # token counts (holdbacks merge many tokens into one chunk).
             stats_out["prompt_tokens"] = len(ids)
-        toks: "queue.Queue[int]" = queue.Queue()
+        toks: "queue.Queue[Tuple[int, float]]" = queue.Queue()
         trace = tracing.current()
+        stages = stages if stages is not None else StageTimer()
+        lags: List[float] = []
         t_submit = time.perf_counter()
-        on_tok, first_at = _first_token_timer(toks.put)
+        on_tok, first_at = _first_token_timer(
+            lambda tok: toks.put((tok, time.perf_counter())))
         fut = self.scheduler.submit(
             ids, max_new_tokens=self._budget(len(ids), max_new_tokens),
             sampling=sampling or self.sampling, seed=seed,
@@ -8225,12 +8405,14 @@ class SchedulerBackend:
             done = False
             while not done:
                 try:
-                    out_ids.append(toks.get(timeout=0.05))
+                    tok, t_emit = toks.get(timeout=0.05)
                 except queue.Empty:
                     done = fut.done()
                     continue
-                text = self.tokenizer.decode(out_ids)
-                trimmed = trim_stop_texts(text, self.stop_texts)
+                out_ids.append(tok)
+                with stages.stage("stream.detok"):
+                    text = self.tokenizer.decode(out_ids)
+                    trimmed = trim_stop_texts(text, self.stop_texts)
                 if trimmed != text:  # a stop text landed: flush and end
                     if len(trimmed) > len(emitted):
                         yield trimmed[len(emitted):]
@@ -8250,10 +8432,11 @@ class SchedulerBackend:
                 delta = safe[len(emitted):]
                 if delta and not delta.endswith("�"):
                     emitted += delta
+                    lags.append(time.perf_counter() - t_emit)
                     yield delta
             fut.result()  # propagate errors; also syncs the token list
             while not toks.empty():
-                out_ids.append(toks.get_nowait())
+                out_ids.append(toks.get_nowait()[0])
             text = trim_stop_texts(
                 self.tokenizer.decode(out_ids), self.stop_texts
             )
@@ -8290,6 +8473,11 @@ class SchedulerBackend:
                 qw = getattr(fut, "_lsot_queue_wait", 0.0)
                 if qw:
                     stats_out["queue_wait_s"] = qw
+                stats_out.update(getattr(fut, "_lsot_waits", {}))
+                if lags:
+                    lags.sort()
+                    stats_out["stream_lag_p90_s"] = lags[
+                        min(len(lags) - 1, int(0.9 * len(lags)))]
                 stats_out["rclass"] = self._rclass(constrain)
                 stats_out["replica"] = getattr(fut, "_lsot_replica", "")
 

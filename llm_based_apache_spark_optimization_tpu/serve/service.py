@@ -22,8 +22,8 @@ from ..utils import tracing
 from ..utils.observability import (
     MetricsRegistry,
     RequestMetrics,
+    StageTimer,
     resilience,
-    trace_capture,
 )
 from ..utils.tracing import TRACER
 from .templates import TEMPLATES, Template
@@ -600,16 +600,14 @@ class GenerationService:
             with tracing.use(tr) if own is not None else contextlib.nullcontext():
                 with tracing.span("service.generate", model=model,
                                   constrained=constrain is not None):
-                    with trace_capture(f"generate-{model}"):
-                        completion = entry.backend.complete(
-                            rendered, max_new_tokens=max_new_tokens,
-                            sampling=sampling, seed=seed,
-                            **self._constrain_kwargs(entry, constrain),
-                            **self._deadline_kwargs(entry, deadline_s),
-                            **self._idempotency_kwargs(entry,
-                                                       idempotency_key),
-                            **self._qos_kwargs(entry, tenant, qos),
-                        )
+                    completion = entry.backend.complete(
+                        rendered, max_new_tokens=max_new_tokens,
+                        sampling=sampling, seed=seed,
+                        **self._constrain_kwargs(entry, constrain),
+                        **self._deadline_kwargs(entry, deadline_s),
+                        **self._idempotency_kwargs(entry, idempotency_key),
+                        **self._qos_kwargs(entry, tenant, qos),
+                    )
         finally:
             TRACER.finish(own)
         latency = time.perf_counter() - t0
@@ -694,11 +692,15 @@ class GenerationService:
         request_id: Optional[str] = None,
         tenant: str = "",
         qos: str = "",
+        stages: Optional[StageTimer] = None,
     ):
         """Yield the completion as text chunks while it decodes (Ollama's
         `stream=true` surface). Backends without a `complete_stream` seam
         (the one-XLA-program engine, fakes) degrade to a single chunk.
-        Metrics record the request exactly like generate(). Front-door
+        Metrics record the request exactly like generate(), with the sums
+        of the stream's own spans: `stages` is the stream's StageTimer —
+        the HTTP layer passes the one its `http.chunk` spans go to, the
+        backend adds `stream.detok`. Front-door
         admission (ISSUE 18) runs on the generator's FIRST step — the
         HTTP layer primes the stream before sending headers, so a shed
         still answers a real 429."""
@@ -725,6 +727,8 @@ class GenerationService:
             return tracing.use(tr) if own is not None \
                 else contextlib.nullcontext()
 
+        if stages is None:
+            stages = StageTimer(rid=rid)
         t0 = time.perf_counter()
         out_tokens = prompt_tokens = 0
         stream_stats: dict = {}
@@ -747,19 +751,18 @@ class GenerationService:
                 inner = streamer(
                     rendered, max_new_tokens=max_new_tokens,
                     sampling=sampling, seed=seed, stats_out=stream_stats,
-                    **ckw,
+                    stages=stages, **ckw,
                 )
                 try:
-                    with trace_capture(f"generate-{model}"):
-                        # tracing.stepwise: the backend advances under
-                        # the trace context, which is never held across
-                        # our own yields (the generator/contextvar
-                        # hazard). Only needed when this call drew the
-                        # sample; the HTTP path advances plain.
-                        src = tracing.stepwise(inner, tr) \
-                            if own is not None else inner
-                        for chunk in src:
-                            yield chunk
+                    # tracing.stepwise: the backend advances under the
+                    # trace context, which is never held across our own
+                    # yields (the generator/contextvar hazard). Only
+                    # needed when this call drew the sample; the HTTP
+                    # path advances plain.
+                    src = tracing.stepwise(inner, tr) \
+                        if own is not None else inner
+                    for chunk in src:
+                        yield chunk
                 finally:
                     # Deterministically unwind the backend generator
                     # (its finally cancels the scheduler request and
@@ -778,6 +781,7 @@ class GenerationService:
             out_tokens = stream_stats.get("output_tokens", out_tokens)
             prompt_tokens = stream_stats.get("prompt_tokens", prompt_tokens)
             latency = time.perf_counter() - t0
+            spans = stages.spans
             with self._lock:
                 s = self.stats[model]
                 s["requests"] += 1
@@ -790,6 +794,13 @@ class GenerationService:
                 latency_s=latency,
                 ttft_s=stream_stats.get("ttft_s", 0.0),
                 queue_wait_s=stream_stats.get("queue_wait_s", 0.0),
+                prefill_s=stream_stats.get("prefill_s", 0.0),
+                first_hold_s=stream_stats.get("first_hold_s", 0.0),
+                prefix_reused_tokens=stream_stats.get(
+                    "prefix_reused_tokens", 0),
+                stream_lag_p90_s=stream_stats.get("stream_lag_p90_s", 0.0),
+                detok_s=spans.get("stream.detok", 0.0),
+                chunk_s=spans.get("http.chunk", 0.0),
                 rclass=stream_stats.get("rclass", ""),
                 replica=stream_stats.get("replica", ""),
                 request_id=rid,
@@ -821,12 +832,11 @@ class GenerationService:
             self._admit_qos(tenant, qos, None)
         rendered = [entry.template(system, p) for p in prompts]
         t0 = time.perf_counter()
-        with trace_capture(f"generate-batch-{model}"):
-            completions = entry.backend.complete_batch(
-                rendered, max_new_tokens=max_new_tokens, sampling=sampling,
-                seed=seed, **self._constrain_kwargs(entry, constrain),
-                **self._qos_kwargs(entry, tenant, qos),
-            )
+        completions = entry.backend.complete_batch(
+            rendered, max_new_tokens=max_new_tokens, sampling=sampling,
+            seed=seed, **self._constrain_kwargs(entry, constrain),
+            **self._qos_kwargs(entry, tenant, qos),
+        )
         latency = time.perf_counter() - t0
         with self._lock:
             s = self.stats[model]

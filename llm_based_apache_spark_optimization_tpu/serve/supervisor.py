@@ -92,7 +92,7 @@ from concurrent.futures import TimeoutError as FutureTimeoutError
 from typing import Callable, Dict, List, Optional, Sequence
 
 from ..ops.sampling import SamplingParams
-from ..utils.observability import resilience
+from ..utils.observability import FUTURE_STAMPS, resilience
 from .flightrecorder import FlightRecorder, append_jsonl, merge_snapshots
 from .resilience import (
     CircuitBreaker,
@@ -1247,7 +1247,7 @@ class SupervisedScheduler:
         # Surface the serving attempt's measured queue wait / replica on
         # the CLIENT-facing future (the inner future is an implementation
         # detail that dies with the loop).
-        for attr in ("_lsot_queue_wait", "_lsot_replica"):
+        for attr in FUTURE_STAMPS:
             v = getattr(entry.inner, attr, None)
             if v is not None:
                 setattr(entry.future, attr, v)

@@ -5,15 +5,15 @@ bracket in its eval harness (SURVEY.md §5 "Tracing/profiling",
 `Model_Evaluation_&_Comparision.py:42-44`). Here the serving stack gets real
 counters:
 
-- `StageTimer` — wall-clock spans around pipeline stages (prefill vs decode,
-  SQL exec, persistence), cheap enough to always be on.
+- `StageTimer` — named spans around the stages of a loop (the scheduler's
+  `sched.*`, a stream's `stream.detok` / `http.chunk`), cheap enough to
+  always be on. Each span is summed on the host's clock and, while a
+  `/debug/profile` capture runs, is also an event of the device trace's
+  host plane, on the device's clock.
 - `RequestMetrics` / `MetricsRegistry` — per-request records (prompt/output
   tokens, decode tok/s, end-to-end latency) with process-lifetime aggregates
   (count, p50/p95 latency, aggregate tok/s), surfaced by the app's
   `/metrics` endpoint and printed by the bench harness.
-- `trace_capture` — `jax.profiler` trace of a code region, gated behind the
-  LSOT_TRACE_DIR env var: zero overhead when unset, a TensorBoard-loadable
-  trace directory when set.
 
 Everything is thread-safe: the serving layer calls this from request
 threads and the continuous-batching scheduler loop alike.
@@ -22,7 +22,6 @@ threads and the continuous-batching scheduler loop alike.
 from __future__ import annotations
 
 import bisect
-import contextlib
 import dataclasses
 import json
 import logging
@@ -30,7 +29,7 @@ import os
 import random
 import threading
 import time
-from typing import Dict, Iterator, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 log = logging.getLogger("lsot.metrics")
 
@@ -64,30 +63,96 @@ def reconfigure_request_log(sample: float) -> None:
     registry._log_sample = _LOG_SAMPLE_OVERRIDE
 
 
+#: (TraceAnnotation, StepTraceAnnotation), imported on first use: importing
+#: this module must not import JAX.
+_ANNOTATIONS: Optional[tuple] = None
+
+
+def _annotations() -> tuple:
+    global _ANNOTATIONS
+    if _ANNOTATIONS is None:
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+        _ANNOTATIONS = (TraceAnnotation, StepTraceAnnotation)
+    return _ANNOTATIONS
+
+
+class _Stage:
+    """One open span of a `StageTimer`: a `perf_counter` pair summed under
+    the span's name, and a `jax.profiler` annotation (a TraceMe: a no-op
+    while no capture runs) that puts the same span, with its arguments,
+    on the host plane of a device trace."""
+
+    __slots__ = ("_timer", "_name", "_ann", "_t0")
+
+    def __init__(self, timer: "StageTimer", name: str, ann):
+        self._timer, self._name, self._ann = timer, name, ann
+
+    def __enter__(self) -> "_Stage":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def set(self, **args) -> None:
+        """Arguments known only once the work is done (`emitted`, `rows`):
+        they join the span's arguments in the trace."""
+        self._ann.set_metadata(**args)
+
+    def __exit__(self, *exc) -> bool:
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        self._timer._add(self._name, dt)
+        return False
+
+
 class StageTimer:
-    """Accumulates named wall-clock spans: `with timer.stage("prefill"): ...`.
+    """Accumulates named spans: `with timer.stage("sched.admit"): ...`.
 
-    Re-entering a stage name accumulates (decode chunks sum into one
-    "decode" figure)."""
+    Re-entering a name accumulates (the chunks of a request sum into one
+    `stream.detok` figure), from any thread. `take()` returns the sums and
+    clears them: the scheduler takes once a round record, a stream once a
+    request. Names follow `utils/tracing.py`'s dotted convention; keyword
+    arguments — the constructor's on every stage (a stream's `rid`), a
+    stage's own beside them — are the span's arguments in a device trace
+    and are not kept on the host's clock."""
 
-    def __init__(self):
+    def __init__(self, **args):
+        self._args = args
         self._spans: Dict[str, float] = {}
         self._lock = threading.Lock()
 
-    @contextlib.contextmanager
-    def stage(self, name: str) -> Iterator[None]:
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            with self._lock:
-                self._spans[name] = self._spans.get(name, 0.0) + dt
+    def stage(self, name: str, **args) -> _Stage:
+        if self._args:
+            args = {**self._args, **args}
+        return _Stage(self, name, _annotations()[0](name, **args))
+
+    @staticmethod
+    def step(name: str, step_num: int):
+        """The profiler's step (`StepTraceAnnotation`): one pass of a loop,
+        the unit a trace viewer groups its stages by. It covers them, so
+        it is in the trace only and not summed on the host's clock."""
+        return _annotations()[1](name, step_num=step_num)
+
+    def _add(self, name: str, dt: float) -> None:
+        with self._lock:
+            self._spans[name] = self._spans.get(name, 0.0) + dt
 
     @property
     def spans(self) -> Dict[str, float]:
         with self._lock:
             return dict(self._spans)
+
+    def take(self) -> Dict[str, float]:
+        with self._lock:
+            spans, self._spans = self._spans, {}
+        return spans
+
+
+#: What the scheduler's worker stamps on a request's future at terminal
+#: time for the request log (`queue_wait_s`, `replica`, and the dict of
+#: `prefill_s` / `first_hold_s` / `prefix_reused_tokens`): wrappers that
+#: hand the client another future (supervisor, remote) copy exactly these.
+FUTURE_STAMPS = ("_lsot_queue_wait", "_lsot_replica", "_lsot_waits")
 
 
 @dataclasses.dataclass
@@ -110,6 +175,21 @@ class RequestMetrics:
     # Queue wait (submit -> slot admission) on the scheduler path: the
     # share of latency that is BACKLOG, not compute. 0.0 = not measured.
     queue_wait_s: float = 0.0
+    # The rest of the time to the first token, on the scheduler's clock
+    # (0.0 = not measured): admission -> prompt ready, and ready -> the
+    # first token handed to the stream (it rides the harvest of a decode
+    # round). queue_wait_s + prefill_s + first_hold_s is the worker-side
+    # TTFT. `prefix_reused_tokens`: prompt tokens the prefix cache spared
+    # the prefill.
+    prefill_s: float = 0.0
+    first_hold_s: float = 0.0
+    prefix_reused_tokens: int = 0
+    # A streamed request's way out: per token, the worker's emit -> the
+    # piece leaving the stream generator (90th percentile); the sums of
+    # its `stream.detok` and `http.chunk` spans (StageTimer).
+    stream_lag_p90_s: float = 0.0
+    detok_s: float = 0.0
+    chunk_s: float = 0.0
     # Request class for the histogram label set: "" (plain), or any of
     # "constrained"/"speculative"/"constrained+speculative" — the classes
     # whose latency profiles an operator prices separately.
@@ -145,6 +225,14 @@ class RequestMetrics:
             out["ttft_s"] = round(self.ttft_s, 4)
         if self.queue_wait_s:
             out["queue_wait_s"] = round(self.queue_wait_s, 4)
+        if self.first_hold_s:
+            out["prefill_s"] = round(self.prefill_s, 6)
+            out["first_hold_s"] = round(self.first_hold_s, 6)
+            out["prefix_reused_tokens"] = self.prefix_reused_tokens
+        if self.stream_lag_p90_s:
+            out["stream_lag_p90_s"] = round(self.stream_lag_p90_s, 6)
+            out["detok_s"] = round(self.detok_s, 6)
+            out["chunk_s"] = round(self.chunk_s, 6)
         if self.rclass:
             out["class"] = self.rclass
         if self.replica:
@@ -402,20 +490,3 @@ resilience = CounterSet()
 #: GenerationService.metrics_snapshot and rendered as the lsot_repair_*
 #: Prometheus families.
 repair = CounterSet()
-
-
-@contextlib.contextmanager
-def trace_capture(name: str = "lsot") -> Iterator[None]:
-    """jax.profiler trace of the enclosed region when LSOT_TRACE_DIR is set.
-
-    The resulting directory loads in TensorBoard/XProf and shows XLA op
-    timelines on the TPU — the profiling story SURVEY.md §5 requires.
-    """
-    trace_dir = os.environ.get("LSOT_TRACE_DIR")
-    if not trace_dir:
-        yield
-        return
-    import jax
-
-    with jax.profiler.trace(os.path.join(trace_dir, name)):
-        yield

@@ -189,13 +189,30 @@ def capture_owner() -> Optional[str]:
         return _capture_owner
 
 
+def profile_options():
+    """`jax.profiler.ProfileOptions` of an on-demand capture: the Python
+    tracer off. It hooks every call of the serving loop's thread, which
+    slows the loop it is meant to observe and fills the artifact with its
+    frames; the host's account in the trace is the `sched.*` / `stream.*`
+    / `http.*` spans (`utils/observability.StageTimer`)."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return opts
+
+
 def find_profile_artifacts(trace_dir: str) -> List[str]:
-    """The Perfetto-loadable artifacts a jax.profiler capture wrote under
-    `trace_dir` (the same *.trace.json.gz files `Trace.load_dir` parses
-    and scripts/obs_smoke.sh asserts non-empty)."""
-    return sorted(glob.glob(
-        os.path.join(trace_dir, "**", "*.trace.json.gz"), recursive=True
-    ))
+    """What a jax.profiler capture wrote under `trace_dir`: the
+    Perfetto-loadable `*.trace.json.gz` (`Trace.load_dir` parses them,
+    scripts/obs_smoke.sh asserts them non-empty) and the `*.xplane.pb`
+    they were made from, which `jax.profiler.ProfileData` and the
+    benchmark read."""
+    return sorted(
+        path for pattern in ("*.trace.json.gz", "*.xplane.pb")
+        for path in glob.glob(os.path.join(trace_dir, "**", pattern),
+                              recursive=True)
+    )
 
 
 @contextlib.contextmanager

@@ -32,6 +32,19 @@ the Chrome export): `service.generate`, `sched.queue_wait`,
 round, with accepted-token / speculation / grammar attrs),
 `stream.deliver`, `sql.load`, `sql.exec`, `sql.write_csv`.
 
+The same convention names the stages of the serving LOOP, which belong to
+no request and are not recorded here but by `utils/observability.StageTimer`
+— summed on the host's clock into every round's flight record and every
+streamed request's log record, and, while a `/debug/profile` capture runs,
+events on the `/host:CPU` plane of the device trace with their arguments:
+`sched.loop` (one pass, the profiler's step: in the trace only, it covers
+the others), `sched.upkeep`, `sched.admit`
+(`admitted`, `queued`), `sched.prefill_dispatch` (`rows`, `bucket`,
+`tokens`), `sched.issue_decode` / `sched.issue_mixed` (`round`,
+`occupancy`), `sched.harvest_wait` (`round`: the wait for the device),
+`sched.harvest` (`round`, `emitted`), `sched.idle` (the wait for work),
+and per stream `stream.detok` and `http.chunk` (`rid`).
+
 Everything is thread-safe: the HTTP thread and the scheduler worker
 thread append spans to one trace concurrently.
 """

@@ -216,7 +216,8 @@ def test_mixed_role_default_reproduces_today_bitforbit(tiny_model_module):
     assert out_a == out_b
     assert stats_a == stats_b
     strip = ("ts", "round_wall_s", "cadence_s", "mfu", "hbm_util",
-             "bound", "prefill_mfu", "prefill_hbm_util", "perf_ctx")
+             "bound", "prefill_mfu", "prefill_hbm_util", "perf_ctx",
+             "host_s", "harvest_wait_s", "idle_s")  # times, like the first
 
     def core(snap):
         return [{k: v for k, v in r.items() if k not in strip}

@@ -100,7 +100,7 @@ def test_metrics_endpoint():
 
 
 @pytest.mark.slow
-def test_device_trace_captures_real_op_time():
+def test_device_trace_reads_real_op_time():
     """traceprof parses jax.profiler's chrome trace into device-op time:
     a matmul loop's device_time_s must be positive, bounded by wall, and
     the hot op list non-empty."""
