@@ -25,8 +25,10 @@ import time
 import traceback
 
 
-def stream(host: str, port: int, body: dict, deadline_s: float) -> dict:
-    """POST /api/generate with stream=true; never raises."""
+def stream(host: str, port: int, body: dict, deadline_s: float,
+           on_first=None) -> dict:
+    """POST /api/generate with stream=true; never raises. `on_first` is
+    called when the first chunk has arrived."""
     rec = {"sent": time.time(), "chunk_t": [], "chunk_text": [],
            "status": None, "request_id": None, "error": None, "done": False}
     conn = None
@@ -58,6 +60,8 @@ def stream(host: str, port: int, body: dict, deadline_s: float) -> dict:
                 break
             rec["chunk_t"].append(now)
             rec["chunk_text"].append(msg.get("response", ""))
+            if on_first is not None and len(rec["chunk_t"]) == 1:
+                on_first()
             if now > end:
                 rec["error"] = "request deadline passed mid-stream"
                 break
@@ -96,12 +100,12 @@ def main() -> int:
     print(f"T0 {t0!r}", flush=True)
     t_end = t0 + seconds
 
-    def run_one(req: dict, due: float) -> None:
+    def run_one(req: dict, due: float, on_first=None) -> None:
         tag = dict(idx=req["idx"], due=due, max_new_tokens=req["max_new_tokens"])
         with lock:  # on record from the moment it is sent: never lost
             records[req["idx"]] = {**tag, "pending": True, "done": False,
                                    "error": "not finished when the drain ended"}
-        rec = stream(host, port, body_of(req), deadline)
+        rec = stream(host, port, body_of(req), deadline, on_first)
         rec.update(tag)
         with lock:
             records[req["idx"]] = rec
@@ -121,15 +125,32 @@ def main() -> int:
         for req in sched["requests"]:
             lanes.setdefault(req["client"], []).append(req)
 
-        def lane(reqs: list) -> None:
+        # Eight requests sent in one millisecond reach the server's queue in
+        # whatever order its handler threads finish, and the order decides
+        # which prompts share a prefill batch: the whole window then runs on
+        # one of several timelines, run by run (PERF.md section 6, PR 28's
+        # refusal). With `ramp_lane_gap_s` the first lane starts alone, and
+        # the others one by one, that far apart, once its first chunk has
+        # arrived: the server's loop is then paced by the device and has just
+        # harvested a round, so all of them wait in its queue, in the order
+        # they were sent, for its next pass. That is the edge a closed loop
+        # stands on at every turn of a lane.
+        gap = sched.get("ramp_lane_gap_s")
+        go = threading.Event()
+
+        def lane(reqs: list, nth: int) -> None:
+            if gap is not None and nth:
+                go.wait(lead_in)
+                time.sleep(nth * gap)
             for req in sorted(reqs, key=lambda r: r["order"]):
                 now = time.time()
                 if now >= t_end:
-                    return
-                run_one(req, now)  # closed loop: due when the last one ended
+                    break
+                run_one(req, now, go.set)  # closed loop: due when the last one ended
+            go.set()
 
-        for reqs in lanes.values():
-            th = threading.Thread(target=lane, args=(reqs,), daemon=True)
+        for nth, reqs in enumerate(lanes.values()):
+            th = threading.Thread(target=lane, args=(reqs, nth), daemon=True)
             th.start()
             threads.append(th)
         time.sleep(max(0.0, t_end - time.time()))

@@ -11,8 +11,8 @@ plain reference (`reference.py`), and prints one JSON line last.
 
 `--trace 0`: the line's metrics are the cell's end-to-end metrics.
 `--trace 1`: a device trace of a few rounds is taken inside the window
-(`/debug/profile`, which holds the serving loop while it starts and stops),
-and the line's metrics are the cell's per-layer metrics.
+(`/debug/profile`, beside the serving loop), and the line's metrics are the
+cell's per-layer metrics.
 
 Exit code: 0 whenever a result line was printed — a request that fails is
 counted in `failed`, never in the exit code. Non-zero, and no result line,
@@ -117,6 +117,26 @@ def end_to_end(cell, records: list, t0: float, t_end: float, seconds: float,
         if m["name"] in have and have[m["name"]] is not None:
             out[prefix + m["name"]] = {"value": have[m["name"]], "unit": m["unit"]}
     return out, len(due), len(due) - len(ok), ok, have
+
+
+def open_loop_count(records: list, t0: float, t_end: float,
+                    seconds: float) -> dict:
+    """What an open loop's count of tokens inside the window is made of. The
+    schedule offers the tokens asked of the requests due in the window; the
+    count adds the lead-in's backlog that arrives after the window opened and
+    leaves out what the window's own requests deliver after it closed. Below
+    the knee both spills shrink as the server gets faster, so there the count
+    says what was offered and how late, not what the server can do, and
+    `output_tok_s` is no metric of such a cell (`BENCHMARK.json`)."""
+    return {
+        "offered_tok_s": sum(r["max_new_tokens"] for r in records
+                             if t0 <= r["due"] < t_end) / seconds,
+        "lead_in_tokens_inside": sum(
+            1 for r in records if r["due"] < t0
+            for t in r.get("chunk_t", ()) if t0 <= t < t_end),
+        "window_tokens_after": sum(
+            1 for r in records if t0 <= r["due"] < t_end
+            for t in r.get("chunk_t", ()) if t >= t_end)}
 
 
 def within_limits(compared: dict) -> bool:
@@ -282,9 +302,8 @@ def main() -> int:
         t0, lambda: edges.__setitem__("t0", srv.get("/metrics")))),
         threading.Thread(target=at, daemon=True, args=(
             t_end, lambda: edges.__setitem__("t1", srv.get("/metrics"))))]
-    # The trace is armed late: starting and stopping it holds the serving
-    # loop for seconds, so the counts and host-clock readings of a traced
-    # run are taken over the part of the window before it.
+    # The trace is armed late, and the counts and host-clock readings of a
+    # traced run are taken over the part of the window before it.
     trace_at = t_end - float(cell.cell["trace"]["before_end_s"])
     if args.trace:
         timers.append(threading.Thread(target=at, daemon=True, args=(
@@ -353,6 +372,9 @@ def main() -> int:
     log(f"requests due in the window {attempted}, failed {failed}; "
         f"drain used {played['drain_used_s']:.1f} s; all end-to-end readings "
         f"{json.dumps(have)}")
+    if schedule["loop"] == "open":
+        log("the open loop's count of tokens: "
+            f"{json.dumps(open_loop_count(played['records'], t0, t_end, args.seconds))}")
     if failed:
         bad = [r for r in played["records"] if t0 <= r["due"] < t_end
                and not (r.get("done") and not r.get("error"))]
@@ -455,9 +477,9 @@ def main() -> int:
             traced = before[-int(cell.cell["trace"]["rounds"]):]
         with open(os.path.join(out_dir, "flight_traced.json"), "w") as f:
             json.dump(traced, f)
-        # Arming the trace holds the serving loop for seconds while an open
-        # loop's requests keep arriving, so the traced rounds run on the
-        # backlog of the hold, not at the cell's load: say how far apart.
+        # An open loop's load is not the same everywhere in its window, so
+        # the traced rounds need not run at the load of the window before
+        # them: say how far apart.
         for what, rs in (("the window before the trace", in_win),
                          ("the traced rounds", traced)):
             occ = [r["occupancy"] for r in rs if "occupancy" in r]

@@ -245,6 +245,7 @@ def build(cell: spec.Cell, seed: int, seconds: float) -> dict:
             "lead_in_s": float(mix["lead_in_s"]),
             "drain_s": float(mix["drain_s"]),
             "request_deadline_s": float(mix["request_deadline_s"]),
+            "ramp_lane_gap_s": mix.get("ramp_lane_gap_s"),
             "prewarm": prewarm, "requests": requests}
 
 
