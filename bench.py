@@ -1065,8 +1065,12 @@ def _bench_micro(device_kind: str = "") -> dict:
         jax.block_until_ready(out)
         return int((_t.perf_counter() - t0) / reps * 1e9)
 
-    kp = jnp.asarray(rng.normal(size=(pool_pages, kh, ps, h)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(pool_pages, kh, ps, h)), jnp.float32)
+    # Stacked [L, P, ...] pools, read and written at one layer like the
+    # serving path does.
+    kp_l = jnp.asarray(
+        rng.normal(size=(n_layers, pool_pages, kh, ps, h)), jnp.float32)
+    vp_l = jnp.asarray(
+        rng.normal(size=(n_layers, pool_pages, kh, ps, h)), jnp.float32)
     tab = jnp.asarray(
         np.stack([rng.permutation(pool_pages - 1)[:np_tab]
                   for _ in range(b)]), jnp.int32)
@@ -1082,19 +1086,16 @@ def _bench_micro(device_kind: str = "") -> dict:
                   "layers": n_layers},
         "paged_read": {
             "kernel_ns": ns_per_op(
-                ragged_paged_attention, q, kp, vp, tab, pos, None, kvl),
+                ragged_paged_attention, q, kp_l, vp_l, tab, pos, 0, None,
+                kvl),
             "xla_ns": ns_per_op(
-                jax.jit(lambda *a: paged_attention_reference(*a)),
-                q, kp, vp, tab, pos, None, kvl),
+                jax.jit(lambda q_, k_, v_, *a: paged_attention_reference(
+                    q_, k_[0], v_[0], *a)),
+                q, kp_l, vp_l, tab, pos, None, kvl),
         },
     }
 
-    # Write side: one decode sliver per row through the table, stacked
-    # [L, P, ...] pools like the serving path writes them.
-    kp_l = jnp.asarray(
-        rng.normal(size=(n_layers, pool_pages, kh, ps, h)), jnp.float32)
-    vp_l = jnp.asarray(
-        rng.normal(size=(n_layers, pool_pages, kh, ps, h)), jnp.float32)
+    # Write side: one decode sliver per row through the table.
     knew = jnp.asarray(rng.normal(size=(b, 1, kh, h)), jnp.float32)
     vnew = jnp.asarray(rng.normal(size=(b, 1, kh, h)), jnp.float32)
 
@@ -1183,12 +1184,14 @@ def _bench_micro(device_kind: str = "") -> dict:
         kvd, td = kvm_d[n_pref:], tab[n_pref:]
 
         def per_phase(qp_, pp2, kvp_, tp_, qd_, pd_, kvd_, td_):
-            a = ragged_paged_attention(qp_, kp, vp, tp_, pp2, None, kvp_)
-            d = ragged_paged_attention(qd_, kp, vp, td_, pd_, None, kvd_)
+            a = ragged_paged_attention(qp_, kp_l, vp_l, tp_, pp2, 0, None,
+                                       kvp_)
+            d = ragged_paged_attention(qd_, kp_l, vp_l, td_, pd_, 0, None,
+                                       kvd_)
             return a, d
 
-        rag_ns = ns_per_op(ragged_paged_attention, qm, kp, vp, tab,
-                           posm_d, None, kvm_d, qlm_d)
+        rag_ns = ns_per_op(ragged_paged_attention, qm, kp_l, vp_l, tab,
+                           posm_d, 0, None, kvm_d, qlm_d)
         pp_ns = ns_per_op(per_phase, qp, pp_, kvp, tp, qd, pd, kvd, td)
         mixes_out.append({
             "prefill_rows": n_pref, "decode_rows": n_dec,

@@ -215,14 +215,14 @@ def check_kernels(seed: int, rehearse: bool) -> None:
         kv_lens = jnp.asarray(starts + t).at[-1].set(0)
         q = rnd((b, t, n, h))
         close(f"ragged_read_bf16_T{t}",
-              K.ragged_paged_attention(q, kp[1], vp[1], tab, pos, window,
+              K.ragged_paged_attention(q, kp, vp, tab, pos, 1, window,
                                        kv_lens, q_lens, interpret=interpret),
               K.paged_attention_reference(q, kp[1], vp[1], tab, pos, window,
                                           kv_lens, q_lens), atol)
         close(f"ragged_read_int8_T{t}",
               K.ragged_paged_attention_quantized(
-                  q, k8["q8"][1], k8["s"][1], v8["q8"][1], v8["s"][1], tab,
-                  pos, window, kv_lens, q_lens, interpret=interpret),
+                  q, k8["q8"], k8["s"], v8["q8"], v8["s"], tab,
+                  pos, 1, window, kv_lens, q_lens, interpret=interpret),
               K.paged_attention_reference_quantized(
                   q, k8["q8"][1], k8["s"][1], v8["q8"][1], v8["s"][1], tab,
                   pos, window, kv_lens, q_lens), atol)

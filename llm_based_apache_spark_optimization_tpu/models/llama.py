@@ -485,17 +485,22 @@ def forward(
                 x = post_attn(p, x, attn)
             elif paged_cache:
                 # Paged pool: write the sliver through the page table,
-                # then attend. The T=1 pallas path runs BOTH sides fused:
-                # the scatter-through-table write kernel (K+V in one
-                # launch, DMA slivers only — ops/pallas/paged_write) and
-                # the ragged-paged read kernel whose DMA index map does
-                # the gather; the xla/einsum path keeps the XLA reference
-                # scatter (bit-identical to the pre-kernel write) and the
-                # contiguous-view gather (any small T, e.g. verify
-                # windows). An int8 pool ({"kps","vps"} scale arrays)
-                # quantizes the fresh sliver on the way in — inside the
-                # write kernel on the pallas path — and dequantizes on
-                # the way out: in the read kernel's DMA'd tiles, or via
+                # then attend. The pallas path runs BOTH sides fused, and
+                # both on the STACKED pool at layer `l`, which leads
+                # their DMA index maps: the scatter-through-table
+                # write kernel (K+V in one launch, DMA slivers only —
+                # ops/pallas/paged_write) and the ragged-paged read kernel
+                # whose index map does the gather, so a step moves the
+                # touched and the live pages of each layer and never a
+                # layer's pool; the xla/einsum path keeps the XLA
+                # reference scatter (bit-identical to the pre-kernel
+                # write) and the contiguous-view gather of `pool[l]`,
+                # which XLA fuses into the gather (CPU, and windows over
+                # the kernel's row bound). An int8 pool ({"kps","vps"}
+                # scale arrays) quantizes the fresh sliver on the way in
+                # — inside the write kernel on the pallas path — and
+                # dequantizes on the way out: in the read kernel's DMA'd
+                # tiles, or via
                 # the int8-streaming einsum attention on the reference
                 # path. Under a mesh, writes stay on the XLA scatter
                 # (GSPMD partitions it over the pool's tp-sharded head
@@ -541,44 +546,31 @@ def forward(
                             new_cache["vp"], v, positions, ptab, l, q_lens)
                 if impl == "pallas":  # ragged windows (T·N bound validated
                                       # in the kernel wrapper)
+                    # Never `pool[l]` here: as an operand of a Mosaic
+                    # call it is a copy of the layer's pool.
+                    from ..ops.pallas import (
+                        ragged_paged_attention,
+                        ragged_paged_attention_quantized,
+                        sharded_ragged_paged_attention,
+                        sharded_ragged_paged_attention_quantized,
+                    )
+
                     if quant_paged:
-                        from ..ops.pallas import (
-                            ragged_paged_attention_quantized,
-                            sharded_ragged_paged_attention_quantized,
-                        )
-
-                        if mesh is not None:
-                            attn = sharded_ragged_paged_attention_quantized(
-                                mesh, q, new_cache["kp"][l],
-                                new_cache["kps"][l], new_cache["vp"][l],
-                                new_cache["vps"][l], ptab, positions,
-                                cfg.sliding_window, kv_lens, q_lens,
-                            )
-                        else:
-                            attn = ragged_paged_attention_quantized(
-                                q, new_cache["kp"][l], new_cache["kps"][l],
-                                new_cache["vp"][l], new_cache["vps"][l],
-                                ptab, positions, cfg.sliding_window,
-                                kv_lens, q_lens,
-                            )
+                        fn = (sharded_ragged_paged_attention_quantized
+                              if mesh is not None
+                              else ragged_paged_attention_quantized)
+                        pools = (new_cache["kp"], new_cache["kps"],
+                                 new_cache["vp"], new_cache["vps"])
                     else:
-                        from ..ops.pallas import (
-                            ragged_paged_attention,
-                            sharded_ragged_paged_attention,
-                        )
-
-                        if mesh is not None:
-                            attn = sharded_ragged_paged_attention(
-                                mesh, q, new_cache["kp"][l],
-                                new_cache["vp"][l], ptab, positions,
-                                cfg.sliding_window, kv_lens, q_lens,
-                            )
-                        else:
-                            attn = ragged_paged_attention(
-                                q, new_cache["kp"][l], new_cache["vp"][l],
-                                ptab, positions, cfg.sliding_window,
-                                kv_lens, q_lens,
-                            )
+                        fn = (sharded_ragged_paged_attention
+                              if mesh is not None
+                              else ragged_paged_attention)
+                        pools = (new_cache["kp"], new_cache["vp"])
+                    args = (mesh,) if mesh is not None else ()
+                    attn = fn(
+                        *args, q, *pools, ptab, positions, l,
+                        cfg.sliding_window, kv_lens, q_lens,
+                    )
                 elif quant_paged:
                     from ..ops.pallas import gather_page_scales, gather_pages
 

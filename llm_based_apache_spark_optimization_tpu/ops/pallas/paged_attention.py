@@ -1,10 +1,11 @@
 """Ragged paged attention over the shared KV page pool.
 
 The paged twin of `attention.py`'s K-folded flash decode kernel: K/V live in
-a shared pool `[P, K, page, H]` (engine/paged_kv.py) and each batch row owns
-a page TABLE `[NP]` mapping its logical pages to pool pages — the layout
-from "Ragged Paged Attention: A High-Performance and Flexible LLM Inference
-Kernel for TPU" (PAPERS.md) and vLLM's PagedAttention.
+a shared pool `[L, P, K, page, H]` stacked over the layers
+(engine/paged_kv.py) and each batch row owns a page TABLE `[NP]` mapping its
+logical pages to pool pages — the layout from "Ragged Paged Attention: A
+High-Performance and Flexible LLM Inference Kernel for TPU" (PAPERS.md) and
+vLLM's PagedAttention.
 
 Kernel design:
 
@@ -29,6 +30,17 @@ Kernel design:
   happens in the DMA engine's addressing, never as a materialized
   [B, NP*page, ...] copy (that copy is exactly what the XLA reference path
   below pays, and what this kernel exists to avoid).
+- The kernels take the STACKED pool and a `layer`, like
+  `paged_write.fused_page_write`: the layer is the leading coordinate of
+  the same index maps, so it too enters the DMA's addressing. A Mosaic
+  call cannot take a strided view of the `[L, P, K, page, H]` loop carry;
+  handed `pool[layer]`, XLA materializes the slice — the whole pool read
+  and written once a decode step, for K and for V. The layer rides
+  SCALAR PREFETCH beside the table, as a value and not as a static
+  argument: a forward pass's L calls then share one trace and one
+  lowered kernel. A static layer makes L of each, every time a program
+  is built, whether or not its executable is in the compile cache
+  (seconds of server start at 32 layers).
 - Ragged bounding: `kv_lens[b]` clamps the logical page index at the row's
   last live page — grid steps past it re-map the same pool page and Pallas
   elides the repeated DMA, so a row at position p streams
@@ -44,9 +56,10 @@ Kernel design:
 row's pages into a contiguous view, run the einsum attention) with the
 kernel's exact ragged contract (`q_lens` columns past a row's window
 return zeros): the golden in parity tests and the CPU/interpret fallback
-in `models/llama.forward`. The kernel serves any window with
-T·N <= `_MAX_QROWS` folded rows over all heads (the folded query block
-must stay VMEM-resident); larger windows take the reference.
+in `models/llama.forward`; it takes ONE layer's pool `[P, K, page, H]`
+(`pool[layer]`, which XLA fuses into its gather). The kernel serves any
+window with T·N <= `_MAX_QROWS` folded rows over all heads (the folded
+query block must stay VMEM-resident); larger windows take the reference.
 """
 
 from __future__ import annotations
@@ -87,6 +100,7 @@ def _make_paged_decode_kernel(dequant):
         kvlen_ref,  # [B] i32 SMEM (scalar prefetch) — live KV tokens/row
         qlen_ref,   # [B] i32 SMEM (scalar prefetch) — live query cols/row
         table_ref,  # [B, NP] i32 SMEM (scalar prefetch) — page tables
+        layer_ref,  # [1] i32 SMEM (scalar prefetch) — for the index maps
         qpos_ref,   # [1, 1, GT] i32
         q_ref,      # [1, K, GT, H]
         *rest,      # stream refs (pool tiles picked by the index map),
@@ -154,16 +168,21 @@ def _dequant_page_streams(refs, dt):
     runs on the VMEM tiles only (the contract ISSUE 11 names: dequantize
     inside the kernel's DMA'd tiles)."""
     k8, ks, v8, vs = refs
-    k = (k8[0].astype(jnp.float32) * ks[0].astype(jnp.float32)).astype(dt)
-    v = (v8[0].astype(jnp.float32) * vs[0].astype(jnp.float32)).astype(dt)
+    k = (k8[0].astype(jnp.float32)
+         * ks[0].astype(jnp.float32)[..., None]).astype(dt)
+    v = (v8[0].astype(jnp.float32)
+         * vs[0].astype(jnp.float32)[..., None]).astype(dt)
     return k, v
 
 
-# int8 pool: streams are (k8 [1,K,PS,H], ks [1,K,PS,1], v8, vs).
+# int8 pool: streams are (k8 [1,K,PS,H], ks [1,K,PS], v8, vs). The scale
+# tiles get their trailing axis here, in VMEM: a `[..., None]` outside the
+# kernel is a copy of the scale pool (its one-wide minor axis padded to
+# the 128 lanes), and on the stacked pool it would be one a layer.
 _paged_decode_kernel_q8 = _make_paged_decode_kernel(_dequant_page_streams)
 
 
-def _run_paged_grid(kernel, q, streams, page_table, q_positions,
+def _run_paged_grid(kernel, q, streams, layer, page_table, q_positions,
                     sliding_window, kv_lens, q_lens, interpret):
     """The ragged paged pipeline shared by the bf16 and int8 kernels:
     grid (B, NP) with the page table in SCALAR PREFETCH — every stream's
@@ -173,10 +192,13 @@ def _run_paged_grid(kernel, q, streams, page_table, q_positions,
     into the GQA group axis (GT = G·T — identity at T=1, the decode
     layout), and per-row `q_lens` ride prefetch so dead window columns
     zero out in-kernel. `streams` is a list of
-    (array [P, K, PS, ...tail], tail_block_shape) pairs — (h,) for K/V
-    value pools, (1,) for per-position scale columns."""
+    (array [L, P, K, PS, ...tail], tail_block_shape) pairs — (h,) for K/V
+    value pools, () for per-position scale pools. A block is one page
+    of `layer` (prefetched too, clipped to the stack): the layer axis is
+    squeezed out of the block, so the kernel bodies see
+    `[1, K, PS, ...tail]` tiles whatever the depth of the stack."""
     b, t, n, h = q.shape
-    num_pages, kh, ps = streams[0][0].shape[:3]
+    num_layers, num_pages, kh, ps = streams[0][0].shape[:4]
     g = n // kh
     gt = g * t
     np_tab = page_table.shape[1]
@@ -189,6 +211,7 @@ def _run_paged_grid(kernel, q, streams, page_table, q_positions,
         q_lens = jnp.full((b,), t, jnp.int32)
     q_lens = jnp.clip(q_lens.astype(jnp.int32), 0, t)
     table = jnp.clip(page_table.astype(jnp.int32), 0, num_pages - 1)
+    layer = jnp.clip(jnp.asarray(layer, jnp.int32), 0, num_layers - 1)
 
     # [B, T, N, H] -> [B, K, G·T, H]: fold the window axis under the GQA
     # group axis so folded row r = gi*t + ti (identity at T=1 — the
@@ -200,28 +223,27 @@ def _run_paged_grid(kernel, q, streams, page_table, q_positions,
     )
     qpos = jnp.tile(q_positions.astype(jnp.int32), (1, g))[:, None, :]
 
-    def kv_map(bi, i, kvl, ql, tab):
-        # Clamp at the row's last LIVE logical page, then translate through
-        # its table: steps past the live region re-map the same pool page
-        # and the DMA is elided — the bandwidth saving, not just a compute
-        # skip.
-        last = jnp.maximum((kvl[bi] + ps - 1) // ps - 1, 0)
-        return (tab[bi, jnp.minimum(i, last)], 0, 0, 0)
+    def page_spec(tail):
+        def kv_map(bi, i, kvl, ql, tab, lay):
+            # Clamp at the row's last LIVE logical page, then translate
+            # through its table: steps past the live region re-map the same
+            # pool page and the DMA is elided — the bandwidth saving, not
+            # just a compute skip. The layer leads the coordinates.
+            last = jnp.maximum((kvl[bi] + ps - 1) // ps - 1, 0)
+            return (lay[0], tab[bi, jnp.minimum(i, last)], 0, 0) + (
+                0,) * len(tail)
+
+        return pl.BlockSpec((None, 1, kh, ps) + tail, kv_map)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b, np_tab),
         in_specs=[
-            pl.BlockSpec((1, 1, gt), lambda bi, i, kvl, ql, tab: (bi, 0, 0)),
-            pl.BlockSpec(
-                (1, kh, gt, h), lambda bi, i, kvl, ql, tab: (bi, 0, 0, 0)
-            ),
-        ] + [
-            pl.BlockSpec((1, kh, ps) + tail, kv_map)
-            for _, tail in streams
-        ],
+            pl.BlockSpec((1, 1, gt), lambda bi, i, *_: (bi, 0, 0)),
+            pl.BlockSpec((1, kh, gt, h), lambda bi, i, *_: (bi, 0, 0, 0)),
+        ] + [page_spec(tail) for _, tail in streams],
         out_specs=pl.BlockSpec(
-            (1, kh, gt, h), lambda bi, i, kvl, ql, tab: (bi, 0, 0, 0)
+            (1, kh, gt, h), lambda bi, i, *_: (bi, 0, 0, 0)
         ),
         scratch_shapes=[
             pltpu.VMEM((kh, gt, _LANES), jnp.float32),
@@ -242,7 +264,8 @@ def _run_paged_grid(kernel, q, streams, page_table, q_positions,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(kv_lens, q_lens, table, qpos, q5, *[arr for arr, _ in streams])
+    )(kv_lens, q_lens, table, layer.reshape(1), qpos, q5,
+      *[arr for arr, _ in streams])
     return out.reshape(b, kh, g, t, h).transpose(0, 3, 1, 2, 4).reshape(
         b, t, n, h
     )
@@ -276,28 +299,32 @@ def _validate_window(q, page_size, interpret, *, quantized=False):
 )
 def ragged_paged_attention(
     q: jnp.ndarray,            # [B, T, N, H] — ragged query windows
-    k_pool: jnp.ndarray,       # [P, K, PS, H] — one layer's page pool
-    v_pool: jnp.ndarray,       # [P, K, PS, H]
+    k_pool: jnp.ndarray,       # [L, P, K, PS, H] — the stacked page pool
+    v_pool: jnp.ndarray,       # [L, P, K, PS, H]
     page_table: jnp.ndarray,   # [B, NP] i32 — pool page per logical page
     q_positions: jnp.ndarray,  # [B, T] i32
+    layer,                     # i32 scalar: which layer of the stack
     sliding_window: Optional[int] = None,
     kv_lens: Optional[jnp.ndarray] = None,  # [B] i32 — live tokens per row
     q_lens: Optional[jnp.ndarray] = None,   # [B] i32 — live query cols/row
     *,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    """Ragged flash attention reading K/V through per-row page tables.
+    """Ragged flash attention reading K/V of one layer of the stacked pool
+    through per-row page tables.
 
     Returns [B, T, N, H] in q's dtype. Output depends only on the first
     `kv_lens[b]` logical positions of each row (defaults to max(position)+1;
     kv_lens=0 parks a row — zero output, one elided-DMA sweep) and the
     first `q_lens[b]` window columns (defaults to T; columns past a row's
     q_len return exact zeros). One launch therefore serves T=1 decode
-    rows, speculative verify windows, and prefill chunks together."""
-    interpret = _validate_window(q, k_pool.shape[2], interpret)
+    rows, speculative verify windows, and prefill chunks together. The
+    pool is read in place: HBM traffic is the live pages of `layer` alone,
+    whatever the depth of the stack."""
+    interpret = _validate_window(q, k_pool.shape[3], interpret)
     h = q.shape[3]
     return _run_paged_grid(
-        _paged_decode_kernel, q, [(k_pool, (h,)), (v_pool, (h,))],
+        _paged_decode_kernel, q, [(k_pool, (h,)), (v_pool, (h,))], layer,
         page_table, q_positions, sliding_window, kv_lens, q_lens, interpret,
     )
 
@@ -307,12 +334,13 @@ def ragged_paged_attention(
 )
 def ragged_paged_attention_quantized(
     q: jnp.ndarray,            # [B, T, N, H] — ragged query windows
-    k_pool: jnp.ndarray,       # [P, K, PS, H] int8 — one layer's page pool
-    k_scale: jnp.ndarray,      # [P, K, PS] f32 — per-position K scales
-    v_pool: jnp.ndarray,       # [P, K, PS, H] int8
-    v_scale: jnp.ndarray,      # [P, K, PS] f32
+    k_pool: jnp.ndarray,       # [L, P, K, PS, H] int8 — the stacked pool
+    k_scale: jnp.ndarray,      # [L, P, K, PS] f32 — per-position K scales
+    v_pool: jnp.ndarray,       # [L, P, K, PS, H] int8
+    v_scale: jnp.ndarray,      # [L, P, K, PS] f32
     page_table: jnp.ndarray,   # [B, NP] i32
     q_positions: jnp.ndarray,  # [B, T] i32
+    layer,                     # i32 scalar: which layer of the stack
     sliding_window: Optional[int] = None,
     kv_lens: Optional[jnp.ndarray] = None,  # [B] i32
     q_lens: Optional[jnp.ndarray] = None,   # [B] i32
@@ -326,21 +354,20 @@ def ragged_paged_attention_quantized(
     bounding stacked, the paged twin of
     `attention.flash_gqa_attention_quantized`."""
     interpret = _validate_window(
-        q, k_pool.shape[2], interpret, quantized=True
+        q, k_pool.shape[3], interpret, quantized=True
     )
     h = q.shape[3]
-    ks4 = k_scale.astype(jnp.float32)[..., None]  # [P, K, PS, 1]
-    vs4 = v_scale.astype(jnp.float32)[..., None]
     return _run_paged_grid(
         _paged_decode_kernel_q8, q,
-        [(k_pool, (h,)), (ks4, (1,)), (v_pool, (h,)), (vs4, (1,))],
-        page_table, q_positions, sliding_window, kv_lens, q_lens, interpret,
+        [(k_pool, (h,)), (k_scale, ()), (v_pool, (h,)), (v_scale, ())],
+        layer, page_table, q_positions, sliding_window, kv_lens, q_lens,
+        interpret,
     )
 
 
 def sharded_ragged_paged_attention(
     mesh,
-    q, k_pool, v_pool, page_table, q_positions,
+    q, k_pool, v_pool, page_table, q_positions, layer,
     sliding_window: Optional[int] = None,
     kv_lens: Optional[jnp.ndarray] = None,
     q_lens: Optional[jnp.ndarray] = None,
@@ -348,13 +375,13 @@ def sharded_ragged_paged_attention(
     interpret: Optional[bool] = None,
 ):
     """`ragged_paged_attention` under a tp mesh via `jax.shard_map`: the
-    pool shards its KV-HEAD axis over tp (parallel/sharding — every page
-    holds all heads, each device holds its heads' slice of every page),
-    page tables, positions, and per-row lengths replicate, and the
-    per-device body is the single-device kernel on local shapes — no
-    collective inside, exactly like
-    `attention.sharded_flash_gqa_attention`. The batch axis rides "dp"
-    (dp=1 for the scheduler, whose slot axis never shards)."""
+    stacked pool shards its KV-HEAD axis over tp (parallel/sharding —
+    every page holds all heads, each device holds its heads' slice of
+    every page of every layer), page tables, positions, the layer and
+    per-row lengths replicate, and the per-device body is the
+    single-device kernel on local shapes — no collective inside, exactly
+    like `attention.sharded_flash_gqa_attention`. The batch axis rides
+    "dp" (dp=1 for the scheduler, whose slot axis never shards)."""
     from jax.sharding import PartitionSpec as P
 
     body = functools.partial(
@@ -365,22 +392,24 @@ def sharded_ragged_paged_attention(
         kv_lens = jnp.max(q_positions.astype(jnp.int32), axis=1) + 1
     if q_lens is None:
         q_lens = jnp.full((q.shape[0],), q.shape[1], jnp.int32)
+    pool = P(None, None, "tp", None, None)
     return jax.shard_map(
-        lambda q_, k_, v_, t_, p_, l_, w_: body(
-            q_, k_, v_, t_, p_, kv_lens=l_, q_lens=w_
+        lambda q_, k_, v_, t_, p_, y_, l_, w_: body(
+            q_, k_, v_, t_, p_, y_, kv_lens=l_, q_lens=w_
         ),
         mesh=mesh,
-        in_specs=(P("dp", None, "tp", None), P(None, "tp", None, None),
-                  P(None, "tp", None, None), P("dp", None), P("dp", None),
-                  P("dp"), P("dp")),
+        in_specs=(P("dp", None, "tp", None), pool, pool,
+                  P("dp", None), P("dp", None), P(), P("dp"), P("dp")),
         out_specs=P("dp", None, "tp", None),
         check_vma=False,
-    )(q, k_pool, v_pool, page_table, q_positions, kv_lens, q_lens)
+    )(q, k_pool, v_pool, page_table, q_positions,
+      jnp.asarray(layer, jnp.int32), kv_lens, q_lens)
 
 
 def sharded_ragged_paged_attention_quantized(
     mesh,
     q, k_pool, k_scale, v_pool, v_scale, page_table, q_positions,
+    layer,
     sliding_window: Optional[int] = None,
     kv_lens: Optional[jnp.ndarray] = None,
     q_lens: Optional[jnp.ndarray] = None,
@@ -399,30 +428,32 @@ def sharded_ragged_paged_attention_quantized(
         kv_lens = jnp.max(q_positions.astype(jnp.int32), axis=1) + 1
     if q_lens is None:
         q_lens = jnp.full((q.shape[0],), q.shape[1], jnp.int32)
+    pool, scales = P(None, None, "tp", None, None), P(None, None, "tp", None)
     return jax.shard_map(
-        lambda q_, k_, ks_, v_, vs_, t_, p_, l_, w_: body(
-            q_, k_, ks_, v_, vs_, t_, p_, kv_lens=l_, q_lens=w_
+        lambda q_, k_, ks_, v_, vs_, t_, p_, y_, l_, w_: body(
+            q_, k_, ks_, v_, vs_, t_, p_, y_, kv_lens=l_, q_lens=w_
         ),
         mesh=mesh,
-        in_specs=(P("dp", None, "tp", None), P(None, "tp", None, None),
-                  P(None, "tp", None), P(None, "tp", None, None),
-                  P(None, "tp", None), P("dp", None), P("dp", None),
-                  P("dp"), P("dp")),
+        in_specs=(P("dp", None, "tp", None), pool, scales, pool, scales,
+                  P("dp", None), P("dp", None), P(), P("dp"), P("dp")),
         out_specs=P("dp", None, "tp", None),
         check_vma=False,
     )(q, k_pool, k_scale, v_pool, v_scale, page_table, q_positions,
-      kv_lens, q_lens)
+      jnp.asarray(layer, jnp.int32), kv_lens, q_lens)
 
 
 def gather_pages(
-    pool: jnp.ndarray,        # [P, K, PS, H] — one layer's page pool
+    pool: jnp.ndarray,        # [P, K, PS, H] — one layer of the stacked
+                              # pool (`pool[l]`: XLA fuses the slice
+                              # into the gather, so no layer is copied)
     page_table: jnp.ndarray,  # [B, NP] i32
 ) -> jnp.ndarray:
     """Materialize per-row contiguous K or V views [B, K, NP*PS, H] by
     gathering pool pages through the table (unmapped sentinel entries clip
     to a real page; their garbage sits at causally masked positions). This
     COPY is what the Pallas kernel's DMA-level gather avoids — it exists
-    for the reference path, T>1 verify windows, and prefill row views."""
+    for the reference path, windows over the kernel's row bound, and
+    prefill row views."""
     num_pages, kh, ps, h = pool.shape
     b, np_tab = page_table.shape
     safe = jnp.clip(page_table.astype(jnp.int32), 0, num_pages - 1)
@@ -432,6 +463,7 @@ def gather_pages(
 
 def gather_page_scales(
     pool_s: jnp.ndarray,      # [P, K, PS] — one layer's per-position scales
+                              # (`scales[l]` of the stack, as above)
     page_table: jnp.ndarray,  # [B, NP] i32
 ) -> jnp.ndarray:
     """Materialize per-row contiguous scale views [B, K, NP*PS] by

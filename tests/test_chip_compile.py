@@ -15,8 +15,10 @@ Also here: the tier-1 rehearsal of `chip_smoke.py` itself, and the compile-
 cache helper every entry point shares.
 """
 
+import dataclasses
 import json
 import os
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -27,9 +29,14 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from llm_based_apache_spark_optimization_tpu.models import init_params
 from llm_based_apache_spark_optimization_tpu.models.configs import MISTRAL_7B
+from llm_based_apache_spark_optimization_tpu.models.llama import forward
 from llm_based_apache_spark_optimization_tpu.ops import pallas as K
-from llm_based_apache_spark_optimization_tpu.ops.pallas import paged_attention
+from llm_based_apache_spark_optimization_tpu.ops.pallas import (
+    dispatch,
+    paged_attention,
+)
 from llm_based_apache_spark_optimization_tpu.ops.pallas.int4mm import int4_matmul
 from llm_based_apache_spark_optimization_tpu.utils import jaxenv
 
@@ -66,18 +73,19 @@ def chip():
 
 
 def _read(t, quantized):
-    """Ragged paged read over one layer's pool, window of T query rows."""
+    """Ragged paged read of one layer of the stacked pool, window of T
+    query rows."""
     def build(S):
         q, tab, pos = S((B, t, N, H), BF), S((B, NP), I32), S((B, t), I32)
         if not quantized:
-            pool = S((P, KH, PS, H), BF)
+            pool = S((L, P, KH, PS, H), BF)
             return (lambda q, k, v, tab, pos: K.ragged_paged_attention(
-                q, k, v, tab, pos, WINDOW, interpret=False),
+                q, k, v, tab, pos, 1, WINDOW, interpret=False),
                 (q, pool, pool, tab, pos))
-        pool, scale = S((P, KH, PS, H), I8), S((P, KH, PS), F32)
+        pool, scale = S((L, P, KH, PS, H), I8), S((L, P, KH, PS), F32)
         return (lambda q, k, ks, v, vs, tab, pos:
                 K.ragged_paged_attention_quantized(
-                    q, k, ks, v, vs, tab, pos, WINDOW, interpret=False),
+                    q, k, ks, v, vs, tab, pos, 1, WINDOW, interpret=False),
                 (q, pool, scale, pool, scale, tab, pos))
     return build
 
@@ -156,16 +164,55 @@ def test_read_window_bound_is_the_compilers(chip):
     and twice the bound is refused by the wrapper, not by Mosaic."""
     t = paged_attention._MAX_QROWS // N
     q, tab, pos = chip((B, t, N, H), BF), chip((B, NP), I32), chip((B, t), I32)
-    pool, scale = chip((P, N, PS, H), I8), chip((P, N, PS), F32)  # MHA
+    pool, scale = chip((L, P, N, PS, H), I8), chip((L, P, N, PS), F32)  # MHA
     jax.jit(lambda q, k, ks, v, vs, tab, pos:
             K.ragged_paged_attention_quantized(
-                q, k, ks, v, vs, tab, pos, None, interpret=False)
+                q, k, ks, v, vs, tab, pos, 1, None, interpret=False)
             ).lower(q, pool, scale, pool, scale, tab, pos).compile()
     with pytest.raises(ValueError, match="folded rows"):
         K.ragged_paged_attention(
-            jnp.zeros((1, 2 * t, N, H), BF), jnp.zeros((4, KH, PS, H), BF),
-            jnp.zeros((4, KH, PS, H), BF), jnp.zeros((1, 2), I32),
-            jnp.zeros((1, 2 * t), I32))
+            jnp.zeros((1, 2 * t, N, H), BF), jnp.zeros((1, 4, KH, PS, H), BF),
+            jnp.zeros((1, 4, KH, PS, H), BF), jnp.zeros((1, 2), I32),
+            jnp.zeros((1, 2 * t), I32), 0)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_decode_step_reads_the_pool_in_place(chip, monkeypatch, quantized):
+    """The decode path of `forward` over an L=2 paged cache at Mistral
+    widths: both sides lower through Mosaic, and the program makes no copy
+    of a layer's pool. A Mosaic call cannot take a strided view of the
+    stacked loop carry, so a read kernel handed `pool[l]` made XLA
+    materialize the slice, every layer of every step (a quarter to a half
+    of a decode step's device time on the chip). The pool is sized like a
+    served one in this: far too large for the compiler to stage in fast
+    memory (at 128 pages it does, in slices of a layer), and a layer's K or
+    V by far the largest buffer the program could make. So both the text
+    (no result of that shape) and the temporaries (under one such buffer)
+    say whether a layer is copied."""
+    pages = 1024
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)  # compiled kernels
+    cfg = dataclasses.replace(MISTRAL_7B, num_layers=L)
+    params = jax.tree.map(
+        lambda a: chip(a.shape, a.dtype),
+        jax.eval_shape(lambda: init_params(cfg, jax.random.key(0), dtype=BF)))
+    pool = chip((L, pages, KH, PS, H), I8 if quantized else BF)
+    cache = {"kp": pool, "vp": pool, "ptab": chip((B, NP), I32)}
+    if quantized:
+        cache["kps"] = cache["vps"] = chip((L, pages, KH, PS), F32)
+
+    def step(params, cache, tokens, positions, kv_lens):
+        return forward(cfg, params, tokens, positions, cache,
+                       attn_impl="pallas", kv_lens=kv_lens)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, chip((B, 1), I32), chip((B, 1), I32), chip((B,), I32)
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 * L
+    one_layer = re.compile(rf"= \w+\[(1,)?{pages},{KH},{PS},{H}\]")
+    assert not [ln[:160] for ln in text.splitlines() if one_layer.search(ln)]
+    layer_bytes = pages * KH * PS * H * pool.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes / 2
 
 
 def test_chip_smoke_rehearsal():

@@ -198,7 +198,8 @@ def test_ragged_paged_kernel_matches_reference(rng, ps, np_tab):
     pos = jnp.asarray([[ps // 2], [s_virt - ps - 1], [s_virt - 1]], jnp.int32)
     kvl = pos[:, 0] + 1
 
-    out_k = ragged_paged_attention(q, kp, vp, tab, pos, None, kvl)
+    out_k = ragged_paged_attention(q, kp[None], vp[None], tab, pos, 0, None,
+                                   kvl)
     out_r = paged_attention_reference(q, kp, vp, tab, pos, None, kvl)
     np.testing.assert_allclose(out_k, out_r, atol=2e-6)
 
@@ -224,7 +225,8 @@ def test_ragged_paged_kernel_kv_lens_truncates_and_parks(rng):
     q = jnp.asarray(rng.normal(size=(b, 1, n, h)), jnp.float32)
     pos = jnp.asarray([[10], [10]], jnp.int32)
     kvl = jnp.asarray([11, 11], jnp.int32)
-    base = ragged_paged_attention(q, kp, vp, tab, pos, None, kvl)
+    base = ragged_paged_attention(q, kp[None], vp[None], tab, pos, 0, None,
+                                  kvl)
     # Scribble every position >= kv_lens: the wholly-dead logical pages 2-3
     # of both rows, and the in-page tail of logical page 1 (kv_lens=11 ->
     # offsets 3+ of positions 8..15 are past the live region). Output must
@@ -238,10 +240,12 @@ def test_ragged_paged_kernel_kv_lens_truncates_and_parks(rng):
         pg = int(tab[b_, 1])
         kp2 = kp2.at[pg, :, 3:].set(99.0)
         vp2 = vp2.at[pg, :, 3:].set(-99.0)
-    out = ragged_paged_attention(q, kp2, vp2, tab, pos, None, kvl)
+    out = ragged_paged_attention(q, kp2[None], vp2[None], tab, pos, 0, None,
+                                 kvl)
     np.testing.assert_array_equal(np.asarray(base), np.asarray(out))
     parked = ragged_paged_attention(
-        q, kp, vp, tab, pos, None, jnp.asarray([0, 11], jnp.int32)
+        q, kp[None], vp[None], tab, pos, 0, None,
+        jnp.asarray([0, 11], jnp.int32)
     )
     assert float(jnp.abs(parked[0]).max()) == 0.0
     np.testing.assert_array_equal(np.asarray(parked[1]), np.asarray(base[1]))
@@ -1014,15 +1018,15 @@ def test_quantized_ragged_kernel_matches_reference(rng, ps, np_tab):
     pos = jnp.asarray([[ps // 2], [s_virt - ps - 1], [s_virt - 1]],
                       jnp.int32)
     kvl = pos[:, 0] + 1
-    out_k = ragged_paged_attention_quantized(q, kp, ks, vp, vs, tab, pos,
-                                             None, kvl)
+    out_k = ragged_paged_attention_quantized(
+        q, kp[None], ks[None], vp[None], vs[None], tab, pos, 0, None, kvl)
     out_r = paged_attention_reference_quantized(q, kp, ks, vp, vs, tab,
                                                 pos, None, kvl)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                atol=2e-5)
     # kv_lens=0 parks a row, like the bf16 kernel.
     parked = ragged_paged_attention_quantized(
-        q, kp, ks, vp, vs, tab, pos, None,
+        q, kp[None], ks[None], vp[None], vs[None], tab, pos, 0, None,
         jnp.asarray([0] + [int(x) for x in kvl[1:]], jnp.int32),
     )
     assert float(jnp.abs(parked[0]).max()) == 0.0
@@ -1102,7 +1106,8 @@ def test_ragged_window_shapes_property(rng, quant):
 
         if quant:
             out_k = ragged_paged_attention_quantized(
-                q, kp, ks, vp, vs, tab, pos, None, kvl_j, q_lens_j
+                q, kp[None], ks[None], vp[None], vs[None], tab, pos, 0,
+                None, kvl_j, q_lens_j
             )
             out_r = paged_attention_reference_quantized(
                 q, kp, ks, vp, vs, tab, pos, None, kvl_j, q_lens_j
@@ -1110,7 +1115,7 @@ def test_ragged_window_shapes_property(rng, quant):
             atol = 2e-5
         else:
             out_k = ragged_paged_attention(
-                q, kp, vp, tab, pos, None, kvl_j, q_lens_j
+                q, kp[None], vp[None], tab, pos, 0, None, kvl_j, q_lens_j
             )
             out_r = paged_attention_reference(
                 q, kp, vp, tab, pos, None, kvl_j, q_lens_j
@@ -1142,6 +1147,146 @@ def test_ragged_window_shapes_property(rng, quant):
             assert float(jnp.abs(out_r[bi, ql:]).max() if ql < T
                          else 0.0) == 0.0
         assert float(jnp.abs(out_k[4]).max()) == 0.0
+
+
+# The read kernels take the STACKED pool and a layer (the write kernels'
+# interface). The one-layer form they replaced is gone, so what it gave on
+# `pool[layer]` is kept below as a golden of four values a case.
+
+_STACK_L = 3
+_STACK_HEADS = {"gqa32x8_h128": (32, 8, 128), "mha32_h64": (32, 32, 64)}
+_STACK_PROBES = ((0, 0, 0, 0), (1, 0, 5, 3), (3, 0, 17, 11), (3, -1, -1, -1))
+
+
+def _stack_case(heads, quant, ragged):
+    """An L=3 stack whose layers hold different values, four rows of mixed
+    age behind shuffled page tables: a young row, a row ending mid-page, a
+    PARKED row (kv_lens = 0) and a row on its last page. `ragged` gives the
+    rows windows of 1..T query columns (`q_lens`); else T = 1."""
+    n, kh, h = _STACK_HEADS[heads]
+    rng = np.random.default_rng(29)
+    b, ps, np_tab, pool_pages, t = 4, 8, 4, 9, (5 if ragged else 1)
+    shape = (_STACK_L, pool_pages, kh, ps, h)
+    if quant:
+        pools = tuple(
+            jnp.asarray(a) for _ in range(2) for a in (
+                rng.integers(-127, 128, size=shape).astype(np.int8),
+                rng.uniform(0.002, 0.02, size=shape[:-1]).astype(np.float32)))
+    else:
+        pools = tuple(jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+                      for _ in range(2))
+    tab = np.stack([rng.permutation(pool_pages)[:np_tab] for _ in range(b)])
+    tab[0, -1] = pool_pages  # unmapped sentinel past row 0's live region
+    q_lens = np.asarray([1, 3, 2, t] if ragged else [1] * b, np.int32)
+    kvl = np.asarray([ps // 2 + 1, 2 * ps + 3, 0, np_tab * ps], np.int32)
+    pos = np.full((b, t), np_tab * ps - 1, np.int32)  # dead-column junk
+    for bi in range(b):
+        top = max(int(kvl[bi]), int(q_lens[bi]))
+        pos[bi, :q_lens[bi]] = top - q_lens[bi] + np.arange(q_lens[bi])
+    q = jnp.asarray(rng.normal(size=(b, t, n, h)), jnp.bfloat16)
+    return (q, pools, jnp.asarray(tab, jnp.int32), jnp.asarray(pos),
+            jnp.asarray(kvl), jnp.asarray(q_lens))
+
+
+# What the parent's one-layer kernel gave on `pool[layer]` at _STACK_PROBES
+# (interpret mode, this CPU); the whole outputs were compared bit for bit
+# when the form changed. Key: (heads, quant, ragged, layer).
+_STACK_GOLDEN = {
+    ('gqa32x8_h128', False, False, 0):
+        [-0.10205078125, 0.361328125, 0.046875, -0.1201171875],
+    ('gqa32x8_h128', False, False, 2):
+        [0.18359375, 0.921875, -0.1416015625, -0.0830078125],
+    ('gqa32x8_h128', False, True, 0):
+        [-0.10205078125, 0.275390625, -0.08349609375, 0.228515625],
+    ('gqa32x8_h128', False, True, 2):
+        [0.18359375, 0.078125, -0.294921875, 0.373046875],
+    ('gqa32x8_h128', True, False, 0):
+        [0.37890625, 0.2119140625, -0.2734375, 0.5390625],
+    ('gqa32x8_h128', True, False, 2):
+        [-0.09521484375, 0.4453125, -0.236328125, -0.33203125],
+    ('gqa32x8_h128', True, True, 0):
+        [0.37890625, 0.197265625, -0.11083984375, -0.197265625],
+    ('gqa32x8_h128', True, True, 2):
+        [-0.09521484375, 0.54296875, -0.0859375, -0.1201171875],
+    ('mha32_h64', False, False, 0):
+        [0.294921875, -0.0537109375, -0.039794921875, 0.095703125],
+    ('mha32_h64', False, False, 2):
+        [0.10546875, -0.365234375, -0.3359375, -0.1923828125],
+    ('mha32_h64', False, True, 0):
+        [0.294921875, -0.232421875, -0.0654296875, 0.345703125],
+    ('mha32_h64', False, True, 2):
+        [0.10546875, 0.1875, -0.55859375, -0.12353515625],
+    ('mha32_h64', True, False, 0):
+        [0.006683349609375, 0.13671875, -0.107421875, -0.07568359375],
+    ('mha32_h64', True, False, 2):
+        [0.515625, -0.1220703125, 0.490234375, 0.1796875],
+    ('mha32_h64', True, True, 0):
+        [0.006683349609375, -0.12109375, -0.1103515625, -0.1484375],
+    ('mha32_h64', True, True, 2):
+        [0.515625, 0.1435546875, 0.1591796875, -0.1396484375],
+}
+
+
+@pytest.mark.parametrize("layer", [0, _STACK_L - 1])
+@pytest.mark.parametrize("ragged", [False, True], ids=["T1", "ragged"])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("heads", sorted(_STACK_HEADS))
+def test_read_kernel_on_the_stack_is_the_reference_on_the_layer(
+        heads, quant, ragged, layer):
+    from llm_based_apache_spark_optimization_tpu.ops.pallas import (
+        paged_attention_reference,
+        paged_attention_reference_quantized,
+        ragged_paged_attention,
+        ragged_paged_attention_quantized,
+    )
+
+    q, pools, tab, pos, kvl, q_lens = _stack_case(heads, quant, ragged)
+    kernel, reference = (
+        (ragged_paged_attention_quantized,
+         paged_attention_reference_quantized) if quant
+        else (ragged_paged_attention, paged_attention_reference))
+    out = np.asarray(
+        kernel(q, *pools, tab, pos, layer, None, kvl, q_lens), np.float32)
+    ref = np.asarray(reference(
+        q, *(p[layer] for p in pools), tab, pos, None, kvl, q_lens),
+        np.float32)
+    # bf16 outputs of O(1) values; another layer's pool would be O(1) off.
+    np.testing.assert_allclose(out, ref, atol=3e-2, rtol=2e-2)
+    assert np.abs(out[2]).max() == 0.0                     # the parked row
+    for bi in range(out.shape[0]):                         # dead columns
+        assert np.abs(out[bi, int(q_lens[bi]):]).sum() == 0.0
+    others = [l for l in range(_STACK_L) if l != layer]
+    assert all(np.abs(out - np.asarray(kernel(
+        q, *pools, tab, pos, l, None, kvl, q_lens), np.float32)).max() > 0.1
+        for l in others)
+    probes = [float(out[probe]) for probe in _STACK_PROBES]
+    assert probes == _STACK_GOLDEN[heads, quant, ragged, layer]
+
+
+@pytest.mark.parametrize("layer", [0, _STACK_L - 1])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_sharded_read_kernel_on_the_stack_matches_single(quant, layer):
+    """The shard_map wrappers pass the stack through with the layer axis
+    unsharded and the KV-head axis over tp: same output as one device."""
+    from llm_based_apache_spark_optimization_tpu.ops.pallas import (
+        ragged_paged_attention,
+        ragged_paged_attention_quantized,
+        sharded_ragged_paged_attention,
+        sharded_ragged_paged_attention_quantized,
+    )
+    from llm_based_apache_spark_optimization_tpu.parallel import make_mesh
+
+    q, pools, tab, pos, kvl, q_lens = _stack_case("gqa32x8_h128", quant, True)
+    single, sharded = (
+        (ragged_paged_attention_quantized,
+         sharded_ragged_paged_attention_quantized) if quant
+        else (ragged_paged_attention, sharded_ragged_paged_attention))
+    mesh = make_mesh(dp=1, tp=4, devices=jax.devices()[:4])
+    np.testing.assert_array_equal(
+        np.asarray(sharded(mesh, q, *pools, tab, pos, layer, None, kvl,
+                           q_lens), np.float32),
+        np.asarray(single(q, *pools, tab, pos, layer, None, kvl, q_lens),
+                   np.float32))
 
 
 def test_fused_page_write_matches_reference(rng):
