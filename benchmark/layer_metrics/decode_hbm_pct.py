@@ -1,7 +1,7 @@
-"""Model step programs: bytes a decode step must read — the weights in their
-served type and the KV of every live token (`costs.py`) — over the HBM
-bandwidth, over the decode program's device time a step."""
-import costs
+"""Model step programs: bytes a decode step must read, counted by the
+configuration's family (`families/<family>/costs.py`, `decode_step_bytes`:
+the weights in their served type and the KV of every live token) — over the
+HBM bandwidth, over the decode program's device time a step."""
 from xtrace import MODULES
 
 
@@ -13,6 +13,5 @@ def read(ctx):
     if not n:
         return None
     step_s = secs / (n * ctx.cell.serving["decode_chunk"])
-    need = (costs.weight_bytes_per_step(ctx.cfg)
-            + costs.kv_bytes_per_token(ctx.cfg) * dec["live_tokens"])
+    need = ctx.family("costs").decode_step_bytes(ctx)
     return 100.0 * need / ctx.peaks["hbm_bytes_per_s"] / step_s

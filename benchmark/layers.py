@@ -7,7 +7,6 @@ reader that raises is logged and left out the same way."""
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
 import os
 import statistics
 import traceback
@@ -33,11 +32,16 @@ class Context:
     def cfg(self) -> dict:
         return self.cell.config
 
+    def family(self, part: str):
+        """A part of the configuration's model family (`spec.family`)."""
+        return spec.family(self.cfg, part)
+
     def program(self, name: str) -> dict:
         return spec.load_json("programs", name + ".json")
 
     def kernel(self, name: str):
-        return _load(os.path.join(spec.HERE, "kernels", name + ".py"))
+        return spec.load_module(
+            os.path.join(spec.HERE, "kernels", name + ".py"))
 
     def serving_delta(self, block: str, key: str) -> float:
         a = self.metrics_t0[self.model]["serving"][block][key]
@@ -78,20 +82,12 @@ def percentile(values: list, q: float) -> float:
     return xs[min(len(xs) - 1, max(0, int(-(-q * len(xs) // 1)) - 1))]
 
 
-def _load(path: str):
-    name = "bench_" + os.path.splitext(os.path.basename(path))[0].replace(".", "_")
-    sp = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(sp)
-    sp.loader.exec_module(mod)
-    return mod
-
-
 def read_all(ctx: Context, metrics: list, log) -> dict:
     out = {}
     for m in metrics:
         path = os.path.join(spec.HERE, "layer_metrics", m["name"] + ".py")
         try:
-            value = _load(path).read(ctx)
+            value = spec.load_module(path).read(ctx)
         except Exception:  # noqa: BLE001 — one reader never costs the run
             log(f"per-layer reader {m['name']} raised:\n{traceback.format_exc()}")
             continue

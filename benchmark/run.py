@@ -139,6 +139,19 @@ def open_loop_count(records: list, t0: float, t_end: float,
             for t in r.get("chunk_t", ()) if t >= t_end)}
 
 
+def longest_silence(records: list, t0: float) -> dict:
+    """The longest time in which no chunk of any request reached the client,
+    and when it began, in seconds from the window's opening. In a closed
+    loop some lane is always decoding, so a silence of several rounds is a
+    stall of the whole serving loop; in an open loop it may be an idle
+    stretch of the schedule."""
+    ts = sorted(t for r in records for t in r.get("chunk_t", ()))
+    if len(ts) < 2:
+        return {}
+    gap, at = max((b - a, a) for a, b in zip(ts, ts[1:]))
+    return {"seconds": round(gap, 3), "from_s": round(at - t0, 2)}
+
+
 def within_limits(compared: dict) -> bool:
     """The comparison that decides `correct`: every number compared is at
     or under its limit. A NaN fails too."""
@@ -379,8 +392,13 @@ def main() -> int:
         bad = [r for r in played["records"] if t0 <= r["due"] < t_end
                and not (r.get("done") and not r.get("error"))]
         log(f"failed requests: {[(r['idx'], r.get('error')) for r in bad[:8]]}")
-        with open(os.path.join(out_dir, "flight.json"), "w") as f:
-            json.dump(flight, f)
+    # Every run keeps its round records: about one run in ten of
+    # `mistral-7b-int8.explain` holds one stall of all lanes for 1-2.5 s
+    # (PERF.md section 7), and whether the device or the host held the round
+    # is in the round's record and nowhere else.
+    with open(os.path.join(out_dir, "flight.json"), "w") as f:
+        json.dump(flight, f)
+    log(f"longest silence at the client: {json.dumps(longest_silence(played['records'], t0))}")
     with open(os.path.join(out_dir, "server_requests.json"), "w") as f:
         json.dump(srv.request_log.records, f)
     server_log = {r.get("request_id"): r for r in srv.request_log.records}

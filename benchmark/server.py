@@ -4,7 +4,8 @@ from a thread of this process on port 0 (`chip_smoke.Server`'s pattern,
 copied: the benchmark keeps its own).
 
 From the program this takes `build_app`, `build_parser`, `AppConfig` and
-the process-level JAX settings; the weights come from `weights.py`.
+the process-level JAX settings; the program's config object and the weights
+come from the configuration's family (`families/<family>/program.py`).
 """
 
 from __future__ import annotations
@@ -16,26 +17,6 @@ import shutil
 import urllib.request
 
 import spec
-import weights
-
-
-def llama_config(cfg: dict):
-    """The program's `LlamaConfig` for a configuration file."""
-    from llm_based_apache_spark_optimization_tpu.models.configs import LlamaConfig
-
-    return LlamaConfig(
-        name=cfg["name"], vocab_size=cfg["vocab_size"],
-        hidden_size=cfg["hidden_size"],
-        intermediate_size=cfg["intermediate_size"],
-        num_layers=cfg["num_hidden_layers"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        max_seq_len=cfg["max_position_embeddings"],
-        rope_theta=float(cfg["rope_theta"]), norm_eps=cfg["rms_norm_eps"],
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        sliding_window=cfg.get("sliding_window"),
-        bos_id=cfg["bos_token_id"], eos_id=cfg["eos_token_id"],
-        pad_id=cfg.get("pad_token_id", 0))
 
 
 class SeededWeights:
@@ -52,7 +33,8 @@ class SeededWeights:
                              f"arguments ask for another format")
         if mesh is not None:
             raise ValueError("one-chip cells only: no mesh")
-        return llama_config(self.cfg), weights.served_tree(
+        program = spec.family(self.cfg, "program")
+        return program.config(self.cfg), program.served_tree(
             self.cfg, fmt, self.seed, self.emit_ids)
 
 

@@ -14,6 +14,11 @@ import spec
 import weights
 
 EMIT = list(range(2, 200))
+# Both configurations are of one family; its parts, found as a run finds them.
+LLAMA = spec.load_json("configs", "mistral-7b-int8.json")
+program = spec.family(LLAMA, "program")
+fam_weights = spec.family(LLAMA, "weights")
+fam_reference = spec.family(LLAMA, "reference")
 
 
 def _tiny(name):
@@ -25,14 +30,14 @@ def _tiny(name):
 def test_served_tree_is_the_reference_weights(name):
     cfg = _tiny(name)
     fmt = cfg["serving"]["weights"]
-    tree = weights.served_tree(cfg, fmt, 2**31 + 5, EMIT)
+    tree = program.served_tree(cfg, fmt, 2**31 + 5, EMIT)
     k_t, k_l = weights.keys_for(2**31 + 5, cfg["num_hidden_layers"])
     for l in range(cfg["num_hidden_layers"]):
-        one = weights.layer(cfg, fmt, k_l[l])
+        one = fam_weights.layer(cfg, fmt, k_l[l])
         for a, b in zip(jax.tree.leaves(one),
                         jax.tree.leaves(jax.tree.map(lambda x: x[l], tree["blocks"]))):
             assert np.array_equal(np.asarray(a), np.asarray(b))
-    other = weights.served_tree(cfg, fmt, 6, EMIT)
+    other = program.served_tree(cfg, fmt, 6, EMIT)
     assert not np.array_equal(np.asarray(tree["embed"]), np.asarray(other["embed"]))
 
 
@@ -55,7 +60,7 @@ def test_control_fails_and_reference_passes(name, seed):
             toks = np.zeros((1, width), np.int32)
             seq = prompt + out
             toks[0, :len(seq)] = seq
-            lg = reference.logits_at(cfg, fmt, seed, EMIT, toks,
+            lg = fam_reference.logits_at(cfg, fmt, seed, EMIT, toks,
                                      np.array([[len(seq) - 1]]))
             out.append(int(jnp.argmax(lg[0, 0])))
         samples.append((prompt, out))

@@ -2,11 +2,12 @@
 published sizes of both configurations."""
 import pytest
 
-import costs
+import costs as shared
 import spec
 
 MISTRAL = spec.load_json("configs", "mistral-7b-int8.json")
 SMOL = spec.load_json("configs", "smollm2-1.7b-bf16.json")
+costs = spec.family(MISTRAL, "costs")  # both are of one family
 
 
 def test_mistral_by_hand():
@@ -51,8 +52,8 @@ def test_pool_tokens_are_the_budget():
 
 def test_prefill_attention_positions():
     # positions 1..4 attend to 1+2+3+4 keys; after 2 reused: 3+4
-    assert costs.prefill_attention_positions(0, 4) == 10
-    assert costs.prefill_attention_positions(2, 2) == 7
+    assert shared.prefill_attention_positions(0, 4) == 10
+    assert shared.prefill_attention_positions(2, 2) == 7
 
 
 def test_kernel_costs_by_hand():
@@ -71,8 +72,8 @@ def test_kernel_costs_by_hand():
 
 
 def test_unknown_device_kind_is_an_error():
-    assert costs.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
+    assert shared.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
     with pytest.raises(KeyError):
-        costs.peaks("TPU v9")
+        shared.peaks("TPU v9")
     with pytest.raises(KeyError):
-        costs.peaks("cpu")
+        shared.peaks("cpu")

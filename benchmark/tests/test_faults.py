@@ -36,14 +36,27 @@ def test_sound_run_is_correct(capsys, monkeypatch, cell):
 def test_altered_token_is_not_correct(capsys, monkeypatch, cell):
     from llm_based_apache_spark_optimization_tpu.serve import scheduler
 
+    import spec
+    import traffic
+
+    # The altered id is another of the ids the cell's head emits (`run.main`
+    # builds the same list), so it prints as a token, every altered request
+    # can still be sampled, and it is the reference that has to see it.
+    tok = traffic.Tok()
+    config = spec.Cell(cell, rehearse=True).config
+    emit_ids = sorted(tok.emit_table({tok.eos, config["eos_token_id"]}).values())
     real = scheduler._Request.emit
 
     def emit(self, tok):  # the 4th token of every request, where it is produced
         n = self.__dict__["_bench_n"] = self.__dict__.get("_bench_n", 0) + 1
-        return real(self, 40 + (tok - 39) % 90 if n == 4 else tok)
+        if n == 4:
+            at = emit_ids.index(tok) if tok in emit_ids else 0
+            tok = emit_ids[(at + len(emit_ids) // 2) % len(emit_ids)]
+        return real(self, tok)
 
     monkeypatch.setattr(scheduler._Request, "emit", emit)
     line = _run(capsys, monkeypatch, cell, 2**31 + 78)
     assert line["correct"] is False
+    assert line["compared"]["chunks_not_a_token"]["value"] == 0
     c = line["compared"]["max_logit_gap"]
     assert c["value"] > c["limit"]

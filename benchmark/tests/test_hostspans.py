@@ -28,7 +28,7 @@ NEW = ("round_host_busy_ms", "host_late_rounds_pct", "prefill_span_p50_ms",
 
 
 def _read(name, ctx):
-    return layers._load(
+    return spec.load_module(
         os.path.join(HERE, "layer_metrics", name + ".py")).read(ctx)
 
 

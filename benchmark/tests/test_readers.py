@@ -36,7 +36,7 @@ def _ctx(rounds):
 
 
 def _read(name, ctx):
-    return layers._load(
+    return spec.load_module(
         os.path.join(HERE, "layer_metrics", name + ".py")).read(ctx)
 
 

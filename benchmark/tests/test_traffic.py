@@ -82,6 +82,28 @@ def test_nl2sql_no_seed_moves_a_shared_prefix_block(tok):
         assert _shared_blocks(tok, cell, seed) == want
 
 
+@pytest.mark.parametrize("name", CELLS[1:])
+def test_explain_no_seed_moves_a_shared_prefix_block(name, tok):
+    """The same in the closed loop, where the order of arrival is the
+    server's: whichever two traces meet in its cache, they share the
+    instruction, the template and the fixed opening of the exception — 17
+    blocks of 16 tokens — and part at the tag, 5 tokens short of the next
+    block's edge. Before PR 30 they parted inside the first column's name:
+    on seed 2147490101 four requests of `mistral-7b-int8.explain` reused 288
+    tokens for 272 and the cell read `ttft_p50_ms` 933 for 955, run after
+    run (PERF.md section 2)."""
+    cell = spec.Cell(name)
+    for seed in (2147490101, 7, 2**31 + 11, 4100000011):
+        prompts = [traffic.prompt_ids(tok, cell.traffic, r["system"], r["prompt"])
+                   for r in traffic.build(cell, seed, 45.0)["requests"]]
+        for i, ids in enumerate(prompts):
+            common = max(
+                next(n for n in range(len(ids) + 1)
+                     if n >= min(len(ids), len(o)) or ids[n] != o[n])
+                for j, o in enumerate(prompts) if j != i)
+            assert 272 + 8 <= common < 288 - 3, (seed, i, common)
+
+
 def test_text_kinds_are_data(tok):
     import random
     for kind in ("schema", "question", "spark_trace"):

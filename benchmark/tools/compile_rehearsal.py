@@ -30,9 +30,7 @@ import jax.numpy as jnp  # noqa: E402
 from jax.experimental import topologies  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-import server  # noqa: E402
 import spec  # noqa: E402
-import weights  # noqa: E402
 
 HBM_BYTES = 16 * 2**30
 
@@ -47,6 +45,8 @@ def main() -> int:
                          "default: the largest prefill and decode")
     args = ap.parse_args()
     cfg = spec.load_json("configs", args.config + ".json")
+    spec.check_family(cfg, f"benchmark/configs/{args.config}.json")
+    program = spec.family(cfg, "program")
     sv = cfg["serving"]
     gb = args.kv_hbm_gb if args.kv_hbm_gb is not None else sv["kv_hbm_gb"]
     slots = args.slots or sv["slots"]
@@ -60,10 +60,10 @@ def main() -> int:
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     one = SingleDeviceSharding(topo.devices[0])
     abstract = jax.eval_shape(
-        lambda: weights.served_tree(cfg, sv["weights"], 0, [2, 3]))
+        lambda: program.served_tree(cfg, sv["weights"], 0, [2, 3]))
     params = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), abstract)
     sched = ContinuousBatchingScheduler(
-        server.llama_config(cfg), params, num_slots=slots,
+        program.config(cfg), params, num_slots=slots,
         prompt_bucket=sv["prompt_bucket"], kv_layout="paged",
         kv_hbm_budget_bytes=int(gb * 2**30))
     param_bytes = sum(x.nbytes for x in jax.tree.leaves(params))

@@ -1,94 +1,24 @@
-"""Weights from `--seed`, made by the benchmark and by nothing else.
-
-One function per piece (`layer`, `tables`), each a pure function of the
-configuration and a key. The served tree is the same functions mapped over
-the layer keys inside ONE jitted call, born on the device in the type it is
-served in; the plain reference (`reference.py`) calls them again, one layer
-at a time, and so takes nothing the program has touched.
-
-Formats (`serving.weights` in the configuration file):
-
-- `int8`: the seven block matrices are int8 `[in, out]` with one float32
-  scale per output channel (random in 0.75..1.25 of `sqrt(3 / fan_in) / 127`,
-  so the stated weights have bf16's deviation and a path that dropped or
-  transposed the scales cannot pass); embeddings,
-  head and norms bfloat16. The stated weights ARE `q8 * scale`.
-- `bf16`: every matrix bfloat16, normal with deviation `fan_in**-0.5`.
+"""Weights from `--seed`, made by the benchmark and by nothing else: what
+every model family shares. The pieces themselves (`layer`, `tables`) and
+the tree in the program's layout are the family's
+(`families/<family>/weights.py`, `program.py`), found through the
+configuration's `family` (`spec.family`).
 
 The served tokenizer (`benchmark/tokenizer`) has 320 entries, the models
 32,000 and 49,152. A random head would put nearly every greedy token outside
 the tokenizer, where it prints as nothing and the stream would carry no
-chunk to time. So the head's rows outside `emit_ids` (the ids that print as
-plain ASCII and are no stop id) are scaled by 1/64: every greedy token then
-prints, one NDJSON chunk per token, and the full-width head is still
-computed. Norm weights are random about 1. `init.qk_gain` in the
-configuration file multiplies the query and key matrices; the file says why.
+chunk to time. So a family scales the head's rows outside `emit_ids` (the
+ids that print as plain ASCII and are no stop id) by `HEAD_DAMP`: every
+greedy token then prints, one NDJSON chunk per token, and the full-width
+head is still computed.
 """
 
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-MATRICES = ("wq", "wk", "wv", "wo", "wg", "wu", "wd")
 HEAD_DAMP = 1.0 / 64.0
-
-
-def shapes(cfg: dict) -> dict:
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    nh, kh, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                  cfg["head_dim"])
-    return {"wq": (d, nh * hd), "wk": (d, kh * hd), "wv": (d, kh * hd),
-            "wo": (nh * hd, d), "wg": (d, f), "wu": (d, f), "wd": (f, d)}
-
-
-def layer(cfg: dict, fmt: str, key) -> dict:
-    """One layer's weights in the served types."""
-    out = {}
-    keys = jax.random.split(key, 2 * len(MATRICES) + 2)
-    qk_gain = cfg.get("init", {}).get("qk_gain", 1.0)
-    gains = {"wq": qk_gain, "wk": qk_gain}
-    for i, (name, (n_in, n_out)) in enumerate(shapes(cfg).items()):
-        gain = float(gains.get(name, 1.0))
-        if fmt == "int8":
-            q = jax.lax.bitcast_convert_type(
-                jax.random.bits(keys[2 * i], (n_in, n_out), jnp.uint8), jnp.int8)
-            q = jnp.maximum(q, jnp.int8(-127))
-            # uniform int8 has deviation 127/sqrt(3): the scale makes the
-            # stated weight's deviation gain * fan_in**-0.5, as bf16's is
-            s = (gain * (3.0 / n_in) ** 0.5 / 127.0) * jax.random.uniform(
-                keys[2 * i + 1], (n_out,), jnp.float32, 0.75, 1.25)
-            out[name] = {"q8": q, "s": s}
-        elif fmt == "bf16":
-            out[name] = (jax.random.normal(keys[2 * i], (n_in, n_out), jnp.float32)
-                         * (gain * n_in ** -0.5)).astype(jnp.bfloat16)
-        else:
-            raise ValueError(f"unknown weight format {fmt!r}")
-    d = cfg["hidden_size"]
-    for j, name in enumerate(("ln_attn", "ln_mlp")):
-        out[name] = jax.random.uniform(keys[-2 + j], (d,), jnp.float32,
-                                       0.8, 1.2).astype(jnp.bfloat16)
-    return out
-
-
-def tables(cfg: dict, key, emit_mask) -> dict:
-    """Embedding, head and final norm. `emit_mask` is a bool `[vocab]`."""
-    v, d = cfg["vocab_size"], cfg["hidden_size"]
-    k_e, k_h, k_n = jax.random.split(key, 3)
-    damp = jnp.where(emit_mask, 1.0, HEAD_DAMP)[:, None]
-
-    def table(k, damped):
-        t = jax.random.normal(k, (v, d), jnp.float32) * d ** -0.5
-        return (t * damp if damped else t).astype(jnp.bfloat16)
-
-    tied = bool(cfg["tie_word_embeddings"])
-    out = {"embed": table(k_e, tied),
-           "final_norm": jax.random.uniform(k_n, (d,), jnp.float32, 0.8,
-                                            1.2).astype(jnp.bfloat16)}
-    if not tied:
-        out["lm_head"] = table(k_h, True)
-    return out
 
 
 def keys_for(seed: int, num_layers: int):
@@ -103,17 +33,3 @@ def emit_mask(cfg: dict, emit_ids) -> np.ndarray:
     mask = np.zeros(cfg["vocab_size"], bool)
     mask[np.asarray(list(emit_ids), np.int64)] = True
     return mask
-
-
-def served_tree(cfg: dict, fmt: str, seed: int, emit_ids):
-    """The whole tree in the program's layout (`models/llama.init_params`:
-    blocks stacked on a leading layer axis), in one jitted call."""
-    k_t, k_l = keys_for(seed, cfg["num_hidden_layers"])
-    mask = jnp.asarray(emit_mask(cfg, emit_ids))
-
-    @jax.jit
-    def make(k_t, k_l, mask):
-        blocks = jax.lax.map(lambda k: layer(cfg, fmt, k), k_l)
-        return {**tables(cfg, k_t, mask), "blocks": blocks}
-
-    return make(k_t, k_l, mask)
