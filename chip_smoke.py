@@ -164,6 +164,10 @@ def check_kernels(seed: int, rehearse: bool) -> None:
         attention_mask,
         gqa_attention,
     )
+    from llm_based_apache_spark_optimization_tpu.ops.lanepack import (
+        pack_cache,
+        unpack_cache,
+    )
     from llm_based_apache_spark_optimization_tpu.ops.quant import quantize_kv
 
     cfg = REGISTRY["tiny" if rehearse else MODEL]
@@ -176,7 +180,7 @@ def check_kernels(seed: int, rehearse: bool) -> None:
         L, P, ps, np_tab, b, s_flash, t_flash = 2, 96, 64, 8, 8, 2048, 128
     t_chunk = 16
     dt = jnp.float32 if rehearse else jnp.bfloat16
-    keys = iter(jax.random.split(jax.random.key(seed), 16))
+    keys = iter(jax.random.split(jax.random.key(seed), 32))
 
     def rnd(shape, dtype=dt):
         return jax.random.normal(next(keys), shape, jnp.float32).astype(dtype)
@@ -188,6 +192,14 @@ def check_kernels(seed: int, rehearse: bool) -> None:
     tab = jnp.asarray(tab)
     kp, vp = rnd((L, P, kh, ps, h)), rnd((L, P, kh, ps, h))
     k8, v8 = quantize_kv(kp), quantize_kv(vp)
+    # A head-64 pool is stored two heads a 128-lane row (engine/paged_kv
+    # .lane_pack): the same three kernels through their packed wrappers,
+    # against the references on the LOGICAL pool. Compiled is what counts:
+    # the interpreter passed a form of the wrapper that the TPU compiler,
+    # jitted around the kernel call, turned into wrong values (PR 33).
+    n6, h6 = (4, 64) if rehearse else (32, 64)      # MHA, as SmolLM2-1.7B
+    lk, lv = rnd((L, P, n6, ps, h6)), rnd((L, P, n6, ps, h6))
+    pk, pv = pack_cache(lk, 2), pack_cache(lv, 2)
     results = {}
 
     def close(name, got, want, atol):
@@ -243,6 +255,18 @@ def check_kernels(seed: int, rehearse: bool) -> None:
              K.paged_write_reference_quantized(
                  k8["q8"], k8["s"], v8["q8"], v8["s"], k_new, v_new, wpos,
                  tab, 1, q_lens))
+        q, k_new, v_new = (rnd((b, t, n6, h6)) for _ in range(3))
+        close(f"ragged_read_head64_packed_T{t}",
+              K.ragged_paged_attention(q, pk, pv, tab, pos, 1, window,
+                                       kv_lens, q_lens, interpret=interpret),
+              K.paged_attention_reference(q, lk[1], lv[1], tab, pos, window,
+                                          kv_lens, q_lens), atol)
+        same(f"page_write_head64_packed_T{t}",
+             [unpack_cache(x, 2) for x in K.fused_page_write(
+                 pk, pv, k_new, v_new, wpos, tab, 1, q_lens=q_lens,
+                 interpret=interpret)],
+             (K.paged_write_reference(lk, k_new, wpos, tab, 1, q_lens),
+              K.paged_write_reference(lv, v_new, wpos, tab, 1, q_lens)))
 
     # Flash prefill over a contiguous row view, one chunk in mid-window.
     bq = 2
@@ -253,6 +277,13 @@ def check_kernels(seed: int, rehearse: bool) -> None:
         + np.arange(t_flash, dtype=np.int32))
     close("flash_prefill",
           K.flash_gqa_attention(q, kc, vc, pos, window, interpret=interpret),
+          gqa_attention(q, kc, vc, attention_mask(pos, s_flash, window)),
+          atol)
+    q = rnd((bq, t_flash, n6, h6))
+    kc, vc = rnd((bq, n6, s_flash, h6)), rnd((bq, n6, s_flash, h6))
+    close("flash_prefill_head64_packed",
+          K.flash_gqa_attention(q, pack_cache(kc, 2), pack_cache(vc, 2), pos,
+                                window, interpret=interpret),
           gqa_attention(q, kc, vc, attention_mask(pos, s_flash, window)),
           atol)
     emit("kernels", widths={"heads": n, "kv_heads": kh, "head_dim": h,

@@ -869,6 +869,12 @@ def build_app(args, cfg: AppConfig, load_weights=load_checkpoint_file):
             if ledger.get("kernels"):
                 print(f"kernels {model}/{ledger.get('replica')}: "
                       f"{json.dumps(ledger['kernels'])}", file=sys.stderr)
+        pages = stats.get("kv_pages") or {}
+        if "kv_pool_lane_pack" in pages:
+            print(f"kv_pool {model}: " + json.dumps(
+                {k: pages[k] for k in ("kv_pool_lane_pack", "kv_pool_shape",
+                                       "pages_total", "page_size")}),
+                file=sys.stderr)
     return app, service
 
 

@@ -245,11 +245,13 @@ def _make_generate_fn(
             # INSIDE the pack (int8 pages + per-position scales) — the
             # same prefill-bf16-then-quantize-once handoff as the
             # contiguous int8 path, per page.
-            from .paged_kv import pack_prefill_pages
+            from .paged_kv import lane_pack, pack_prefill_pages
 
             ppr = -(-(t + max_new) // page_size)
-            cache = pack_prefill_pages(cache, page_size, ppr,
-                                       kv_quant=kv_quant)
+            tp = dict(mesh.shape).get("tp", 1) if mesh is not None else 1
+            cache = pack_prefill_pages(
+                cache, page_size, ppr, kv_quant=kv_quant,
+                pack=lane_pack(cfg, kv_quant, tp))
             if mesh is not None:
                 cache = constrain_cache(cache, mesh)
         elif kv_quant:

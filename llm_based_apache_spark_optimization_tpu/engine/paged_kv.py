@@ -10,6 +10,25 @@ PagedAttention design, PAPERS.md):
     pool:        {"kp": [L, P, K, page_size, H], "vp": [L, P, K, page_size, H]}
     page table:  [slots, pages_per_slot] int32 — per-slot logical->pool map
 
+THE STORED SHAPE. Where a head is narrower than the TPU's 128-lane tile
+the pool is stored LANE-PACKED, `f` heads a row:
+
+    [L, P, K/f, page_size, f*H],  packed[l,p,j,s,i*H+d] = logical[l,p,f*j+i,s,d]
+
+`lane_pack` below decides `f`, alone, from the configuration and the
+pool's kind (`f = 128 // head_dim` for a plain bf16/f32 pool whose KV
+heads divide by it; 1 — the shape above — at head 128, for an int8 pool,
+for an odd number of KV heads); `init_page_pool` and `pack_prefill_pages`
+ask it, and from there the pool's own shape is the only record of `f`:
+every reader and writer takes `pool.shape[-1] // head_dim` (ops/lanepack.py
+says how each side uses it). A minor axis of 64 would have XLA keep the
+pool with another axis minor and convert it whole, in and out, around
+every Mosaic call of every device program (PERF.md section 5); stored so,
+no program holds a result of pool shape but the donated pool itself.
+Whole-page operations (copy-on-write, spill, export/import) index the page
+axis and move a page's bytes as they lie; a blob imports only into a pool
+of its own stored shape.
+
 - The pool is sized to an HBM budget (`pages_for_budget`), not to
   slots × S_max: a request holds ceil(need / page_size) pages for
   `need = bucketed prompt + max_new + overshoot` — mixed long/short traffic
@@ -65,6 +84,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.configs import LlamaConfig
+from ..ops.lanepack import LANES, pack_cache
 
 
 class PageAccountingError(RuntimeError):
@@ -127,14 +147,33 @@ def pages_for_tokens(n_tokens: int, page_size: int) -> int:
     return -(-int(n_tokens) // int(page_size))
 
 
+def lane_pack(
+    cfg: LlamaConfig, kv_quant: Optional[str] = None, tp: int = 1,
+) -> int:
+    """How many KV heads share one stored row (module docstring, "THE
+    STORED SHAPE"): `128 // head_dim` where that fills the lane tile
+    exactly, the KV heads divide by it, the packed heads still divide
+    over `tp` (the pool shards its head axis) and the pool holds plain
+    bf16/f32 values; otherwise 1. An int8 pool stays unpacked: its scale
+    per head and position would have to follow its part of the row into
+    the kernels."""
+    h, kh = cfg.head_dim, cfg.num_kv_heads
+    if kv_quant is not None or h >= LANES or LANES % h:
+        return 1
+    f = LANES // h
+    return f if kh % f == 0 and (kh // f) % max(1, int(tp)) == 0 else 1
+
+
 def init_page_pool(
     cfg: LlamaConfig, num_pages: int, page_size: int, dtype=jnp.bfloat16,
-    kv_quant: Optional[str] = None,
+    kv_quant: Optional[str] = None, tp: int = 1,
 ) -> Dict[str, jnp.ndarray]:
     """Allocate the shared device page pool. Layout mirrors the contiguous
     cache with the (batch, S) axes replaced by one page axis: per
     (page, kv-head) the pool is a contiguous [page_size, H] tile — the
-    MXU/Pallas-friendly trailing (sublane, lane) shape.
+    MXU/Pallas-friendly trailing (sublane, lane) shape — or, lane-packed
+    (`lane_pack`; `tp` is the mesh's tensor-parallel degree), a
+    [page_size, f*H] tile per f heads.
 
     `kv_quant="int8"` stores int8 values plus f32 per-position scales
     ("kps"/"vps", [L, P, K, page_size] — the paged twin of the contiguous
@@ -145,8 +184,9 @@ def init_page_pool(
         raise ValueError(
             f"page_size must be a positive multiple of 8, got {page_size}"
         )
-    shape = (cfg.num_layers, num_pages, cfg.num_kv_heads, page_size,
-             cfg.head_dim)
+    f = lane_pack(cfg, kv_quant, tp)
+    shape = (cfg.num_layers, num_pages, cfg.num_kv_heads // f, page_size,
+             f * cfg.head_dim)
     if kv_quant == "int8":
         sshape = shape[:-1]
         return {
@@ -162,7 +202,7 @@ def init_page_pool(
 
 def pack_prefill_pages(
     cache: Dict[str, jnp.ndarray], page_size: int, pages_per_row: int,
-    kv_quant: Optional[str] = None,
+    kv_quant: Optional[str] = None, pack: int = 1,
 ) -> Dict[str, jnp.ndarray]:
     """Contiguous prefill cache {"k","v"} [L, B, K, S, H] -> paged cache
     {"kp","vp","ptab"} with identity per-row tables (row b owns pool pages
@@ -179,7 +219,11 @@ def pack_prefill_pages(
     the int8 pool layout {"kp","kps","vp","vps","ptab"} — the
     prefill-fills-bf16-then-quantize-once handoff the contiguous int8
     path uses, applied per page. Unwritten pool scale entries stay 1.0 so
-    unmapped-page garbage dequantizes finite."""
+    unmapped-page garbage dequantizes finite.
+
+    `pack` (the caller's `lane_pack(cfg, kv_quant, tp)`) stores the pool
+    with that many heads a row, as `init_page_pool` would."""
+    cache = {n: pack_cache(a, pack) for n, a in cache.items()}
     k = cache["k"]
     n_layers, b, kh, s, h = k.shape
     ppr = int(pages_per_row)
@@ -259,9 +303,26 @@ def import_pages(
     `_page_wait`/overcommit admission every fresh request rides, so
     migration changes no pressure semantics."""
     idx = jnp.asarray(page_ids, jnp.int32)
+    for c, s in zip(cache, stacks):
+        check_blob_shape(c.shape, s.shape)
     return tuple(
         c.at[:, idx].set(jnp.asarray(s)) for c, s in zip(cache, stacks)
     )
+
+
+def check_blob_shape(pool_shape, blob_shape) -> None:
+    """Refuse an `export_pages` array `[L, n, K/f, page(, f*H)]` that was
+    not cut from a pool stored like this one: a page's bytes mean what
+    the stored shape says (heads a row, page size), and another shape is
+    never reinterpreted. The scheduler asks when a blob arrives
+    (`requeue`), `import_pages` when it lands."""
+    pool_shape, blob_shape = tuple(pool_shape), tuple(blob_shape)
+    if pool_shape[:1] + pool_shape[2:] != blob_shape[:1] + blob_shape[2:]:
+        raise ValueError(
+            f"KV page blob of stored shape {blob_shape} "
+            f"([L, n, K/f, page(, f*H)]) cannot be imported into a pool "
+            f"of stored shape {pool_shape}"
+        )
 
 
 def handoff_bytes(stacks: Sequence[np.ndarray]) -> int:

@@ -485,11 +485,13 @@ def _make_speculative_generate_fn(
             first = jnp.argmax(first_logits, axis=-1).astype(jnp.int32)
         cstate = g_next[init_states, first] if constrained else None
         if paged:
-            from .paged_kv import pack_prefill_pages
+            from .paged_kv import lane_pack, pack_prefill_pages
 
             ppr = -(-(t + max_new + d1) // page_size)
-            cache = pack_prefill_pages(cache, page_size, ppr,
-                                       kv_quant=kv_quant)
+            tp = dict(mesh.shape).get("tp", 1) if mesh is not None else 1
+            cache = pack_prefill_pages(
+                cache, page_size, ppr, kv_quant=kv_quant,
+                pack=lane_pack(cfg, kv_quant, tp))
             if mesh is not None:
                 cache = constrain_cache(cache, mesh)
 

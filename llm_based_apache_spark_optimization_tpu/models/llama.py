@@ -45,6 +45,7 @@ from ..ops.attention import (
     gqa_attention,
     gqa_attention_quantized,
 )
+from ..ops.lanepack import pack_factor, pack_heads, unpack_cache
 from ..ops.norm import rms_norm
 from ..ops.pallas import (
     flash_gqa_attention,
@@ -252,7 +253,9 @@ def forward(
     positions: jnp.ndarray,   # [B, T] int32 — absolute position of each token
     cache: Optional[Dict[str, jnp.ndarray]] = None,  # {"k","v"}: [L, B, K, S, H]
                               # or paged {"kp","vp": [L, P, K, PS, H],
-                              # "ptab": [B, NP] i32} (engine/paged_kv.py)
+                              # "ptab": [B, NP] i32} (engine/paged_kv.py);
+                              # either may be lane-packed, [.., K/f, S|PS,
+                              # f*H] (ops/lanepack.py)
     logit_indices: Optional[jnp.ndarray] = None,  # [B] int32 — unembed only these T-indices
     attn_impl: str = "xla",  # "xla" | "pallas" | "ring"; resolve via ops.pallas.attention_impl
     mesh=None,  # required for attn_impl="ring" (context-parallel prefill)
@@ -340,6 +343,12 @@ def forward(
     )
 
     nh, kh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    # Lane packing (ops/lanepack.py): a bf16/f32 cache of narrow heads may
+    # be stored with f heads a 128-lane row — the paged pool, and the row
+    # views batched prefill gathers from it. Its own minor axis says so;
+    # f == 1 is the plain layout and every pack/unpack below the identity.
+    stored = None if cache is None else cache.get("kp", cache.get("k"))
+    f = 1 if stored is None else pack_factor(stored, hd)
 
     def qkv(p, x):
         h = rms_norm(x, p["ln_attn"], cfg.norm_eps)
@@ -386,7 +395,8 @@ def forward(
                 sliding_window=cfg.sliding_window,
             )
         else:
-            attn = gqa_attention(q, k_full, v_full, mask)
+            attn = gqa_attention(q, unpack_cache(k_full, f),
+                                 unpack_cache(v_full, f), mask)
         return post_attn(p, x, attn)
 
     def post_attn(p, x, attn):
@@ -409,8 +419,8 @@ def forward(
             k_full, v_full = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
             k_out = v_out = None
         else:
-            k_full = _update_cache(k_cache, k, start)
-            v_full = _update_cache(v_cache, v, start)
+            k_full = _update_cache(k_cache, pack_heads(k, f), start)
+            v_full = _update_cache(v_cache, pack_heads(v, f), start)
             k_out, v_out = k_full, v_full
         x = attn_mlp(p, x, q, k_full, v_full, k, v)
         return x, (k_out, v_out)
@@ -587,16 +597,18 @@ def forward(
 
                     attn = gqa_attention(
                         q,
-                        gather_pages(new_cache["kp"][l], ptab),
-                        gather_pages(new_cache["vp"][l], ptab),
+                        unpack_cache(
+                            gather_pages(new_cache["kp"][l], ptab), f),
+                        unpack_cache(
+                            gather_pages(new_cache["vp"][l], ptab), f),
                         mask,
                     )
                 x = post_attn(p, x, attn)
             else:
                 new_cache["k"] = _update_cache_layer(
-                    new_cache["k"], k, start, l)
+                    new_cache["k"], pack_heads(k, f), start, l)
                 new_cache["v"] = _update_cache_layer(
-                    new_cache["v"], v, start, l)
+                    new_cache["v"], pack_heads(v, f), start, l)
                 x = attn_mlp(p, x, q, new_cache["k"][l], new_cache["v"][l],
                              k, v)
     else:

@@ -56,6 +56,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..common import NEG_INF
+from ..lanepack import gather_outputs, pack_factor, spread_queries
 from .dispatch import resolve_interpret
 
 _LANES = 128  # VMEM lane width: scratch row-stats are kept lane-broadcast
@@ -248,7 +249,7 @@ _DECODE_KV_BLOCK_BYTES = 2 * 1024 * 1024
 
 
 def _run_decode_grid(kernel, q, streams, q_positions, kv_lens,
-                     sliding_window, blk, interpret):
+                     sliding_window, blk, interpret, scale):
     """The K-folded decode pipeline shared by the bf16 and int8-KV
     kernels: grid (B, S_blocks), per-block DMA of every `streams` array
     through the kv_lens-clamped index map, online-softmax scratch, and
@@ -306,7 +307,7 @@ def _run_decode_grid(kernel, q, streams, q_positions, kv_lens,
     )
     out = pl.pallas_call(
         functools.partial(
-            kernel, scale=h**-0.5, sliding_window=sliding_window, kv_len=s,
+            kernel, scale=scale, sliding_window=sliding_window, kv_len=s,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, gt, h), q.dtype),
@@ -326,8 +327,8 @@ def _run_decode_grid(kernel, q, streams, q_positions, kv_lens,
 )
 def flash_gqa_attention(
     q: jnp.ndarray,            # [B, T, N, H]
-    k: jnp.ndarray,            # [B, K, S, H]  (head-major cache layout)
-    v: jnp.ndarray,            # [B, K, S, H]
+    k: jnp.ndarray,            # [B, K, S, H]  (head-major cache layout),
+    v: jnp.ndarray,            # or lane-packed [B, K/f, S, f*H]
     q_positions: jnp.ndarray,  # [B, T] i32 — absolute position of each query
     sliding_window: Optional[int] = None,
     kv_lens: Optional[jnp.ndarray] = None,  # [B] i32 — live KV slots per row
@@ -346,8 +347,14 @@ def flash_gqa_attention(
 
     Returns [B, T, N, H] in q's dtype.
     """
-    b, t, n, h = q.shape
     kh, s = k.shape[1], k.shape[2]
+    # A lane-packed cache (ops/lanepack.py: the row views batched prefill
+    # gathers from a packed pool) is a GQA cache of K/f heads of width
+    # f*H to everything below; only the softmax scale is the true head's.
+    f = pack_factor(k, q.shape[3])
+    scale = q.shape[3] ** -0.5
+    q = spread_queries(q, f, kh)
+    b, t, n, h = q.shape
     g = n // kh
     gt = g * t
 
@@ -366,10 +373,10 @@ def flash_gqa_attention(
         # Decode: fold the KV-head axis into the cell (see module docstring)
         # and run the shared K-folded pipeline (which owns the clip / head
         # fold / qpos tiling for the decode grid).
-        return _run_decode_grid(
+        return gather_outputs(_run_decode_grid(
             _flash_decode_kernel, q, [(k, (h,)), (v, (h,))],
-            q_positions, kv_lens, sliding_window, blk, interpret,
-        )
+            q_positions, kv_lens, sliding_window, blk, interpret, scale,
+        ), f, kh)
 
     kv_lens = jnp.clip(kv_lens.astype(jnp.int32), 0, s)
     # [B, T, N, H] -> [B, K, G*T, H]: fold query groups into rows per KV head.
@@ -420,7 +427,7 @@ def flash_gqa_attention(
     )
     out = pl.pallas_call(
         functools.partial(
-            _flash_kernel, scale=h**-0.5, sliding_window=sliding_window,
+            _flash_kernel, scale=scale, sliding_window=sliding_window,
             kv_len=s,
         ),
         grid_spec=grid_spec,
@@ -437,7 +444,8 @@ def flash_gqa_attention(
     )(kv_lens, qpos, q5, k, v)
 
     # [B, K, G*T, H] -> [B, T, N, H]
-    return out.reshape(b, kh, g, t, h).transpose(0, 3, 1, 2, 4).reshape(b, t, n, h)
+    out = out.reshape(b, kh, g, t, h).transpose(0, 3, 1, 2, 4).reshape(b, t, n, h)
+    return gather_outputs(out, f, kh)
 
 
 @functools.partial(
@@ -482,6 +490,7 @@ def flash_gqa_attention_quantized(
         _flash_decode_kernel_q8, q,
         [(k8, (h,)), (ks4, (1,)), (v8, (h,)), (vs4, (1,))],
         q_positions, kv_lens, sliding_window, min(block_kv, s), interpret,
+        h**-0.5,
     )
 
 

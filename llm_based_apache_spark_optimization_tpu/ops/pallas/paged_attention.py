@@ -7,6 +7,19 @@ logical pages to pool pages — the layout from "Ragged Paged Attention: A
 High-Performance and Flexible LLM Inference Kernel for TPU" (PAPERS.md) and
 vLLM's PagedAttention.
 
+The stored shape: where a head is narrower than the 128-lane tile the pool
+is LANE-PACKED, `[L, P, K/f, page, f*H]` with f heads a row. Who decides:
+`engine/paged_kv.lane_pack`, from the configuration and the pool's kind,
+and nobody else — the wrappers here read `f = pool.shape[-1] // q.shape[-1]`
+off the pool they are handed (ops/lanepack.py). To the grid below a packed
+pool is a GQA pool of K/f heads of width f*H whose group is f*G query rows:
+`ragged_paged_attention` spreads query head f*j+i over lanes [i*H, (i+1)*H)
+of a zero row, passes the softmax scale of the TRUE head width, and keeps
+those lanes of the row's output. The kernel bodies, the folded row count
+(T*N) and the VMEM tiles are the plain layout's; the page DMA is half as
+many rows of full lanes, and no program converts the pool's layout around
+the call. The int8 pool (`_quantized`) is never packed.
+
 Kernel design:
 
 - Grid = (B, NP): the logical-page axis is innermost, so one core sweeps a
@@ -73,6 +86,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..common import NEG_INF
+from ..lanepack import (
+    gather_outputs,
+    pack_factor,
+    spread_queries,
+    unpack_cache,
+)
 from .attention import _flash_block_update, _LANES
 from .dispatch import resolve_interpret
 
@@ -183,7 +202,7 @@ _paged_decode_kernel_q8 = _make_paged_decode_kernel(_dequant_page_streams)
 
 
 def _run_paged_grid(kernel, q, streams, layer, page_table, q_positions,
-                    sliding_window, kv_lens, q_lens, interpret):
+                    sliding_window, kv_lens, q_lens, interpret, scale):
     """The ragged paged pipeline shared by the bf16 and int8 kernels:
     grid (B, NP) with the page table in SCALAR PREFETCH — every stream's
     BlockSpec index map translates the kv_lens-clamped logical page
@@ -196,7 +215,9 @@ def _run_paged_grid(kernel, q, streams, layer, page_table, q_positions,
     value pools, () for per-position scale pools. A block is one page
     of `layer` (prefetched too, clipped to the stack): the layer axis is
     squeezed out of the block, so the kernel bodies see
-    `[1, K, PS, ...tail]` tiles whatever the depth of the stack."""
+    `[1, K, PS, ...tail]` tiles whatever the depth of the stack. `scale`
+    is the softmax scale, the caller's to give: under lane packing the
+    row this grid sees is wider than the head it scores."""
     b, t, n, h = q.shape
     num_layers, num_pages, kh, ps = streams[0][0].shape[:4]
     g = n // kh
@@ -253,7 +274,7 @@ def _run_paged_grid(kernel, q, streams, layer, page_table, q_positions,
     )
     out = pl.pallas_call(
         functools.partial(
-            kernel, scale=h**-0.5,
+            kernel, scale=scale,
             sliding_window=sliding_window, kv_len=s_virt, window=t,
         ),
         grid_spec=grid_spec,
@@ -299,8 +320,8 @@ def _validate_window(q, page_size, interpret, *, quantized=False):
 )
 def ragged_paged_attention(
     q: jnp.ndarray,            # [B, T, N, H] — ragged query windows
-    k_pool: jnp.ndarray,       # [L, P, K, PS, H] — the stacked page pool
-    v_pool: jnp.ndarray,       # [L, P, K, PS, H]
+    k_pool: jnp.ndarray,       # [L, P, K, PS, H] — the stacked page pool,
+    v_pool: jnp.ndarray,       # or lane-packed [L, P, K/f, PS, f*H]
     page_table: jnp.ndarray,   # [B, NP] i32 — pool page per logical page
     q_positions: jnp.ndarray,  # [B, T] i32
     layer,                     # i32 scalar: which layer of the stack
@@ -323,10 +344,17 @@ def ragged_paged_attention(
     whatever the depth of the stack."""
     interpret = _validate_window(q, k_pool.shape[3], interpret)
     h = q.shape[3]
-    return _run_paged_grid(
-        _paged_decode_kernel, q, [(k_pool, (h,)), (v_pool, (h,))], layer,
+    # A lane-packed pool (ops/lanepack.py) is, to the grid, a GQA pool of
+    # K/f heads of width f*H; its shape alone says so.
+    kh, w = k_pool.shape[2], k_pool.shape[4]
+    f = pack_factor(k_pool, h)
+    out = _run_paged_grid(
+        _paged_decode_kernel, spread_queries(q, f, kh),
+        [(k_pool, (w,)), (v_pool, (w,))], layer,
         page_table, q_positions, sliding_window, kv_lens, q_lens, interpret,
+        h**-0.5,
     )
+    return gather_outputs(out, f, kh)
 
 
 @functools.partial(
@@ -361,7 +389,7 @@ def ragged_paged_attention_quantized(
         _paged_decode_kernel_q8, q,
         [(k_pool, (h,)), (k_scale, ()), (v_pool, (h,)), (v_scale, ())],
         layer, page_table, q_positions, sliding_window, kv_lens, q_lens,
-        interpret,
+        interpret, h**-0.5,
     )
 
 
@@ -497,8 +525,8 @@ def _zero_dead_qcols(out, q_lens):
 
 def paged_attention_reference(
     q: jnp.ndarray,            # [B, T, N, H]
-    k_pool: jnp.ndarray,       # [P, K, PS, H]
-    v_pool: jnp.ndarray,       # [P, K, PS, H]
+    k_pool: jnp.ndarray,       # [P, K, PS, H], or lane-packed
+    v_pool: jnp.ndarray,       # [P, K/f, PS, f*H]
     page_table: jnp.ndarray,   # [B, NP] i32
     q_positions: jnp.ndarray,  # [B, T] i32
     sliding_window: Optional[int] = None,
@@ -510,8 +538,10 @@ def paged_attention_reference(
     CPU runs take this path)."""
     from ..attention import attention_mask, gqa_attention
 
-    k_full = gather_pages(k_pool, page_table)
-    v_full = gather_pages(v_pool, page_table)
+    # A lane-packed pool gathers packed; the einsum wants logical heads.
+    f = pack_factor(k_pool, q.shape[3])
+    k_full = unpack_cache(gather_pages(k_pool, page_table), f)
+    v_full = unpack_cache(gather_pages(v_pool, page_table), f)
     s_virt = k_full.shape[2]
     mask = attention_mask(q_positions, s_virt, sliding_window)
     if kv_lens is not None:

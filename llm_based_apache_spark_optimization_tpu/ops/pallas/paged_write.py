@@ -65,6 +65,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..lanepack import pack_factor, pack_heads
 from ..quant import KV_SCALE_STEP
 from .dispatch import resolve_interpret
 
@@ -234,8 +235,8 @@ def _run_write(kernel, pools, k_new, v_new, positions, page_table, layer,
 @functools.partial(jax.jit, static_argnums=(6,),
                    static_argnames=("interpret",))
 def fused_page_write(
-    kp: jnp.ndarray,          # [L, P, K, PS, H] — shared K page pool
-    vp: jnp.ndarray,          # [L, P, K, PS, H]
+    kp: jnp.ndarray,          # [L, P, K, PS, H] — shared K page pool,
+    vp: jnp.ndarray,          # or lane-packed [L, P, K/f, PS, f*H]
     k_new: jnp.ndarray,       # [B, T, K, H] fresh K sliver
     v_new: jnp.ndarray,       # [B, T, K, H]
     positions: jnp.ndarray,   # [B, T] i32 absolute positions
@@ -248,11 +249,14 @@ def fused_page_write(
     """Write K and V slivers through per-row page tables at a static layer
     index, in one kernel launch (the Pallas twin of
     `paged_write_reference`, which remains the XLA/CPU golden). Both
-    pools alias their outputs: HBM traffic is the touched pages alone."""
+    pools alias their outputs: HBM traffic is the touched pages alone.
+    Into a lane-packed pool (ops/lanepack.py) a position's `[K, H]` goes
+    as `[K/f, f*H]`, a reshape: the same bytes, rows of full lanes."""
+    f = pack_factor(kp, k_new.shape[-1])
     return _run_write(
-        _bf16_write_kernel, (kp, vp), k_new.astype(kp.dtype),
-        v_new.astype(vp.dtype), positions, page_table, layer, q_lens,
-        interpret, "fused_page_write")
+        _bf16_write_kernel, (kp, vp), pack_heads(k_new.astype(kp.dtype), f),
+        pack_heads(v_new.astype(vp.dtype), f), positions, page_table, layer,
+        q_lens, interpret, "fused_page_write")
 
 
 @functools.partial(jax.jit, static_argnums=(8,),
@@ -281,7 +285,7 @@ def fused_page_write_quantized(
 
 
 def paged_write_reference(
-    pool: jnp.ndarray,        # [L, P, K, PS, H]
+    pool: jnp.ndarray,        # [L, P, K, PS, H], or lane-packed
     new: jnp.ndarray,         # [B, T, K, H]
     positions: jnp.ndarray,   # [B, T] i32
     page_table: jnp.ndarray,  # [B, NP] i32
@@ -298,7 +302,8 @@ def paged_write_reference(
     pages, offs = _coords(positions, page_table, ps, num_pages, q_lens)
     # Advanced indices at non-adjacent dims (pool page, in-page offset)
     # broadcast to the front: the update is [B, T, K, H] — exactly `new`.
-    return pool.at[layer, pages, :, offs].set(new.astype(pool.dtype))
+    new = pack_heads(new.astype(pool.dtype), pack_factor(pool, new.shape[-1]))
+    return pool.at[layer, pages, :, offs].set(new)
 
 
 def paged_write_reference_quantized(

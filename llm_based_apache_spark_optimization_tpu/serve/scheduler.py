@@ -136,6 +136,7 @@ from ..constrain.masks import CompiledMask, trivial_tables
 from ..engine.kvcache import bucket_len, init_cache
 from ..engine.paged_kv import (
     PageAllocator,
+    check_blob_shape,
     default_page_size,
     export_pages,
     handoff_bytes,
@@ -1020,9 +1021,12 @@ class ContinuousBatchingScheduler:
         # self._ptab, a non-donated arg to every program).
         def make_cache():
             if self._paged:
+                # Stored lane-packed where the heads are narrow
+                # (engine/paged_kv.lane_pack decides; the pool's shape
+                # is the record every program reads it from).
                 pool = init_page_pool(
                     cfg, self._page_alloc.num_pages, self._page_size,
-                    dtype=dtype, kv_quant=kv_quant,
+                    dtype=dtype, kv_quant=kv_quant, tp=tp,
                 )
                 return ((pool["kp"], pool["kps"], pool["vp"], pool["vps"])
                         if kv_quant else (pool["kp"], pool["vp"]))
@@ -1069,6 +1073,10 @@ class ContinuousBatchingScheduler:
         else:
             arrs = make_cache()
         self._cache = arrs
+        # The cache as stored, for /metrics and for refusing a page blob
+        # of another shape: read here, once, from threads that must not
+        # touch a buffer the worker has donated.
+        self._kv_stored_shape = tuple(arrs[0].shape)
 
         # Per-slot state lives ON DEVICE and chains between rounds: decode
         # rounds and admission scatters are issued asynchronously and the
@@ -2236,6 +2244,11 @@ class ContinuousBatchingScheduler:
         out["page_bytes"] = page_bytes(
             self.cfg, self._page_size, self._dtype.itemsize, self.kv_quant
         )
+        # The pool as stored: KV heads a 128-lane row (engine/paged_kv
+        # .lane_pack; 1 is the plain [L, P, K, page, H]) and the shape.
+        pool = self._kv_stored_shape
+        out["kv_pool_lane_pack"] = pool[-1] // self.cfg.head_dim
+        out["kv_pool_shape"] = "x".join(str(d) for d in pool)
         return out
 
     # --------------------------------------------------- performance ledger
@@ -2536,6 +2549,10 @@ class ContinuousBatchingScheduler:
                     # [L, k, K, NP*ps(, H)] for the chunk forward (the same
                     # row gather the contiguous path pays via c[:, slots];
                     # the scale arrays of an int8 pool drop the H axis).
+                    # A lane-packed pool [L, P, K/f, ps, f*H] gives packed
+                    # row views [L, k, K/f, NP*ps, f*H]: forward reads f
+                    # off them, writes the chunk packed, and the window
+                    # scatter below moves rows as they lie.
                     g = pool[:, safe]  # [L, k, NP, K, ps(, H)]
                     perm = ((0, 1, 3, 2, 4, 5) if pool.ndim == 5
                             else (0, 1, 3, 2, 4))
@@ -3969,6 +3986,8 @@ class ContinuousBatchingScheduler:
                     f"handoff blob page size {req.spilled[0].shape[3]} "
                     f"!= this pool's {self._page_size}"
                 )
+            # ... and the same stored shape (heads a row).
+            check_blob_shape(self._kv_stored_shape, req.spilled[0].shape)
         with self._submit_lock:
             if self._closed:
                 if self._crash is not None:
@@ -6187,7 +6206,8 @@ class SchedulerPool:
         # against summed free pages would misread per-pool pressure).
         for k in ("page_size", "overcommit", "spill", "kv_quant",
                   "page_bytes", "watermark_low_pages",
-                  "watermark_high_pages"):
+                  "watermark_high_pages", "kv_pool_lane_pack",
+                  "kv_pool_shape"):
             if k in per[0]:
                 out[k] = per[0][k]
         return out

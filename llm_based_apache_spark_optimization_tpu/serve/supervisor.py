@@ -1752,6 +1752,10 @@ class SupervisedScheduler:
                 "name": self.name, "ts": time.time(),
                 "state": self._state, "restarts": self._restarts,
                 "stalls": self._stalls, "pending": len(pending),
+                # How the KV pool is stored (lane packing): what a page's
+                # bytes in any spilled or exported blob mean.
+                "kv_pool_shape": (self.page_stats or {}).get(
+                    "kv_pool_shape"),
             }
             written = append_jsonl(self.postmortem_path, [
                 header,

@@ -30,7 +30,13 @@ import jax.numpy as jnp
 import pytest
 
 from llm_based_apache_spark_optimization_tpu.models import init_params
-from llm_based_apache_spark_optimization_tpu.models.configs import MISTRAL_7B
+from llm_based_apache_spark_optimization_tpu.engine.paged_kv import (
+    init_page_pool,
+)
+from llm_based_apache_spark_optimization_tpu.models.configs import (
+    MISTRAL_7B,
+    LlamaConfig,
+)
 from llm_based_apache_spark_optimization_tpu.models.llama import forward
 from llm_based_apache_spark_optimization_tpu.ops import pallas as K
 from llm_based_apache_spark_optimization_tpu.ops.pallas import (
@@ -47,6 +53,14 @@ D, F = MISTRAL_7B.hidden_size, MISTRAL_7B.intermediate_size
 WINDOW = MISTRAL_7B.sliding_window
 L, P, PS, NP, B = 2, 128, 64, 16, 8   # a small pool; page size = the default
 BF, I8, F32, I32 = jnp.bfloat16, jnp.int8, jnp.float32, jnp.int32
+# SmolLM2-1.7B's widths (benchmark/configs/smollm2-1.7b-bf16.json): MHA
+# 32/32 at head 64, whose pool is stored two heads a 128-lane row
+# (engine/paged_kv.lane_pack): [L, P, 16, 64, 128].
+SMOL = LlamaConfig(
+    name="smollm2-widths", vocab_size=49152, hidden_size=2048,
+    intermediate_size=8192, num_layers=24, num_heads=32, num_kv_heads=32,
+    head_dim=64, rope_theta=130000.0, max_seq_len=2048, tie_embeddings=True)
+H64, K64, W64 = SMOL.head_dim, SMOL.num_kv_heads // 2, 2 * SMOL.head_dim
 
 
 @pytest.fixture(scope="module")
@@ -117,6 +131,37 @@ def _flash(b, t, s):
     return build
 
 
+def _read64(t):
+    """The ragged read of a lane-packed head-64 pool (two heads a row)."""
+    def build(S):
+        pool = S((L, P, K64, PS, W64), BF)
+        return (lambda q, k, v, tab, pos: K.ragged_paged_attention(
+            q, k, v, tab, pos, 1, None, interpret=False),
+            (S((B, t, N, H64), BF), pool, pool, S((B, NP), I32),
+             S((B, t), I32)))
+    return build
+
+
+def _write64(t):
+    """The fused write of head-64 slivers into the lane-packed pool."""
+    def build(S):
+        pool, new = S((L, P, K64, PS, W64), BF), S((B, t, N, H64), BF)
+        return (lambda kp, vp, k, v, pos, tab: K.fused_page_write(
+            kp, vp, k, v, pos, tab, 1, interpret=False),
+            (pool, pool, new, new, S((B, t), I32), S((B, NP), I32)))
+    return build
+
+
+def _flash64(b, t, s):
+    """Flash attention over the lane-packed row views of batched prefill."""
+    def build(S):
+        kv = S((b, K64, s, W64), BF)
+        return (lambda q, k, v, pos: K.flash_gqa_attention(
+            q, k, v, pos, None, interpret=False),
+            (S((b, t, N, H64), BF), kv, kv, S((b, t), I32)))
+    return build
+
+
 def _int4(rows, n_in, n_out):
     def build(S):
         return (lambda x, q4, s4: int4_matmul(x, q4, s4, interpret=False),
@@ -142,6 +187,13 @@ CASES = {
     "write_int8_T32": _write(32, True),
     "flash_prefill_T128": _flash(8, 128, 2048),
     "flash_decode_T1": _flash(8, 1, 2048),
+    "read_head64_packed_T1": _read64(1),
+    "read_head64_packed_T16": _read64(16),
+    "read_head64_packed_Tmax": _read64(_T_MAX),
+    "write_head64_packed_T1": _write64(1),
+    "write_head64_packed_T32": _write64(32),
+    "flash_head64_packed_prefill_T128": _flash64(4, 128, 2048),
+    "flash_head64_packed_decode_T1": _flash64(4, 1, 2048),
     "int4_matmul_gate": _int4(8, D, F),
     "int4_matmul_down": _int4(8, F, D),
     "int4_matmul_prefill_rows": _int4(512, D, D),
@@ -176,11 +228,18 @@ def test_read_window_bound_is_the_compilers(chip):
             jnp.zeros((1, 2 * t), I32), 0)
 
 
-@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
-def test_decode_step_reads_the_pool_in_place(chip, monkeypatch, quantized):
+@pytest.mark.parametrize("base,quantized", [
+    (MISTRAL_7B, False), (MISTRAL_7B, True), (SMOL, False)],
+    ids=["bf16", "int8", "smollm2_head64"])
+def test_decode_step_reads_the_pool_in_place(chip, monkeypatch, base,
+                                             quantized):
     """The decode path of `forward` over an L=2 paged cache at Mistral
-    widths: both sides lower through Mosaic, and the program makes no copy
-    of a layer's pool. A Mosaic call cannot take a strided view of the
+    widths, and at SmolLM2's: both sides lower through Mosaic, and the
+    program makes no copy of a layer's pool. At head 64 the pool is the
+    one `init_page_pool` stores, two heads a 128-lane row; stored with a
+    minor axis of 64 XLA kept it with the page axis minor and converted K
+    and V whole, in and out, around the Mosaic calls (four copies of pool
+    shape and 8 GiB of temporaries for a 2.5 GiB pool at 24 layers). A Mosaic call cannot take a strided view of the
     stacked loop carry, so a read kernel handed `pool[l]` made XLA
     materialize the slice, every layer of every step (a quarter to a half
     of a decode step's device time on the chip). The pool is sized like a
@@ -191,14 +250,18 @@ def test_decode_step_reads_the_pool_in_place(chip, monkeypatch, quantized):
     say whether a layer is copied."""
     pages = 1024
     monkeypatch.setattr(dispatch, "on_tpu", lambda: True)  # compiled kernels
-    cfg = dataclasses.replace(MISTRAL_7B, num_layers=L)
+    cfg = dataclasses.replace(base, num_layers=L)
     params = jax.tree.map(
         lambda a: chip(a.shape, a.dtype),
         jax.eval_shape(lambda: init_params(cfg, jax.random.key(0), dtype=BF)))
-    pool = chip((L, pages, KH, PS, H), I8 if quantized else BF)
-    cache = {"kp": pool, "vp": pool, "ptab": chip((B, NP), I32)}
-    if quantized:
-        cache["kps"] = cache["vps"] = chip((L, pages, KH, PS), F32)
+    cache = {n: chip(a.shape, a.dtype) for n, a in jax.eval_shape(
+        lambda: init_page_pool(cfg, pages, PS, BF,
+                               "int8" if quantized else None)).items()}
+    pool = cache["kp"]
+    kh, h = pool.shape[2], pool.shape[4]
+    assert pool.shape == ((L, pages, 16, PS, 128) if base is SMOL
+                          else (L, pages, KH, PS, H))
+    cache["ptab"] = chip((B, NP), I32)
 
     def step(params, cache, tokens, positions, kv_lens):
         return forward(cfg, params, tokens, positions, cache,
@@ -209,9 +272,14 @@ def test_decode_step_reads_the_pool_in_place(chip, monkeypatch, quantized):
     ).compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2 * L
-    one_layer = re.compile(rf"= \w+\[(1,)?{pages},{KH},{PS},{H}\]")
+    # No result of a layer's pool or of the whole pool (in any layout)
+    # but the donated pool's own parameters and the aliased custom calls.
+    one_layer = re.compile(rf"= \w+\[(1,)?{pages},{kh},{PS},{h}\]")
     assert not [ln[:160] for ln in text.splitlines() if one_layer.search(ln)]
-    layer_bytes = pages * KH * PS * H * pool.dtype.itemsize
+    whole = re.compile(rf"= \w+\[{L},{pages},{kh},{PS},{h}\]\S* "
+                       r"(copy|transpose|bitcast-convert|fusion)\(")
+    assert not [ln[:160] for ln in text.splitlines() if whole.search(ln)]
+    layer_bytes = pages * kh * PS * h * pool.dtype.itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes / 2
 
 

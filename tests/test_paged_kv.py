@@ -1505,3 +1505,242 @@ def test_page_stats_reports_true_int8_capacity(tiny):
     # The reported page_bytes reconcile the pool's actual device arrays.
     assert st8["page_bytes"] * st8["pages_total"] == \
         sum(a.nbytes for a in s8._cache)
+
+
+# ------------------------------------------- lane-packed pool (ISSUE 33) --
+# A head narrower than the 128-lane tile is stored `f` heads a row
+# (engine/paged_kv.lane_pack, ops/lanepack.py): [L, P, K/f, page, f*H].
+# Everything below holds the packed pool to the LOGICAL one — the read to
+# `paged_attention_reference` on the logical pool and to the same kernel
+# on it; the writes bit for bit.
+
+import dataclasses  # noqa: E402
+
+from llm_based_apache_spark_optimization_tpu.engine.paged_kv import (  # noqa: E402
+    export_pages,
+    import_pages,
+    lane_pack,
+)
+from llm_based_apache_spark_optimization_tpu.ops.lanepack import (  # noqa: E402
+    pack_cache,
+    pack_heads,
+    unpack_cache,
+)
+
+
+def _widths(num_heads, num_kv_heads, head_dim):
+    from llm_based_apache_spark_optimization_tpu.models import TINY
+
+    return dataclasses.replace(TINY, num_heads=num_heads,
+                               num_kv_heads=num_kv_heads, head_dim=head_dim)
+
+
+@pytest.mark.parametrize("heads,kv_quant,tp,want", [
+    ((4, 4, 64), None, 1, 2),       # MHA at head 64: two heads a row
+    ((8, 2, 64), None, 1, 2),       # GQA 8/2 at head 64
+    ((32, 32, 64), None, 1, 2),     # SmolLM2-1.7B
+    ((32, 8, 64), None, 4, 2),      # Llama-3.2-1B, K/f = 4 over tp = 4
+    ((8, 4, 32), None, 1, 4),       # four heads of 32 a row
+    ((32, 8, 128), None, 1, 1),     # Mistral-7B: a full row already
+    ((4, 4, 64), "int8", 1, 1),     # an int8 pool keeps its scales a head
+    ((4, 4, 16), None, 1, 1),       # the benchmark's rehearsal widths
+    ((4, 2, 8), None, 1, 1),        # TINY
+    ((6, 3, 64), None, 1, 1),       # an odd number of KV heads
+    ((8, 4, 64), None, 4, 1),       # K/f = 2 would not divide over tp = 4
+    ((8, 4, 64), None, 2, 2),
+    ((4, 4, 48), None, 1, 1),       # 128 is no multiple of the head
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_lane_pack_decides_the_stored_shape(heads, kv_quant, tp, want):
+    cfg = _widths(*heads)
+    assert lane_pack(cfg, kv_quant, tp) == want
+    pool = jax.eval_shape(
+        lambda: init_page_pool(cfg, 5, 16, jnp.bfloat16, kv_quant, tp))
+    kh, h = cfg.num_kv_heads, cfg.head_dim
+    assert pool["kp"].shape == pool["vp"].shape == (
+        cfg.num_layers, 5, kh // want, 16, want * h)
+    if want == 1:  # today's pool, to the shape
+        assert pool["kp"].shape == (cfg.num_layers, 5, kh, 16, h)
+    if kv_quant:
+        assert pool["kps"].shape == (cfg.num_layers, 5, kh, 16)
+    # Packing moves bytes and buys none: the budget's pages are the same.
+    assert 2 * np.prod(pool["kp"].shape) * (1 if kv_quant else 2) + (
+        8 * np.prod(pool["kps"].shape) if kv_quant else 0) == 5 * page_bytes(
+        cfg, 16, 2, kv_quant)
+
+
+@pytest.mark.parametrize("f,kh,h", [(2, 4, 64), (2, 2, 64), (4, 8, 32),
+                                    (1, 8, 128)])
+def test_lane_pack_then_unpack_is_the_identity(rng, f, kh, h):
+    x = jnp.asarray(rng.normal(size=(2, 3, kh, 8, h)), jnp.float32)
+    packed = pack_cache(x, f)
+    assert packed.shape == (2, 3, kh // f, 8, f * h)
+    np.testing.assert_array_equal(np.asarray(unpack_cache(packed, f)),
+                                  np.asarray(x))
+    # packed[..., j, s, i*H + d] == logical[..., f*j + i, s, d]
+    j, i, s, d = kh // f - 1, f - 1, 5, h - 3
+    assert packed[1, 2, j, s, i * h + d] == x[1, 2, f * j + i, s, d]
+    # A position's fresh K/V packs by a reshape to the same row.
+    fresh = x[:, :, :, s, :]                                # [.., K, H]
+    np.testing.assert_array_equal(np.asarray(pack_heads(fresh, f)),
+                                  np.asarray(packed[:, :, :, s, :]))
+
+
+_PACK_HEADS = {"mha4x4_h64": (4, 4, 64), "gqa8x2_h64": (8, 2, 64)}
+_PACK_L, _PACK_P, _PACK_PS, _PACK_NP = 3, 20, 8, 4
+
+
+def _pack_case(rng, heads, case):
+    """(q, logical pools, table, positions, kv_lens, q_lens, window)."""
+    n, kh, h = _PACK_HEADS[heads]
+    b, t = 4, (1 if case == "T1" else 3)
+    ps, np_tab = _PACK_PS, _PACK_NP
+    pools = tuple(
+        jnp.asarray(rng.normal(size=(_PACK_L, _PACK_P, kh, ps, h)),
+                    jnp.bfloat16) for _ in range(2))
+    tab = np.stack([rng.permutation(_PACK_P)[:np_tab] for _ in range(b)])
+    kvl = np.asarray([ps // 2 + 1, 2 * ps + 3, 3 * ps, np_tab * ps], np.int32)
+    q_lens = np.full((b,), t, np.int32)
+    window = None
+    if case == "ragged":        # q_lens short of T
+        q_lens = np.asarray([1, 3, 2, 3], np.int32)
+    elif case == "parked":      # kv_lens = 0 parks a row
+        kvl[2] = 0
+    elif case == "sliding":
+        window = ps + 3
+    elif case == "unmapped":    # a sentinel past the live pages
+        tab[0, 1:] = _PACK_P
+        tab[1, 3] = _PACK_P
+    pos = np.full((b, t), np_tab * ps - 1, np.int32)
+    for bi in range(b):
+        top = max(int(kvl[bi]), int(q_lens[bi]))
+        pos[bi, :q_lens[bi]] = top - q_lens[bi] + np.arange(q_lens[bi])
+    q = jnp.asarray(rng.normal(size=(b, t, n, h)), jnp.bfloat16)
+    return (q, pools, jnp.asarray(tab, jnp.int32), jnp.asarray(pos),
+            jnp.asarray(kvl), jnp.asarray(q_lens), window)
+
+
+@pytest.mark.parametrize("case", ["T1", "ragged", "parked", "sliding",
+                                  "unmapped"])
+@pytest.mark.parametrize("heads", sorted(_PACK_HEADS))
+def test_packed_read_is_the_reference_on_the_logical_pool(rng, heads, case):
+    from llm_based_apache_spark_optimization_tpu.ops.pallas import (
+        paged_attention_reference,
+        ragged_paged_attention,
+    )
+
+    q, pools, tab, pos, kvl, q_lens, window = _pack_case(rng, heads, case)
+    packed = tuple(pack_cache(p, 2) for p in pools)
+    assert packed[0].shape[-1] == 128
+    layer = 1
+    out = np.asarray(ragged_paged_attention(
+        q, *packed, tab, pos, layer, window, kvl, q_lens), np.float32)
+    ref = np.asarray(paged_attention_reference(
+        q, *(p[layer] for p in pools), tab, pos, window, kvl, q_lens),
+        np.float32)
+    np.testing.assert_allclose(out, ref, atol=3e-2, rtol=2e-2)
+    # The zeros a spread query row adds to the f32 scores are exact, so
+    # the packed kernel is the logical kernel up to the order of an f32
+    # sum (a bf16 ulp at the most, here none); the reference handed the
+    # packed pool unpacks it and is the logical reference to the bit.
+    np.testing.assert_allclose(out, np.asarray(ragged_paged_attention(
+        q, *pools, tab, pos, layer, window, kvl, q_lens), np.float32),
+        rtol=2**-7, atol=1e-3)
+    np.testing.assert_array_equal(ref, np.asarray(paged_attention_reference(
+        q, *(p[layer] for p in packed), tab, pos, window, kvl, q_lens),
+        np.float32))
+    for bi in range(out.shape[0]):                         # dead columns
+        assert np.abs(out[bi, int(q_lens[bi]):]).sum() == 0.0
+    if case == "parked":
+        assert np.abs(out[2]).max() == 0.0
+    assert np.abs(out - np.asarray(ragged_paged_attention(
+        q, *packed, tab, pos, 0, window, kvl, q_lens), np.float32)).max() > .1
+
+
+@pytest.mark.parametrize("path", ["kernel", "xla"])
+@pytest.mark.parametrize("heads", sorted(_PACK_HEADS))
+def test_packed_write_is_the_logical_write(rng, heads, path):
+    from llm_based_apache_spark_optimization_tpu.ops.pallas import (
+        fused_page_write,
+        paged_write_reference,
+    )
+
+    _, kh, h = _PACK_HEADS[heads]
+    L, P, ps, b, t, np_tab = 2, 16, 8, 4, 6, 3
+    tab = rng.permutation(P)[: b * np_tab].reshape(b, np_tab)
+    tab[1, :] = P                                   # a parked row
+    tab = jnp.asarray(tab, jnp.int32)
+    q_lens = jnp.asarray([6, 6, 2, 5], jnp.int32)   # dead columns
+    positions = jnp.asarray(
+        np.array([5, 0, 13, 20])[:, None] + np.arange(t), jnp.int32)
+    kp, vp = (jnp.asarray(rng.normal(size=(L, P, kh, ps, h)), jnp.float32)
+              for _ in range(2))
+    k_new, v_new = (jnp.asarray(rng.normal(size=(b, t, kh, h)), jnp.float32)
+                    for _ in range(2))
+    want = (paged_write_reference(kp, k_new, positions, tab, 1, q_lens),
+            paged_write_reference(vp, v_new, positions, tab, 1, q_lens))
+    pk, pv = pack_cache(kp, 2), pack_cache(vp, 2)
+    if path == "kernel":
+        got = fused_page_write(pk, pv, k_new, v_new, positions, tab, 1,
+                               q_lens=q_lens)
+    else:
+        got = (paged_write_reference(pk, k_new, positions, tab, 1, q_lens),
+               paged_write_reference(pv, v_new, positions, tab, 1, q_lens))
+    for g, w in zip(got, want):
+        assert g.shape == (L, P, kh // 2, ps, 2 * h)
+        np.testing.assert_array_equal(np.asarray(unpack_cache(g, 2)),
+                                      np.asarray(w))
+    assert np.abs(np.asarray(want[0]) - np.asarray(kp)).max() > 0
+
+
+def test_pack_prefill_pages_lane_packed(rng):
+    """The engines' prefill -> pool hand-off stores what `init_page_pool`
+    would: the packed pool is the plain pool, packed."""
+    cache = {n: jnp.asarray(rng.normal(size=(2, 3, 4, 20, 64)), jnp.float32)
+             for n in ("k", "v")}
+    plain = pack_prefill_pages(cache, 8, 4)
+    packed = pack_prefill_pages(cache, 8, 4, pack=2)
+    assert packed["kp"].shape == (2, 12, 2, 8, 128)
+    for n in ("kp", "vp"):
+        np.testing.assert_array_equal(np.asarray(unpack_cache(packed[n], 2)),
+                                      np.asarray(plain[n]))
+    np.testing.assert_array_equal(np.asarray(packed["ptab"]),
+                                  np.asarray(plain["ptab"]))
+
+
+def test_import_pages_refuses_another_stored_shape(rng):
+    cfg = _widths(4, 4, 64)
+    packed = init_page_pool(cfg, 6, 8, jnp.float32)
+    plain = {n: jnp.zeros((cfg.num_layers, 6, 4, 8, 64), jnp.float32)
+             for n in ("kp", "vp")}
+    assert packed["kp"].shape == (cfg.num_layers, 6, 2, 8, 128)
+    src = tuple(jnp.asarray(rng.normal(size=packed["kp"].shape), jnp.float32)
+                for _ in range(2))
+    blob = export_pages(src, [4, 1])
+    assert blob[0].shape == (cfg.num_layers, 2, 2, 8, 128)
+    out = import_pages((packed["kp"], packed["vp"]), [0, 5], blob)
+    np.testing.assert_array_equal(np.asarray(out[0][:, 5]),
+                                  np.asarray(src[0][:, 1]))
+    with pytest.raises(ValueError) as e:
+        import_pages((plain["kp"], plain["vp"]), [0, 5], blob)
+    assert "(2, 2, 2, 8, 128)" in str(e.value)
+    assert "(2, 6, 4, 8, 64)" in str(e.value)
+
+
+def test_sharded_read_kernel_takes_a_lane_packed_stack():
+    """Under tp the packed head axis shards like the plain one (`lane_pack`
+    packs only where K/f still divides over tp): each device reads `f` off
+    its own shard, and the output is one device's."""
+    from llm_based_apache_spark_optimization_tpu.ops.pallas import (
+        ragged_paged_attention,
+        sharded_ragged_paged_attention,
+    )
+    from llm_based_apache_spark_optimization_tpu.parallel import make_mesh
+
+    q, pools, tab, pos, kvl, q_lens = _stack_case("mha32_h64", False, True)
+    packed = tuple(pack_cache(p, 2) for p in pools)        # K/f = 16
+    mesh = make_mesh(dp=1, tp=4, devices=jax.devices()[:4])
+    np.testing.assert_array_equal(
+        np.asarray(sharded_ragged_paged_attention(
+            mesh, q, *packed, tab, pos, 1, None, kvl, q_lens), np.float32),
+        np.asarray(ragged_paged_attention(
+            q, *packed, tab, pos, 1, None, kvl, q_lens), np.float32))

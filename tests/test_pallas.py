@@ -370,3 +370,39 @@ def test_flash_quantized_sharded_matches_single(  ):
     )
     np.testing.assert_allclose(np.asarray(sharded), np.asarray(single),
                                rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("b,t,s,n,kh,h,f,window", [
+    (2, 1, 32, 4, 4, 64, 2, None),     # decode grid, MHA at head 64
+    (2, 8, 32, 8, 2, 64, 2, None),     # prefill grid, GQA 8/2 at head 64
+    (2, 4, 40, 4, 4, 64, 2, 12),       # sliding window, ragged last block
+    (1, 4, 24, 8, 4, 32, 4, None),     # four heads of 32 a row
+], ids=["decode_mha_h64", "prefill_gqa_h64", "window_h64", "prefill_h32"])
+def test_flash_takes_a_lane_packed_cache(b, t, s, n, kh, h, f, window):
+    """Batched prefill's row views of a lane-packed pool reach the flash
+    kernel as `[B, K/f, S, f*H]` (ops/lanepack.py): to the kernel a GQA
+    cache of K/f heads of width 128, scored at the TRUE head's scale. The
+    zeros a spread query adds are exact; what may differ from the plain
+    layout is the order of an f32 sum over 128 lanes for 64."""
+    from llm_based_apache_spark_optimization_tpu.ops.lanepack import (
+        pack_cache,
+    )
+
+    kq, kk, kv = jax.random.split(jax.random.key(7), 3)
+    q = jax.random.normal(kq, (b, t, n, h), jnp.float32)
+    k = jax.random.normal(kk, (b, kh, s, h), jnp.float32)
+    v = jax.random.normal(kv, (b, kh, s, h), jnp.float32)
+    positions = (s - t - 1) + jnp.arange(t, dtype=jnp.int32)[None, :] \
+        - jnp.arange(b, dtype=jnp.int32)[:, None]
+    kv_lens = jnp.max(positions, axis=1) + 1
+    plain = flash_gqa_attention(q, k, v, positions, window, kv_lens,
+                                block_kv=16, interpret=True)
+    pk, pv = pack_cache(k, f), pack_cache(v, f)
+    assert pk.shape == (b, kh // f, s, f * h)
+    packed = flash_gqa_attention(q, pk, pv, positions, window, kv_lens,
+                                 block_kv=16, interpret=True)
+    np.testing.assert_allclose(np.asarray(packed), np.asarray(plain),
+                               rtol=1e-5, atol=1e-6)
+    ref = gqa_attention(q, k, v, attention_mask(positions, s, window))
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(packed),
+                               rtol=2e-5, atol=2e-5)

@@ -1047,3 +1047,138 @@ def test_sweep_page_wait_fails_expired_in_deadline_order(
         with pytest.raises(DeadlineExceeded):
             r.future.result(timeout=1)
     assert list(sched._page_wait) == [alive]
+
+
+# ------------------------------------------ lane-packed KV pool (ISSUE 33) --
+# At a head narrower than 128 lanes the paged pool is stored f heads a row
+# (engine/paged_kv.lane_pack). The packed scheduler must serve the tokens
+# of the unpacked path — the same scheduler with `lane_pack` held to 1,
+# which is the parent's program — through every operation that touches a
+# page's bytes.
+
+
+@pytest.fixture(scope="module")
+def head64_model():
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from llm_based_apache_spark_optimization_tpu.models import TINY, init_params
+
+    cfg = dataclasses.replace(TINY, name="tiny-head64", num_heads=4,
+                              num_kv_heads=4, head_dim=64)
+    return cfg, init_params(cfg, jax.random.key(3), dtype=jnp.float32)
+
+
+def _paged64(cfg, params, monkeypatch=None, **kw):
+    """A paged scheduler at head 64: lane-packed as built, or — handed a
+    `monkeypatch` — the unpacked reference path."""
+    from llm_based_apache_spark_optimization_tpu.engine import paged_kv
+
+    kw.setdefault("kv_layout", "paged")
+    kw.setdefault("kv_page_size", 16)
+    if monkeypatch is None:
+        sched = make_sched(cfg, params, **kw)
+        assert sched._cache[0].shape[2:] == (2, kw["kv_page_size"], 128)
+        assert sched.page_stats["kv_pool_lane_pack"] == 2
+        return sched
+    with monkeypatch.context() as m:
+        m.setattr(paged_kv, "lane_pack", lambda *a, **k: 1)
+        sched = make_sched(cfg, params, **kw)
+    assert sched._cache[0].shape[2:] == (4, kw["kv_page_size"], 64)
+    assert sched.page_stats["kv_pool_lane_pack"] == 1
+    return sched
+
+
+_LONG = [[1] + [5 + (3 * i + j) % 40 for j in range(n)]
+         for i, n in enumerate((19, 9, 26, 13))]
+_SHARED = [1] + list(range(5, 28))       # 24 tokens: 3 blocks, 1.5 pages
+
+
+@pytest.mark.parametrize("case", ["chunked_prefill", "prefix_hit_cow",
+                                  "spill_restore", "speculative_windows",
+                                  "pallas_kernels"])
+def test_lane_packed_pool_serves_the_unpacked_tokens(
+        head64_model, monkeypatch, case):
+    from llm_based_apache_spark_optimization_tpu.utils.faults import FAULTS
+
+    cfg, params = head64_model
+    kw, pressure, max_new = {}, None, 6
+    prompts = _LONG                       # prompts of 2-4 chunks of 8
+    if case == "prefix_hit_cow":
+        # Blocks of 8 end mid-page (pages of 16): a hit shares the full
+        # page and copies the boundary page (`copy_page`) before writing.
+        prompts = [_SHARED + [40 + i] for i in range(6)]
+    elif case == "spill_restore":
+        # A pressure storm preempts a victim, spills its pages to the
+        # host (`export_pages`) and restores them (`import_pages`).
+        kw = dict(max_seq=64, kv_page_size=8, kv_overcommit=0.25,
+                  kv_pages=9, kv_spill=True)
+        pressure, prompts, max_new = "kv:pressure:1:3", PROMPTS, 24
+    elif case == "speculative_windows":
+        kw = dict(speculative_draft=3)    # verify windows, T = D + 1
+
+    def run(build, impl="auto"):
+        from llm_based_apache_spark_optimization_tpu.ops.pallas import (
+            set_attention_impl,
+        )
+
+        set_attention_impl(impl)          # read when a scheduler is built
+        if pressure:
+            FAULTS.configure(pressure, 0)
+        try:
+            with build() as s:
+                outs = [f.result(timeout=300) for f in
+                        [s.submit(p, max_new_tokens=max_new)
+                         for p in prompts]]
+                return outs, dict(s.page_stats), s.kernel_modes()
+        finally:
+            FAULTS.clear()
+            set_attention_impl("auto")
+
+    want, _, _ = run(lambda: _paged64(cfg, params, monkeypatch, **kw))
+    # The packed side of "pallas_kernels" runs the interpreted kernels —
+    # flash over packed row views, the fused write, the ragged read —
+    # against the unpacked einsum path.
+    got, stats, modes = run(
+        lambda: _paged64(cfg, params, **kw),
+        "pallas" if case == "pallas_kernels" else "auto")
+    assert got == want
+    assert modes["page_write"] == modes["decode_attention"] == (
+        "pallas" if case == "pallas_kernels" else "xla")
+    if case == "chunked_prefill":
+        assert got == engine_golden(cfg, params, prompts, max_new)
+    elif case == "prefix_hit_cow":
+        assert stats["zero_copy_shares"] > 0 and stats["cow_copies"] > 0
+    elif case == "spill_restore":
+        assert stats["preemptions"] >= 1
+        assert stats["spilled_pages"] == stats["restored_pages"] > 0
+
+
+def test_lane_packed_handoff_round_trip_and_refusal(head64_model,
+                                                    monkeypatch):
+    """A prefill replica's exported pages (`export_pages`) restore into a
+    decode replica of the same stored shape (`import_pages`) and decode
+    there to the mixed scheduler's tokens; a replica whose pool is stored
+    otherwise refuses the blob and names both shapes."""
+    cfg, params = head64_model
+    with _paged64(cfg, params) as mixed:
+        want = mixed.generate([_LONG[0]], max_new_tokens=6)[0]
+    ready = threading.Event()
+    pre = _paged64(cfg, params, phase_role="prefill")
+    pre.on_handoff = ready.set
+    with pre:
+        fut = pre.submit(_LONG[0], max_new_tokens=6)
+        assert ready.wait(60)
+        (req,) = pre.extract_handoffs()
+        assert req.spilled[0].shape[2:] == (2, 16, 128)
+        with _paged64(cfg, params, monkeypatch, phase_role="decode") as other:
+            with pytest.raises(ValueError) as e:
+                other.requeue(req)
+            assert "(2, 2, 2, 16, 128)" in str(e.value)
+            assert "4, 16, 64)" in str(e.value)
+        with _paged64(cfg, params, phase_role="decode") as dec:
+            dec.requeue(req)
+            assert fut.result(timeout=120) == want
+            assert dec.handoff_stats["imports"] == 1
