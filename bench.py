@@ -290,12 +290,12 @@ def _param_bytes(params) -> int:
     return sum(x.nbytes for x in jax.tree.leaves(params))
 
 
-def _paged_accounting(cfg, *, slots_contiguous, max_seq, max_new,
+def _paged_accounting(cfg, *, slots_rows, max_seq, max_new,
                       overshoot, mix_lens, page_size=64, itemsize=2,
                       prompt_bucket=128, kv_quant=None):
     """Slots-at-fixed-HBM: how many concurrent requests of a mixed-length
-    traffic sample the PAGED layout admits inside the HBM the contiguous
-    layout spends on `slots_contiguous` worst-case rows. Pure host math
+    traffic sample the page pool admits inside the HBM that
+    `slots_rows` worst-case rows of max_seq tokens take. Pure host math
     over the same sizing functions the scheduler allocates with
     (engine/kvcache.cache_bytes, engine/paged_kv.page_bytes), so the
     artifact's numbers reconcile by construction — a tier-1 test asserts
@@ -313,10 +313,10 @@ def _paged_accounting(cfg, *, slots_contiguous, max_seq, max_new,
 
     # kv_quant prices the pool's KV dtype (engine/paged_kv.page_bytes):
     # an int8 pool's pages cost ~half a compute-dtype page, so the SAME
-    # contiguous-bf16 HBM budget buys ~2x the pages — the slots-at-fixed-
+    # bf16 worst-case-rows HBM budget buys ~2x the pages — the slots-at-fixed-
     # HBM lever ISSUE 11 ships (int8 strictly more slots than bf16,
     # asserted by the tier-1 reconciliation test).
-    budget = cache_bytes(cfg, slots_contiguous, max_seq, itemsize)
+    budget = cache_bytes(cfg, slots_rows, max_seq, itemsize)
     pages_total = budget // page_bytes(cfg, page_size, itemsize, kv_quant)
     needs = []
     for ln in mix_lens:
@@ -346,7 +346,7 @@ def _paged_accounting(cfg, *, slots_contiguous, max_seq, max_new,
         "page_size": page_size,
         "hbm_budget_bytes": budget,
         "pages_total": pages_total,
-        "slots_contiguous": slots_contiguous,
+        "slots_rows": slots_rows,
         "slots_paged": len(admitted),
         "pages_used": used,
         "pages_per_request": admitted,
@@ -357,8 +357,8 @@ def _paged_accounting(cfg, *, slots_contiguous, max_seq, max_new,
         "prompt_bucket": prompt_bucket,
         "max_seq": max_seq,
         "kv_quant": kv_quant or "",
-        "slots_ratio": (round(len(admitted) / slots_contiguous, 2)
-                        if slots_contiguous else 0.0),
+        "slots_ratio": (round(len(admitted) / slots_rows, 2)
+                        if slots_rows else 0.0),
     }
 
 
@@ -764,18 +764,17 @@ def _bench_long(cfg, params) -> dict:
 
 
 def _bench_long_paged(cfg, params, p, n) -> dict:
-    """Paged-vs-contiguous KV at FIXED HBM (ISSUE 7 acceptance leg):
+    """The page pool at FIXED HBM (ISSUE 7 acceptance leg):
 
     - `accounting`: slots-at-fixed-HBM for a mixed-length traffic sample
-      (half full-length, half quarter-length prompts) — the analytic
-      concurrency ratio, reconciled by a tier-1 test.
-    - `contiguous` / `paged`: the same mixed workload with a shared
-      schema prefix driven through two real schedulers (the paged one
-      capped at the contiguous layout's HBM via kv_hbm_budget_bytes),
-      recording tok/s plus the allocator counters that prove prefix hits
-      SHARED pages (zero_copy_shares) instead of copying them
-      (cow_copies stays at boundary counts; the contiguous path's
-      blocks_reused are all gather-copies)."""
+      (half full-length, half quarter-length prompts) — how many
+      requests the pool admits inside the HBM that `slots_rows`
+      worst-case max_seq rows would take, reconciled by a tier-1 test.
+    - `paged`: the same mixed workload with a shared schema prefix
+      driven through a real scheduler capped at that HBM via
+      kv_hbm_budget_bytes, recording tok/s plus the allocator counters
+      that prove prefix hits SHARED pages (zero_copy_shares) instead of
+      copying them (cow_copies stays at boundary counts)."""
     import time as _t
 
     import numpy as np
@@ -802,15 +801,15 @@ def _bench_long_paged(cfg, params, p, n) -> dict:
     ps = default_page_size()
     mix = [p, max(32, p // 4)]
     acct = _paged_accounting(
-        cfg, slots_contiguous=slots_c, max_seq=max_seq, max_new=max_new,
+        cfg, slots_rows=slots_c, max_seq=max_seq, max_new=max_new,
         overshoot=overshoot, mix_lens=mix, page_size=ps,
         prompt_bucket=pb,
     )
     # Slots-at-fixed-HBM for the INT8 pool (ISSUE 11 acceptance): the
-    # same contiguous-bf16 budget, priced at int8 page bytes — strictly
+    # same bf16 worst-case-rows budget, priced at int8 page bytes — strictly
     # more admitted slots than the bf16 pool (tier-1 reconciles).
     acct8 = _paged_accounting(
-        cfg, slots_contiguous=slots_c, max_seq=max_seq, max_new=max_new,
+        cfg, slots_rows=slots_c, max_seq=max_seq, max_new=max_new,
         overshoot=overshoot, mix_lens=mix, page_size=ps,
         prompt_bucket=pb, kv_quant="int8",
     )
@@ -848,22 +847,10 @@ def _bench_long_paged(cfg, params, p, n) -> dict:
                 best = max(best, toks / dt if dt > 0 else 0.0)
         return best
 
-    sched_c = ContinuousBatchingScheduler(
-        cfg, params, num_slots=slots_c, max_seq=max_seq,
-        prompt_bucket=pb, decode_chunk=decode_chunk, stop_ids=(-1,),
-    )
-    out["contiguous"] = {
-        "slots": slots_c,
-        "tok_s": round(drive(sched_c), 1),
-        "prefix": dict(sched_c.prefix_stats),
-        "hbm_budget_bytes": cache_bytes(cfg, slots_c, max_seq),
-    }
-    del sched_c
-
     sched_p = ContinuousBatchingScheduler(
         cfg, params, num_slots=max(1, min(acct["slots_paged"], 4 * slots_c)),
         max_seq=max_seq, prompt_bucket=pb, decode_chunk=decode_chunk,
-        stop_ids=(-1,), kv_layout="paged", kv_page_size=ps,
+        stop_ids=(-1,), kv_page_size=ps,
         kv_hbm_budget_bytes=cache_bytes(cfg, slots_c, max_seq),
     )
     out["paged"] = {
@@ -873,10 +860,6 @@ def _bench_long_paged(cfg, params, p, n) -> dict:
         "kv_pages": dict(sched_p.page_stats),
     }
     del sched_p
-    if out["contiguous"]["tok_s"]:
-        out["tok_s_ratio"] = round(
-            out["paged"]["tok_s"] / out["contiguous"]["tok_s"], 2
-        )
     # The INT8 pool through a real scheduler at the SAME HBM budget: the
     # kv-dtype-aware sizing grants ~2x the pages, so strictly more slots
     # fit (mirrors accounting_int8 with live traffic; 1 rep — the pass
@@ -888,7 +871,7 @@ def _bench_long_paged(cfg, params, p, n) -> dict:
         cfg, params, num_slots=max(1, min(acct8["slots_paged"],
                                           4 * slots_c)),
         max_seq=max_seq, prompt_bucket=pb, decode_chunk=decode_chunk,
-        stop_ids=(-1,), kv_layout="paged", kv_page_size=ps,
+        stop_ids=(-1,), kv_page_size=ps,
         kv_quant="int8",
         kv_hbm_budget_bytes=cache_bytes(cfg, slots_c, max_seq),
     )
@@ -955,7 +938,7 @@ def _bench_kv_pressure(cfg, params, *, slots, max_new, prompt_bucket,
         sched = ContinuousBatchingScheduler(
             cfg, params, num_slots=slots, max_seq=max_seq,
             prompt_bucket=prompt_bucket, decode_chunk=decode_chunk,
-            stop_ids=(-1,), kv_layout="paged", kv_page_size=page_size,
+            stop_ids=(-1,), kv_page_size=page_size,
             kv_pages=pool_pages, kv_overcommit=ratio,
         )
         sched.warmup(prompt_bucket)
@@ -1802,6 +1785,10 @@ def _bench_pool_affinity(cfg, params, n_per_schema: int = 4,
         return ContinuousBatchingScheduler(
             cfg, params, num_slots=1, max_seq=64, prompt_bucket=block,
             stop_ids=(-1,), decode_chunk=4, prefix_cache_blocks=8,
+            # One schema block a page: the pool of a one-slot replica is
+            # too small to keep a 64-token page resident beside its COW
+            # copy.
+            kv_page_size=block,
         )
 
     def drive(affinity: bool) -> dict:
@@ -1901,7 +1888,7 @@ def _bench_disagg(cfg, params, n_long: int = 3, n_short: int = 3,
             cfg, params, num_slots=2, max_seq=max_seq,
             prompt_bucket=bucket, stop_ids=(-1,),
             decode_chunk=decode_chunk, prefix_cache_blocks=0,
-            kv_layout="paged", kv_page_size=8, phase_role=role,
+            kv_page_size=8, phase_role=role,
         )
 
     def drive(roles):
@@ -2018,7 +2005,7 @@ def _bench_qos(cfg, params, n_batch: int = 4, n_inter: int = 3,
             cfg, params, num_slots=2, max_seq=max_seq,
             prompt_bucket=bucket, stop_ids=(-1,),
             decode_chunk=decode_chunk, prefix_cache_blocks=0,
-            kv_layout="paged", kv_page_size=8,
+            kv_page_size=8,
         )
     finally:
         if saved is None:
@@ -2241,7 +2228,7 @@ def _bench_disagg_remote(cfg, params, n_long: int = 3, n_short: int = 3,
             cfg, params, num_slots=2, max_seq=max_seq,
             prompt_bucket=bucket, stop_ids=(-1,),
             decode_chunk=decode_chunk, prefix_cache_blocks=0,
-            kv_layout="paged", kv_page_size=8, phase_role=role,
+            kv_page_size=8, phase_role=role,
         )
 
     def drive(worker_role, local_role):
@@ -2439,7 +2426,7 @@ def _bench_ragged(cfg, params, *, slots, decode_chunk) -> dict:
             cfg, params, num_slots=slots, max_seq=max_seq,
             prompt_bucket=bucket, stop_ids=(-1,),
             decode_chunk=decode_chunk, prefix_cache_blocks=0,
-            kv_layout="paged", ragged=ragged,
+            ragged=ragged,
         )
         sched.warmup(prompt_len)
         ttfts: list = []

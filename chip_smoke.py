@@ -505,9 +505,20 @@ def serve_one_chip(seed: int, rehearse: bool, clog: CompileLog) -> None:
             out.get("error") == "SQL execution failed"
             and "sql_query" in out and "error_details" in out), out
 
-        prof = srv.get("/debug/profile")
         window = {"window_s": round(time.time() - window_t0, 1),
                   **clog.since(mark)}
+        # The capture is stopped on a thread of its own, beside the
+        # serving loop, and that takes 30-55 s on a TPU: wait for its
+        # outcome, not for a time.
+        profile_deadline = time.time() + 180.0
+        while True:
+            prof = srv.get("/debug/profile")
+            done = [c["last"] for c in prof["captures"].values()
+                    if isinstance(c, dict) and c.get("last")]
+            if done or time.time() > profile_deadline:
+                break
+            time.sleep(1.0)
+        assert done, f"the armed capture never finished: {prof}"
 
         metrics = srv.get("/metrics")
         serving = metrics["duckdb-nsql"]["serving"]
@@ -528,8 +539,7 @@ def serve_one_chip(seed: int, rehearse: bool, clog: CompileLog) -> None:
         registry = srv.get("/debug/prefixcache")["models"]
         assert registry["duckdb-nsql"], registry
 
-        last = next(c["last"] for c in prof["captures"].values()
-                    if isinstance(c, dict) and c.get("last"))
+        last = done[0]
         assert last["state"] == "done" and last["artifacts"], last
         from llm_based_apache_spark_optimization_tpu.utils.traceprof import Trace
 

@@ -23,6 +23,7 @@ import sys
 
 from ..history import SQLiteHistory
 from ..serve import EngineBackend, FakeBackend, GenerationService
+from ..serve.scheduler import kv_layout_flag
 from ..sql import default_backend
 from ..utils.jaxenv import force_cpu, place_compile_cache
 from .api import create_api_app
@@ -55,7 +56,6 @@ def _spill_path(app_cfg, tag: str):
 def make_tiny_service(
     max_new_tokens: int, scheduler: bool = False, tp: int = 1,
     supervise: bool = True, speculative: int = 0,
-    kv_layout: str = "contiguous",
 ) -> GenerationService:
     import dataclasses
 
@@ -114,7 +114,6 @@ def make_tiny_service(
                     mcfg, mparams, num_slots=8, prompt_bucket=64, mesh=mesh,
                     max_queue_depth=app_cfg.max_queue_depth,
                     speculative_draft=speculative,
-                    kv_layout=kv_layout,
                 )
 
             if supervise:
@@ -265,8 +264,8 @@ def make_checkpoint_service(args, max_new_tokens: int,
             scheduler_meshes = [mesh]
 
     # --kv-int8, or the LSOT_KV_QUANT env knob (README "Quantized
-    # pages"); the CLI flag wins. Composes with --kv-layout=paged (int8
-    # page pool: ~2x live tokens per HBM byte). Rejections name the knob
+    # pages"); the CLI flag wins. Under --scheduler the page pool stores
+    # int8 pages (~2x live tokens per HBM byte). Rejections name the knob
     # the user actually set, and a bad env value dies here with a clean
     # message instead of a traceback deep in the engine.
     if getattr(args, "kv_int8", False):
@@ -277,11 +276,10 @@ def make_checkpoint_service(args, max_new_tokens: int,
             sys.exit(f"LSOT_KV_QUANT must be '' or 'int8', got {env_q!r}")
         kv_quant, kv_quant_src = env_q, "LSOT_KV_QUANT=int8"
     if kv_quant and getattr(args, "speculative", 0) > 0 \
-            and not args.scheduler \
-            and getattr(args, "kv_layout", "contiguous") != "paged":
+            and not args.scheduler:
         sys.exit(f"{kv_quant_src} cannot combine with --speculative on "
-                 "the contiguous layout: the speculative verify loop "
-                 "streams the bf16 cache (use --kv-layout=paged)")
+                 "the engine backend: its speculative verify loop "
+                 "streams the bf16 cache (use --scheduler)")
     int4 = getattr(args, "int4", False)
     if int4 and args.int8:
         sys.exit("pick one of --int8 / --int4")
@@ -352,8 +350,6 @@ def make_checkpoint_service(args, max_new_tokens: int,
                               stall_min_s=app_cfg.stall_min_s,
                               stall_warmup_s=app_cfg.stall_warmup_s)
                 common["speculative_draft"] = getattr(args, "speculative", 0)
-                common["kv_layout"] = getattr(args, "kv_layout",
-                                              "contiguous")
                 budget_gb = getattr(args, "kv_hbm_gb", 0.0)
                 if budget_gb:
                     common["kv_hbm_budget_bytes"] = int(budget_gb * 2**30)
@@ -384,19 +380,13 @@ def make_checkpoint_service(args, max_new_tokens: int,
             # Disaggregated prefill/decode fleet (LSOT_POOL_PHASES, e.g.
             # "prefill:1,decode:3"): per-replica phase roles. Validated
             # up front so a typo'd spec dies with a clean message, not a
-            # traceback mid-pool-build; roles require the paged layout
-            # (the handoff ships KV pool pages).
+            # traceback mid-pool-build.
             try:
                 phase_roles = parse_pool_phases(
                     app_cfg.pool_phases, len(scheduler_meshes)
                 )
             except ValueError as e:
                 sys.exit(f"LSOT_POOL_PHASES: {e}")
-            if any(r != "mixed" for r in phase_roles) \
-                    and getattr(args, "kv_layout", "contiguous") != "paged":
-                sys.exit("LSOT_POOL_PHASES with prefill/decode roles "
-                         "needs --kv-layout=paged (the prefill→decode "
-                         "handoff ships KV pool pages)")
 
             cfg, params = load(None, quantize_int8=args.int8)
             params = jax.device_get(params)
@@ -435,7 +425,6 @@ def make_checkpoint_service(args, max_new_tokens: int,
                     stop_ids=resolve_stop_ids(cfg, tok),
                     mesh=scheduler_meshes[i],
                     kv_quant=kv_quant,
-                    kv_layout=getattr(args, "kv_layout", "contiguous"),
                     kv_hbm_budget_bytes=(
                         int(getattr(args, "kv_hbm_gb", 0.0) * 2**30)
                         or None
@@ -608,7 +597,7 @@ def _make_multimodel_checkpoint_service(args, specs, max_new_tokens,
 
             params = quantize_params(params)
         # The HBM partition: this model's share of ONE arena budget.
-        # 0 = let each scheduler size itself (contiguous-equivalent).
+        # 0 = let each scheduler size itself (slots x max_seq).
         budget = int(total_budget * m.hbm_fraction) or None
 
         def mk(mcfg=mcfg, params=params, tok=tok, budget=budget,
@@ -620,7 +609,6 @@ def _make_multimodel_checkpoint_service(args, specs, max_new_tokens,
                 mcfg, params, num_slots=args.slots,
                 stop_ids=resolve_stop_ids(mcfg, tok),
                 kv_quant=kv_quant,
-                kv_layout=getattr(args, "kv_layout", "contiguous"),
                 kv_hbm_budget_bytes=budget,
                 kv_overcommit=app_cfg.kv_overcommit,
                 kv_spill=app_cfg.kv_spill,
@@ -712,18 +700,15 @@ def build_parser() -> argparse.ArgumentParser:
                     help="int8 KV cache with per-slot scales: halves the "
                          "serving window's HBM footprint and decode cache "
                          "streaming (scheduler and engine backends)")
-    ap.add_argument("--kv-layout", choices=("contiguous", "paged"),
-                    default="contiguous",
-                    help="KV cache layout for the scheduler backend: "
-                         "'paged' serves from a shared page pool with "
-                         "per-slot page tables — concurrency scales with "
-                         "live tokens and schema-prefix cache hits share "
-                         "pages zero-copy (page size: LSOT_KV_PAGE_SIZE, "
-                         "default 64; pool size: --kv-hbm-gb)")
+    ap.add_argument("--kv-layout", type=kv_layout_flag, default="paged",
+                    help="accepted with the one value 'paged': the "
+                         "scheduler backend serves from a shared page "
+                         "pool with per-slot page tables (page size: "
+                         "LSOT_KV_PAGE_SIZE, default 64; pool size: "
+                         "--kv-hbm-gb)")
     ap.add_argument("--kv-hbm-gb", type=float, default=0.0, metavar="GB",
-                    help="HBM budget for the paged KV pool (0 = the "
-                         "contiguous layout's own slots x max_seq "
-                         "footprint, i.e. same memory, more concurrency)")
+                    help="HBM budget for the KV page pool (0 = slots x "
+                         "max_seq tokens' worth of pages)")
     ap.add_argument("--int8-unembed", action="store_true",
                     help="per-row int8 embedding/unembedding tables — the "
                          "largest remaining bf16 decode stream after block "
@@ -845,9 +830,7 @@ def build_app(args, cfg: AppConfig, load_weights=load_checkpoint_file):
         service = (
             make_tiny_service(32, scheduler=args.scheduler, tp=args.tp,
                               supervise=args.supervise,
-                              speculative=getattr(args, "speculative", 0),
-                              kv_layout=getattr(args, "kv_layout",
-                                                "contiguous"))
+                              speculative=getattr(args, "speculative", 0))
             if args.backend == "tiny" else make_fake_service()
         )
     # Per-tenant model routing (ISSUE 20): LSOT_TENANT_MODELS resolves

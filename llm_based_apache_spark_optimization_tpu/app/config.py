@@ -70,7 +70,7 @@ class AppConfig:
     # written here at drain/exit and recovered (resubmitted) at the next
     # start, so retried idempotency keys find their results. "" = off.
     journal_spill: str = ""
-    # --- paged-KV memory pressure (kv_layout="paged"; README "Operating
+    # --- KV page-pool memory pressure (README "Operating
     # under memory pressure"). Overcommit admission: reserve
     # min(budget, max(ratio × budget, observed-generation EWMA)) pages at
     # admission instead of the worst-case envelope; 1.0 = exact-envelope
@@ -81,7 +81,7 @@ class AppConfig:
     kv_spill: bool = False
     # KV-cache storage dtype ("" = compute dtype, "int8" = quantized KV —
     # README "Quantized pages"): the env twin of the --kv-int8 CLI flag
-    # (the flag wins when both are set). With kv_layout="paged" the pool
+    # (the flag wins when both are set). Under --scheduler the pool
     # stores int8 pages + per-position scales, so the same HBM budget
     # holds ~2x the live tokens; page accounting, watermarks and
     # overcommit all price the true int8 page bytes.
@@ -116,7 +116,7 @@ class AppConfig:
     # the KV pages into a handoff blob and retire into a handoff queue;
     # the phase-aware router places the migrated request on a decode
     # replica (falling back to decoding in place when none can take it).
-    # Counts must sum to --dp; requires --kv-layout=paged. "" = every
+    # Counts must sum to --dp. "" = every
     # replica "mixed" (today's behavior bit for bit).
     pool_phases: str = ""
     # --- multi-host fleet (serve/remote.py; README "Multi-host fleet").

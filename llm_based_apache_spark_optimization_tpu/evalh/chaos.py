@@ -671,7 +671,7 @@ def _run_pressure_stage(seed: int, withhold_pages: int = 6) -> Dict:
             with ContinuousBatchingScheduler(
                 TINY, params, num_slots=2, decode_chunk=4,
                 prompt_bucket=8, stop_ids=(2,), max_seq=96,
-                kv_layout="paged", kv_page_size=8, kv_pages=12,
+                kv_page_size=8, kv_pages=12,
                 kv_overcommit=0.25,
             ) as sched:
                 futs = [
@@ -784,7 +784,7 @@ def _run_disagg_stage(seed: int) -> Dict:
     def make_replica(role="mixed"):
         return ContinuousBatchingScheduler(
             TINY, params, num_slots=2, decode_chunk=4, prompt_bucket=8,
-            stop_ids=(2,), max_seq=96, kv_layout="paged", kv_page_size=8,
+            stop_ids=(2,), max_seq=96, kv_page_size=8,
             phase_role=role,
         )
 
@@ -1256,7 +1256,7 @@ def _run_elastic_stage(seed: int) -> Dict:
     def make_sched(role):
         return ContinuousBatchingScheduler(
             TINY, params, num_slots=2, decode_chunk=4, prompt_bucket=8,
-            stop_ids=(2,), max_seq=96, kv_layout="paged", kv_page_size=8,
+            stop_ids=(2,), max_seq=96, kv_page_size=8,
             phase_role=role,
         )
 
@@ -1306,7 +1306,14 @@ def _run_elastic_stage(seed: int) -> Dict:
             factory=rebuild, max_restarts=3,
             restart_policy=RetryPolicy(max_attempts=4, base_delay_s=0.001,
                                        max_delay_s=0.05),
-            rng=_random.Random(seed), lease_s=0.05, lease_misses=2,
+            # A lease is a ping with a timeout, and these workers share
+            # this process (and its GIL) with the schedulers the stage
+            # builds while they serve: at 0.05 s a live standby missed
+            # two beats on a busy host and was restarted as if dead. One
+            # second is out of a live worker's reach; the killed one
+            # refuses the connection at once, and every wait below is on
+            # a condition, so the stage only takes the two beats longer.
+            rng=_random.Random(seed), lease_s=1.0, lease_misses=2,
         )
 
     sup = SupervisedScheduler(
@@ -1564,7 +1571,7 @@ def _run_qos_stage(seed: int) -> Dict:
             sched = ContinuousBatchingScheduler(
                 TINY, params, num_slots=2, decode_chunk=4,
                 prompt_bucket=8, stop_ids=(2,), max_seq=96,
-                kv_layout="paged", kv_page_size=8, kv_pages=24,
+                kv_page_size=8, kv_pages=24,
             )
         finally:
             if saved is None:

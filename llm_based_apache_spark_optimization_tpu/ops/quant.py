@@ -280,7 +280,7 @@ def quantize_cache(
     """Quantize a K/V cache pair into the canonical int8-cache dict layout
     {"k8", "ks", "v8", "vs"} that models/llama.forward and the scheduler's
     cache-tuple threading consume (one definition of the layout; see also
-    serve/scheduler._cache_dict)."""
+    serve/scheduler._quant_window_tuple)."""
     kq, vq = quantize_kv(k), quantize_kv(v)
     return {"k8": kq["q8"], "ks": kq["s"], "v8": vq["q8"], "vs": vq["s"]}
 

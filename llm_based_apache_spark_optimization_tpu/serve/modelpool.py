@@ -324,7 +324,7 @@ def build_tiny_model_service(
                 cfg, params, num_slots=num_slots,
                 decode_chunk=decode_chunk, prompt_bucket=prompt_bucket,
                 stop_ids=(2,), max_seq=max_seq,
-                kv_layout="paged", kv_page_size=kv_page_size,
+                kv_page_size=kv_page_size,
                 kv_pages=per_replica,
                 model_id=m.model_id,
             ))

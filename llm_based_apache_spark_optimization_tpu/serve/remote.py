@@ -899,7 +899,7 @@ class LoopbackTransport(_TransportBase):
             try:
                 self._ledger.get_or_run(token, execute)
             except ValueError:
-                # Incompatibility (blob page size / contiguous pool) is
+                # Incompatibility (blob page size / stored shape) is
                 # an application answer, not a transport failure: the
                 # pool's placement tries the next sibling.
                 raise
@@ -1021,7 +1021,6 @@ def describe_scheduler(sched) -> Dict[str, object]:
         "model_id": str(getattr(sched, "model_id", "") or ""),
         "pblock": int(getattr(sched, "_pblock", 0) or 0),
         "page_size": int(getattr(sched, "_page_size", 0) or 0),
-        "paged": bool(getattr(sched, "_paged", False)),
     }
 
 
@@ -1816,10 +1815,6 @@ class SocketTransport(_TransportBase):
         return int(self._dig("pblock", 0))
 
     @property
-    def _paged(self) -> bool:
-        return bool(self._dig("paged", False))
-
-    @property
     def _page_size(self) -> int:
         return int(self._dig("page_size", 0))
 
@@ -2405,7 +2400,6 @@ def _build_worker_scheduler(args):
         TINY, params, num_slots=args.num_slots,
         decode_chunk=args.decode_chunk, prompt_bucket=args.prompt_bucket,
         stop_ids=(2,), max_seq=args.max_seq,
-        kv_layout=args.kv_layout,
         kv_page_size=args.kv_page_size or None,
         speculative_draft=args.speculative,
         phase_role=args.phase_role,
@@ -2495,7 +2489,6 @@ def _build_checkpoint_scheduler(args):
         cfg, params, num_slots=args.num_slots,
         decode_chunk=args.decode_chunk, prompt_bucket=args.prompt_bucket,
         stop_ids=stop_ids, max_seq=args.max_seq,
-        kv_layout=args.kv_layout,
         kv_page_size=args.kv_page_size or None,
         kv_quant=(args.kv_quant or None),
         kv_hbm_budget_bytes=(int(args.kv_hbm_gb * (1 << 30))
@@ -2514,7 +2507,9 @@ def _build_checkpoint_scheduler(args):
     return _maybe_supervise(sched, args), resolver
 
 
-def main(argv: Optional[Sequence[str]] = None) -> int:
+def build_parser() -> argparse.ArgumentParser:
+    from .scheduler import kv_layout_flag
+
     ap = argparse.ArgumentParser(
         prog="python -m llm_based_apache_spark_optimization_tpu.serve.remote",
         description="Thin remote replica worker: serve one "
@@ -2526,8 +2521,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--decode-chunk", type=int, default=4)
     ap.add_argument("--prompt-bucket", type=int, default=8)
     ap.add_argument("--max-seq", type=int, default=96)
-    ap.add_argument("--kv-layout", default="contiguous",
-                    choices=["contiguous", "paged"])
+    ap.add_argument("--kv-layout", type=kv_layout_flag, default="paged")
     ap.add_argument("--kv-page-size", type=int, default=0)
     ap.add_argument("--speculative", type=int, default=0)
     ap.add_argument("--phase-role", default="mixed",
@@ -2570,7 +2564,11 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     help="bound on pushed-but-unacked handoffs before "
                          "decode-in-place backpressure (0 = "
                          "LSOT_PUMP_DEPTH, default 32)")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv: Optional[Sequence[str]] = None) -> int:
+    args = build_parser().parse_args(argv)
 
     if args.slo_ttft_ms or args.slo_tpot_ms or args.slo_queue_wait_ms:
         from ..utils import slo

@@ -9,18 +9,24 @@ serving), instead of queueing behind a per-backend lock.
 
 Design (slot-based continuous batching, TPU/XLA-shaped):
 
-- A fixed pool of `num_slots` sequence slots backs a persistent KV cache
-  [L, num_slots, S_max, K, H] that lives across jit calls. Both jitted
-  programs donate the cache buffers, so XLA updates HBM in place — no
-  per-request allocation, no growth, static shapes forever.
-- **Prefill** is one jitted fn per prompt-length bucket: run the prompt
-  through the stack against the slot's cache row (sliced out with
-  `dynamic_slice`, written back with `dynamic_update_slice`) and sample the
-  first token.
+- A fixed pool of `num_slots` sequence slots shares ONE persistent KV
+  page pool [L, pages, K, page, H] (engine/paged_kv.py) that lives across
+  jit calls, addressed through per-slot page tables [slots, pages_per_slot].
+  Every jitted program donates the pool buffers, so XLA updates HBM in
+  place — no per-request allocation, no growth, static shapes forever.
+  Admission maps ceil(need/page) pages for the request's ACTUAL envelope
+  (bucketed prompt + budget + overshoot), so concurrency is bounded by
+  live tokens, not by slots x S_max worst-case rows.
+- **Prefill** is one jitted fn per (prompt-length bucket, group size): gather
+  each row's pages into a per-row view, run the prompt chunk through
+  the stack against it, scatter ONLY the chunk's window back through the
+  page table and sample the first token.
 - **Decode** is one jitted fn total: a `lax.scan` of `decode_chunk` single
-  token steps over the whole slot batch. Chunking amortizes the host↔device
-  sync to 1/chunk per token; the host inspects tokens between chunks to
-  retire finished sequences and admit pending ones into freed slots.
+  token steps over the whole slot batch, reading the pool in place through
+  the page tables (ops/pallas/paged_attention.py on TPU). Chunking
+  amortizes the host↔device sync to 1/chunk per token; the host inspects
+  tokens between chunks to retire finished sequences and admit pending
+  ones into freed slots.
 - Mixed sampling rides per-slot runtime arrays (ops/sampling.sample_runtime):
   greedy SQL generation and temperature/top-p/top-k error analysis share one
   compiled decode program.
@@ -30,22 +36,24 @@ Design (slot-based continuous batching, TPU/XLA-shaped):
   completion no matter what other traffic shares the batch (asserted in
   tests/test_scheduler.py).
 - Free slots keep decoding garbage at a frozen position. That is safe by the
-  cache-visibility invariant (engine/kvcache.py): admission prefill
-  overwrites slots [0, T), and beyond T the new sequence's own decode writes
-  position p before p ever becomes visible to attention.
+  cache-visibility invariant (engine/kvcache.py): a free slot's table row is
+  unmapped (its writes drop), admission prefill overwrites positions [0, T),
+  and beyond T the new sequence's own decode writes position p before p ever
+  becomes visible to attention.
 - **Prefix caching** (block-chained, vLLM-style at block granularity): the
   NL→SQL workload repeats one system prefix — the table schema — across
   every request for a table (reference `Flask/app.py:102-106` rebuilds the
-  same system prompt per query). K/V for completed prefix blocks of
-  `_pblock` tokens is kept in an LRU keyed by the *token content* of the
-  whole prefix up to that block (hash-chain semantics: a block is reusable
-  only when everything before it matched too). Admission copies matching
-  blocks into the slot's cache rows device-to-device and skips their
-  prefill entirely. Content keys mean no invalidation is ever needed, and
+  same system prompt per query). Completed prefix blocks of `_pblock`
+  tokens are kept in an LRU keyed by the *token content* of the whole
+  prefix up to that block (hash-chain semantics: a block is reusable only
+  when everything before it matched too). An entry is a REFERENCE to the
+  publisher's pool pages (refcounts), and admission maps matching pages
+  into the slot's table zero-copy and skips their prefill entirely; the
+  only copy is one page, copy-on-write, where a matched prefix ends
+  mid-page. Content keys mean no invalidation is ever needed, and
   positions line up because a shared prefix occupies the same absolute
-  positions [0, n) in every request. Memory: one block for a 7B bf16 model
-  is ~17 MB (2·L·K·16·H·2B); `prefix_cache_blocks` caps the LRU (0
-  disables).
+  positions [0, n) in every request. `prefix_cache_blocks` caps the LRU
+  (0 disables); entries are evicted first when the pool runs short.
 - Tensor parallelism: pass a mesh with dp=1 — request parallelism comes from
   slots (the batch axis stays unsharded because slots are dynamically
   indexed), TP shards heads/MLP exactly as in engine/generate.py.
@@ -57,14 +65,13 @@ Design (slot-based continuous batching, TPU/XLA-shaped):
   SchedulerPool docstring). That matches the workload: serving throughput
   scales with independent replicas; there is no gradient all-reduce to
   motivate a fused dp program (inference-only framework).
-- **int8 KV cache** (`kv_quant="int8"`): the persistent window stores int8
-  values + per-slot f32 scales (ops/quant.quantize_kv) — half the HBM
-  footprint and decode streaming. Decode runs the int8-streaming einsum
-  attention, or — past the cost crossover where a large mostly-dead
-  window pays for per-row bounded streaming — the quantized flash kernel
-  (ops.pallas.flash_gqa_attention_quantized: int8 bytes AND kv_lens
-  bounding stacked). Chunked prefill dequantizes the gathered rows for
-  the chunk forward and requantizes only its own window on scatter-back.
+- **int8 KV cache** (`kv_quant="int8"`): the pool stores int8 pages +
+  per-position f32 scales (ops/quant.quantize_kv) — about half the HBM
+  footprint and decode streaming, and the same HBM budget buys about
+  twice the pages. Decode reads the int8 pages in place (the quantized
+  ragged-paged kernel on TPU, a gather + int8-streaming einsum
+  elsewhere). Chunked prefill dequantizes the gathered rows for the
+  chunk forward and requantizes only its own window on scatter-back.
 - **Streaming + cancellation**: `submit(on_token=...)` delivers accepted
   tokens in order from the worker thread (SchedulerBackend.complete_stream
   turns them into clean text deltas, byte-identical to the blocking path);
@@ -115,6 +122,7 @@ garbage covered by the invariant above).
 
 from __future__ import annotations
 
+import argparse
 import dataclasses
 import hashlib
 import logging
@@ -133,7 +141,7 @@ import numpy as np
 from jax import lax
 
 from ..constrain.masks import CompiledMask, trivial_tables
-from ..engine.kvcache import bucket_len, init_cache
+from ..engine.kvcache import bucket_len
 from ..engine.paged_kv import (
     PageAllocator,
     check_blob_shape,
@@ -181,6 +189,29 @@ _log = logging.getLogger("lsot.scheduler")
 #: phase-aware router sends it migrated requests and keeps fresh prompts
 #: off it.
 PHASE_ROLES = ("mixed", "prefill", "decode")
+
+
+def require_paged_layout(kv_layout: str) -> str:
+    """The serving path has one KV layout, the page pool. `kv_layout` /
+    `--kv-layout` are still taken with that one value; anything else is
+    refused by name."""
+    if kv_layout != "paged":
+        raise ValueError(
+            f"kv_layout={kv_layout!r}: the contiguous KV layout was "
+            f"removed from the serving path; the page pool ('paged') is "
+            f"the only layout"
+        )
+    return kv_layout
+
+
+def kv_layout_flag(value: str) -> str:
+    """`type=` of the app's and the remote worker's `--kv-layout`: the
+    same refusal as an argparse error."""
+    try:
+        return require_paged_layout(value)
+    except ValueError as e:
+        raise argparse.ArgumentTypeError(str(e)) from None
+
 
 #: A /debug/profile capture older than this is stopped when the loop next
 #: finds nothing to serve: its trace runs from the request on, rounds or
@@ -365,26 +396,17 @@ def _first_token_timer(then: Optional[Callable[[int], None]] = None):
     return on_tok, first_at
 
 
-def _cache_dict(arrs: Sequence[jnp.ndarray]) -> Dict[str, jnp.ndarray]:
-    """Tuple-of-arrays cache -> the dict form models/llama.forward takes."""
-    if len(arrs) == 2:
-        return {"k": arrs[0], "v": arrs[1]}
-    return {"k8": arrs[0], "ks": arrs[1], "v8": arrs[2], "vs": arrs[3]}
-
-
-def _cache_tuple(d: Dict[str, jnp.ndarray]) -> Tuple[jnp.ndarray, ...]:
-    if "k8" in d:
-        return (d["k8"], d["ks"], d["v8"], d["vs"])
-    return (d["k"], d["v"])
+def _quant_window_tuple(d: Dict[str, jnp.ndarray]) -> Tuple[jnp.ndarray, ...]:
+    """ops/quant.quantize_cache's dict -> the int8 pool's array order."""
+    return (d["k8"], d["ks"], d["v8"], d["vs"])
 
 
 def _paged_cache_dict(
     arrs: Sequence[jnp.ndarray], ptab: jnp.ndarray
 ) -> Dict[str, jnp.ndarray]:
-    """Paged cache tuple -> the dict form models/llama.forward takes:
-    (kp, vp) for a compute-dtype pool, (kp, kps, vp, vps) for the int8
-    pool (values + per-position scales, mirroring the contiguous
-    (k8, ks, v8, vs) ordering)."""
+    """Pool tuple -> the dict form models/llama.forward takes: (kp, vp)
+    for a compute-dtype pool, (kp, kps, vp, vps) for the int8 pool
+    (values + per-position scales)."""
     if len(arrs) == 2:
         return {"kp": arrs[0], "vp": arrs[1], "ptab": ptab}
     return {"kp": arrs[0], "kps": arrs[1], "vp": arrs[2], "vps": arrs[3],
@@ -467,7 +489,7 @@ class _Request:
     # request can only land on a same-model replica ("" = the
     # single-model fleet).
     model_id: str = ""
-    # Paged KV (kv_layout="paged"): highest cache position (exclusive) this
+    # Page envelope: highest cache position (exclusive) this
     # request's prefill+decode can ever write — admission allocated pages
     # covering exactly [0, page_end), and the ready-time ensure-writable
     # sweep COWs any published page the decode range intersects.
@@ -646,7 +668,10 @@ class ContinuousBatchingScheduler:
         fuse_matmuls: bool = False,
         max_queue_depth: int = 0,
         slot_stall_rounds: int = 16,
-        kv_layout: str = "contiguous",
+        # The page pool is the one KV layout. The keyword is still taken,
+        # with that one value, for the callers under benchmark/ that pass
+        # it (ROADMAP M10 deletes it with them).
+        kv_layout: str = "paged",
         kv_page_size: Optional[int] = None,
         kv_pages: Optional[int] = None,
         kv_hbm_budget_bytes: Optional[int] = None,
@@ -660,8 +685,7 @@ class ContinuousBatchingScheduler:
         # query-length vector; prefill rows scatter their chunk, decode
         # rows emit tokens), retiring the separate prefill pass from the
         # loop's hot path. None = read LSOT_RAGGED (default off — the
-        # alternating scheduler, bit for bit). Paged-only, mixed-role
-        # only.
+        # alternating scheduler, bit for bit). Mixed-role only.
         ragged: Optional[bool] = None,
         # Multi-model serving (ISSUE 16): which registered checkpoint
         # this replica holds. "" (the default) is the single-model
@@ -686,17 +710,13 @@ class ContinuousBatchingScheduler:
         # place, so a lone prefill-role scheduler still serves). A
         # "decode" replica is routing policy only — full capability, but
         # the router feeds it migrated requests and keeps fresh prompts
-        # off it. Handoff needs pages to ship, hence paged-only.
+        # off it.
         if phase_role not in PHASE_ROLES:
             raise ValueError(
                 f"phase_role must be one of {PHASE_ROLES}, got "
                 f"{phase_role!r}"
             )
-        if phase_role != "mixed" and kv_layout != "paged":
-            raise ValueError(
-                f"phase_role={phase_role!r} needs kv_layout='paged': the "
-                f"prefill→decode handoff ships KV pool pages"
-            )
+        require_paged_layout(kv_layout)
         self.phase_role = phase_role
         self.model_id = str(model_id or "")
         # Accepted tokens over this scheduler's lifetime (ISSUE 16):
@@ -816,158 +836,123 @@ class ContinuousBatchingScheduler:
         if kv_quant not in (None, "int8"):
             raise ValueError(f"kv_quant must be None or 'int8', got {kv_quant!r}")
         self.kv_quant = kv_quant
-        # Paged KV cache (kv_layout="paged", engine/paged_kv.py): the
-        # persistent window becomes a shared page pool sized to an HBM
-        # budget + per-slot page tables, instead of slots × S_max
-        # contiguous rows. Admission allocates ceil(need/page) pages for
-        # the request's ACTUAL envelope (bucketed prompt + budget +
-        # overshoot), so concurrency is bounded by live tokens, mixed
-        # long/short batches stop paying max-bucket padding, and
-        # prefix-cache hits map shared pages zero-copy (refcounts;
-        # copy-on-write only at a non-page-aligned boundary). Decode runs
-        # the ragged-paged-attention path (models/llama.forward paged
-        # branch; ops/pallas/paged_attention.py on TPU).
-        if kv_layout not in ("contiguous", "paged"):
+        # The KV page pool (engine/paged_kv.py): a shared pool sized to an
+        # HBM budget + per-slot page tables. Admission allocates
+        # ceil(need/page) pages for the request's ACTUAL envelope
+        # (bucketed prompt + budget + overshoot), so concurrency is
+        # bounded by live tokens, mixed long/short batches stop paying
+        # max-bucket padding, and prefix-cache hits map shared pages
+        # zero-copy (refcounts; copy-on-write only at a non-page-aligned
+        # boundary). Decode runs the ragged-paged-attention path
+        # (models/llama.forward paged branch; ops/pallas/paged_attention.py
+        # on TPU). Composes with kv_quant="int8" (the pool stores int8
+        # pages + per-position scales — ~2x live tokens per HBM byte; page
+        # accounting below prices the TRUE page bytes) and with a dp=1 tp
+        # mesh (pool KV heads shard over tp; page tables replicate).
+        ps = int(kv_page_size or default_page_size())
+        if ps <= 0 or ps % 8:
             raise ValueError(
-                f"kv_layout must be 'contiguous' or 'paged', got "
-                f"{kv_layout!r}"
+                f"kv_page_size must be a positive multiple of 8, got "
+                f"{ps}"
             )
-        self.kv_layout = kv_layout
-        self._paged = kv_layout == "paged"
-        if self._paged:
-            # Composes with kv_quant="int8" (the pool stores int8 pages +
-            # per-position scales — ~2x live tokens per HBM byte; page
-            # accounting below prices the TRUE page bytes) and with a
-            # dp=1 tp mesh (pool KV heads shard over tp exactly like the
-            # contiguous cache; page tables replicate).
-            ps = int(kv_page_size or default_page_size())
-            if ps <= 0 or ps % 8:
-                raise ValueError(
-                    f"kv_page_size must be a positive multiple of 8, got "
-                    f"{ps}"
-                )
-            self._page_size = ps
-            # Logical pages per slot: enough table entries to address the
-            # whole window (a slot never MAPS them all unless its request
-            # actually needs max_seq).
-            self._pages_per_slot = pages_for_tokens(self.max_seq, ps)
-            if kv_pages:
-                num_pages = int(kv_pages)
-            elif kv_hbm_budget_bytes:
-                # KV-dtype-aware sizing (ISSUE 11 satellite): an int8
-                # pool's pages cost ~half a compute-dtype page, so the
-                # same HBM budget buys ~2x the pages — capacity math must
-                # price the KV dtype, not the compute dtype.
-                num_pages = pages_for_budget(
-                    cfg, kv_hbm_budget_bytes, ps, dtype.itemsize, kv_quant
-                )
-            else:
-                # Default budget = the contiguous layout's own footprint:
-                # same HBM, strictly more concurrency on mixed traffic.
-                num_pages = num_slots * self._pages_per_slot
-            if num_pages < self._pages_per_slot:
-                raise ValueError(
-                    f"page pool of {num_pages} pages cannot hold one "
-                    f"max-length request ({self._pages_per_slot} pages of "
-                    f"{ps} tokens for max_seq={self.max_seq}); raise "
-                    f"kv_pages / kv_hbm_budget_bytes or lower max_seq"
-                )
-            self._page_alloc = PageAllocator(num_pages, ps)
-            # Graceful degradation under page pressure (ISSUE 10).
-            # Overcommit admission: reserve min(budget, max(ratio × budget,
-            # EWMA of observed generation lengths)) generation tokens at
-            # admission instead of the full max_new worst case — 1.0 (the
-            # default) reproduces the exact-envelope admission bit for
-            # bit; below 1.0, decode tops pages up at each harvest and a
-            # failed top-up preempts a victim (fewest generated tokens
-            # first, never the allocating slot) whose deterministic
-            # resume re-prefills prompt+generated (or restores spilled
-            # host page copies under kv_spill).
-            if kv_overcommit is None:
-                kv_overcommit = float(
-                    os.environ.get("LSOT_KV_OVERCOMMIT", "1.0"))
-            if not 0.0 < kv_overcommit <= 1.0:
-                raise ValueError(
-                    f"kv_overcommit must be in (0, 1], got {kv_overcommit}"
-                )
-            self._kv_overcommit = float(kv_overcommit)
-            if kv_spill is None:
-                kv_spill = os.environ.get("LSOT_KV_SPILL", "0").strip() \
-                    .lower() in ("1", "true", "yes", "on")
-            self._kv_spill = bool(kv_spill)
-            # Watermark-driven eviction: when pool free pages fall under
-            # low × pages, the loop proactively evicts LRU prefix-cache
-            # entries until free recovers to high × pages — steady-state
-            # pressure is relieved BEFORE an allocation fails, so traffic
-            # rarely needs a preemption at all. low = 0 disables (the
-            # default: the on-demand eviction inside _alloc_pages remains,
-            # exactly as before).
-            if kv_watermark_low is None:
-                kv_watermark_low = float(
-                    os.environ.get("LSOT_KV_WATERMARK_LOW", "0.0"))
-            if kv_watermark_high is None:
-                kv_watermark_high = float(
-                    os.environ.get("LSOT_KV_WATERMARK_HIGH", "0.0"))
-            if not 0.0 <= kv_watermark_low <= 1.0 or \
-                    not 0.0 <= kv_watermark_high <= 1.0 or \
-                    kv_watermark_high < kv_watermark_low:
-                raise ValueError(
-                    f"kv watermarks must satisfy 0 <= low <= high <= 1, "
-                    f"got low={kv_watermark_low} high={kv_watermark_high}"
-                )
-            self._wm_low_pages = int(kv_watermark_low * num_pages)
-            self._wm_high_pages = max(
-                self._wm_low_pages, int(kv_watermark_high * num_pages))
-            # EWMA of COMPLETED requests' generation lengths: the
-            # "expected generation" admission reserves under overcommit.
-            self._gen_ewma: Optional[float] = None
-            # Host-side per-slot page lists (the device table's mirror).
-            self._slot_pages: List[List[int]] = [[] for _ in range(num_slots)]
-            # Paged prefix cache: content key (token prefix) -> pool page
-            # ids covering it. Entries hold REFERENCES (refcounts), not
-            # copies — publish and hit are both zero-copy.
-            self._prefix_pages: "OrderedDict[Tuple[int, ...], Tuple[int, ...]]" = (
-                OrderedDict()
+        self._page_size = ps
+        # Logical pages per slot: enough table entries to address the
+        # whole window (a slot never MAPS them all unless its request
+        # actually needs max_seq).
+        self._pages_per_slot = pages_for_tokens(self.max_seq, ps)
+        if kv_pages:
+            num_pages = int(kv_pages)
+        elif kv_hbm_budget_bytes:
+            # KV-dtype-aware sizing (ISSUE 11 satellite): an int8
+            # pool's pages cost ~half a compute-dtype page, so the
+            # same HBM budget buys ~2x the pages — capacity math must
+            # price the KV dtype, not the compute dtype.
+            num_pages = pages_for_budget(
+                cfg, kv_hbm_budget_bytes, ps, dtype.itemsize, kv_quant
             )
-            # Requests admitted to a slot but waiting for pool pages
-            # (admission is all-or-nothing so partial holders can't
-            # deadlock); FIFO ahead of the main queue.
-            self._page_wait: "deque[_Request]" = deque()
-            self._page_wait_events = 0
-        # Decode impl is cost-aware: the flash kernel's per-row kv_lens
-        # bounding (parked slots stream nothing) only beats the einsum
-        # path's zero-overhead full-cache read once the persistent
-        # [slots, max_seq] cache is large per device — see
-        # ops.pallas.decode_attention_impl for the measured crossover.
-        # With the int8 KV cache the streamed bytes HALVE (which also
-        # halves the full-read penalty the kernel amortizes), so the
-        # crossover is fed the quantized byte count; past it, decode runs
-        # flash_gqa_attention_quantized — int8 streaming and bounded
-        # streaming stacked.
-        from ..engine.kvcache import cache_bytes as _cache_bytes
-
-        tp = dict(mesh.shape).get("tp", 1) if mesh is not None else 1
-        if self._paged:
-            # page_bytes already prices the KV dtype (int8 values +
-            # scales), so no post-hoc halving; the pool's head axis
-            # shards over tp like the contiguous cache.
-            cache_dev_bytes = self._page_alloc.num_pages * page_bytes(
-                cfg, self._page_size, dtype.itemsize, kv_quant
-            ) // tp
         else:
-            cache_dev_bytes = _cache_bytes(
-                cfg, num_slots, self.max_seq, dtype.itemsize
-            ) // tp
-        if kv_quant and not self._paged:
-            # Halving shifts the kernel/einsum crossover to the quantized
-            # byte count. NOTE (advisor r4): the crossover threshold itself
-            # was measured on the bf16 cache; quantization halves the
-            # kernel's streamed bytes and the einsum's full-read penalty
-            # roughly equally, so feeding the halved count to the bf16
-            # threshold is an extrapolation, not a re-measurement — if int8
-            # decode dispatch ever looks off, re-sweep the crossover with
-            # the int8 cache (ops/pallas/dispatch.py has the recipe).
-            cache_dev_bytes //= 2
+            # Default budget: every slot can map a whole max_seq window
+            # at once (slots x max_seq rows' worth of HBM).
+            num_pages = num_slots * self._pages_per_slot
+        if num_pages < self._pages_per_slot:
+            raise ValueError(
+                f"page pool of {num_pages} pages cannot hold one "
+                f"max-length request ({self._pages_per_slot} pages of "
+                f"{ps} tokens for max_seq={self.max_seq}); raise "
+                f"kv_pages / kv_hbm_budget_bytes or lower max_seq"
+            )
+        self._page_alloc = PageAllocator(num_pages, ps)
+        # Graceful degradation under page pressure (ISSUE 10).
+        # Overcommit admission: reserve min(budget, max(ratio × budget,
+        # EWMA of observed generation lengths)) generation tokens at
+        # admission instead of the full max_new worst case — 1.0 (the
+        # default) reproduces the exact-envelope admission bit for
+        # bit; below 1.0, decode tops pages up at each harvest and a
+        # failed top-up preempts a victim (fewest generated tokens
+        # first, never the allocating slot) whose deterministic
+        # resume re-prefills prompt+generated (or restores spilled
+        # host page copies under kv_spill).
+        if kv_overcommit is None:
+            kv_overcommit = float(
+                os.environ.get("LSOT_KV_OVERCOMMIT", "1.0"))
+        if not 0.0 < kv_overcommit <= 1.0:
+            raise ValueError(
+                f"kv_overcommit must be in (0, 1], got {kv_overcommit}"
+            )
+        self._kv_overcommit = float(kv_overcommit)
+        if kv_spill is None:
+            kv_spill = os.environ.get("LSOT_KV_SPILL", "0").strip() \
+                .lower() in ("1", "true", "yes", "on")
+        self._kv_spill = bool(kv_spill)
+        # Watermark-driven eviction: when pool free pages fall under
+        # low × pages, the loop proactively evicts LRU prefix-cache
+        # entries until free recovers to high × pages — steady-state
+        # pressure is relieved BEFORE an allocation fails, so traffic
+        # rarely needs a preemption at all. low = 0 disables (the
+        # default: the on-demand eviction inside _alloc_pages remains,
+        # exactly as before).
+        if kv_watermark_low is None:
+            kv_watermark_low = float(
+                os.environ.get("LSOT_KV_WATERMARK_LOW", "0.0"))
+        if kv_watermark_high is None:
+            kv_watermark_high = float(
+                os.environ.get("LSOT_KV_WATERMARK_HIGH", "0.0"))
+        if not 0.0 <= kv_watermark_low <= 1.0 or \
+                not 0.0 <= kv_watermark_high <= 1.0 or \
+                kv_watermark_high < kv_watermark_low:
+            raise ValueError(
+                f"kv watermarks must satisfy 0 <= low <= high <= 1, "
+                f"got low={kv_watermark_low} high={kv_watermark_high}"
+            )
+        self._wm_low_pages = int(kv_watermark_low * num_pages)
+        self._wm_high_pages = max(
+            self._wm_low_pages, int(kv_watermark_high * num_pages))
+        # EWMA of COMPLETED requests' generation lengths: the
+        # "expected generation" admission reserves under overcommit.
+        self._gen_ewma: Optional[float] = None
+        # Host-side per-slot page lists (the device table's mirror).
+        self._slot_pages: List[List[int]] = [[] for _ in range(num_slots)]
+        # Prefix cache: content key (token prefix) -> pool page
+        # ids covering it. Entries hold REFERENCES (refcounts), not
+        # copies — publish and hit are both zero-copy.
+        self._prefix_pages: "OrderedDict[Tuple[int, ...], Tuple[int, ...]]" = (
+            OrderedDict()
+        )
+        # Requests admitted to a slot but waiting for pool pages
+        # (admission is all-or-nothing so partial holders can't
+        # deadlock); FIFO ahead of the main queue.
+        self._page_wait: "deque[_Request]" = deque()
+        self._page_wait_events = 0
+        # Decode impl is cost-aware: the Pallas kernels' per-row kv_lens
+        # bounding (parked slots stream nothing) only beats the einsum
+        # path's zero-overhead full read once the pool is large per
+        # device — see ops.pallas.decode_attention_impl for the measured
+        # crossover. page_bytes prices the KV dtype (int8 values +
+        # scales), and the pool's head axis shards over tp.
+        tp = dict(mesh.shape).get("tp", 1) if mesh is not None else 1
+        cache_dev_bytes = self._page_alloc.num_pages * page_bytes(
+            cfg, self._page_size, dtype.itemsize, kv_quant
+        ) // tp
         self._decode_impl = decode_attention_impl(mesh, cache_dev_bytes)
         # Decode anchors its per-layer weight slices outside the chunk
         # scan (models/llama.split_blocks: layout conversions once per
@@ -997,8 +982,8 @@ class ContinuousBatchingScheduler:
             weight_bits=self._weight_bits,
             kv_itemsize=dtype.itemsize,
             kv_quant=kv_quant,
-            kv_layout=kv_layout,
-            page_size=self._page_size if self._paged else None,
+            kv_layout="paged",
+            page_size=self._page_size,
             tp=tp,
             device_kind=jax.devices()[0].device_kind,
         )
@@ -1014,48 +999,39 @@ class ContinuousBatchingScheduler:
         self._profile_last: Optional[Dict[str, object]] = None
         self._profile_writing: Optional[Dict[str, object]] = None
         self._profile_writer: Optional[threading.Thread] = None
-        # The persistent cache is a TUPLE of arrays threaded through every
-        # jitted op: (k, v) in bf16 mode, (k8, ks, v8, vs) with int8 KV
-        # (values + per-slot scales, ops/quant.quantize_kv), (kp, vp) pool
-        # arrays in paged mode (per-slot page tables ride beside them as
-        # self._ptab, a non-donated arg to every program).
+        # The persistent cache is a TUPLE of pool arrays threaded through
+        # every jitted op: (kp, vp) in the compute dtype, (kp, kps, vp,
+        # vps) with int8 KV (values + per-position scales). The per-slot
+        # page tables ride beside them as self._ptab, a non-donated arg
+        # to every program.
         def make_cache():
-            if self._paged:
-                # Stored lane-packed where the heads are narrow
-                # (engine/paged_kv.lane_pack decides; the pool's shape
-                # is the record every program reads it from).
-                pool = init_page_pool(
-                    cfg, self._page_alloc.num_pages, self._page_size,
-                    dtype=dtype, kv_quant=kv_quant, tp=tp,
-                )
-                return ((pool["kp"], pool["kps"], pool["vp"], pool["vps"])
-                        if kv_quant else (pool["kp"], pool["vp"]))
-            cache = init_cache(cfg, num_slots, self.max_seq, dtype=dtype)
-            if kv_quant:
-                from ..ops.quant import quantize_cache
-
-                return _cache_tuple(quantize_cache(cache["k"], cache["v"]))
-            return (cache["k"], cache["v"])
-
-        if self._paged:
-            # Device page tables: [slots, pages_per_slot], the UNMAPPED
-            # sentinel is num_pages — one past the pool, so jax drops the
-            # scatter writes of parked/padding rows and gathers clip to a
-            # causally-masked real page.
-            self._ptab = jnp.full(
-                (num_slots, self._pages_per_slot),
-                self._page_alloc.num_pages, jnp.int32,
+            # Stored lane-packed where the heads are narrow
+            # (engine/paged_kv.lane_pack decides; the pool's shape is the
+            # record every program reads it from).
+            pool = init_page_pool(
+                cfg, self._page_alloc.num_pages, self._page_size,
+                dtype=dtype, kv_quant=kv_quant, tp=tp,
             )
+            return ((pool["kp"], pool["kps"], pool["vp"], pool["vps"])
+                    if kv_quant else (pool["kp"], pool["vp"]))
+
+        # Device page tables: [slots, pages_per_slot], the UNMAPPED
+        # sentinel is num_pages — one past the pool, so jax drops the
+        # scatter writes of parked/padding rows and gathers clip to a
+        # causally-masked real page.
+        self._ptab = jnp.full(
+            (num_slots, self._pages_per_slot),
+            self._page_alloc.num_pages, jnp.int32,
+        )
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            # Slots (contiguous) / pages (paged) unsharded, KV heads on
-            # tp; scale tensors drop the trailing axis from the spec but
-            # keep heads-over-tp. The same two specs serve all four cache
-            # forms — [L, B|P, K, S|PS(, H)]. Born in place: made on the
-            # default device and moved, a pool that fills a chip would
-            # first have to fit on device 0 beside whatever lives there
-            # (its own replica's weights and pool, under dp).
+            # Pages unsharded, KV heads on tp; scale tensors drop the
+            # trailing axis from the spec but keep heads-over-tp —
+            # [L, P, K, PS(, H)]. Born in place: made on the default
+            # device and moved, a pool that fills a chip would first have
+            # to fit on device 0 beside whatever lives there (its own
+            # replica's weights and pool, under dp).
             arrs = jax.jit(make_cache, out_shardings=tuple(
                 NamedSharding(
                     mesh,
@@ -1064,12 +1040,11 @@ class ContinuousBatchingScheduler:
                 )
                 for x in jax.eval_shape(make_cache)
             ))()
-            if self._paged:
-                # Page tables replicate: every device addresses the full
-                # page axis of its own head shard.
-                self._ptab = jax.device_put(
-                    self._ptab, NamedSharding(mesh, P(None, None))
-                )
+            # Page tables replicate: every device addresses the full
+            # page axis of its own head shard.
+            self._ptab = jax.device_put(
+                self._ptab, NamedSharding(mesh, P(None, None))
+            )
         else:
             arrs = make_cache()
         self._cache = arrs
@@ -1141,9 +1116,8 @@ class ContinuousBatchingScheduler:
         self._harvest_lag = 1  # rounds kept in flight before syncing
         (self._park_fn, self._ready_fn, self._retire_fn,
          self._resume_fn) = self._build_state_ops()
-        if self._paged:
-            (self._ptab_row_fn, self._copy_page_fn,
-             self._restore_page_fn) = self._build_page_ops()
+        (self._ptab_row_fn, self._copy_page_fn,
+         self._restore_page_fn) = self._build_page_ops()
         # Unified ragged prefill+decode (ISSUE 19): one compiled
         # mixed-round program admits this round's prefill chunks and every
         # decode slot into the SAME launch — forward takes a per-slot
@@ -1156,18 +1130,15 @@ class ContinuousBatchingScheduler:
             ragged = os.environ.get("LSOT_RAGGED", "0").strip().lower() in (
                 "1", "true", "yes", "on"
             )
-            if ragged and not (self._paged and self.phase_role == "mixed"):
-                # Env-driven opt-in degrades silently on replicas that
-                # can't serve it (contiguous layout, phase-split roles):
-                # one LSOT_RAGGED=1 environment may spawn heterogeneous
-                # fleets.
+            if ragged and self.phase_role != "mixed":
+                # Env-driven opt-in degrades silently on phase-split
+                # replicas: one LSOT_RAGGED=1 environment may spawn
+                # heterogeneous fleets.
                 ragged = False
-        elif ragged and not (self._paged and self.phase_role == "mixed"):
+        elif ragged and self.phase_role != "mixed":
             raise ValueError(
-                "ragged mixed rounds need kv_layout='paged' (prefill rows "
-                "scatter chunks through page tables) and "
-                "phase_role='mixed' (a phase-split replica has no mixed "
-                "rounds to unify)"
+                "ragged mixed rounds need phase_role='mixed' (a "
+                "phase-split replica has no mixed rounds to unify)"
             )
         self._ragged = bool(ragged)
         if self._ragged:
@@ -1263,22 +1234,16 @@ class ContinuousBatchingScheduler:
             self._spec_tokens_samp = 0
 
         # Prefix cache: block size = the smallest bucket, so chunk boundaries
-        # always land on block boundaries. OrderedDict as LRU of
-        # content-keyed cache-block tuples (one entry per cache array:
-        # [L, 1, K, pblock, H] values, plus [L, 1, K, pblock] scales under
-        # kv_quant).
+        # always land on block boundaries. `_prefix_pages` (above) is the
+        # LRU of content-keyed page references.
         self._pblock = self._buckets[0]
         self._prefix_cache_blocks = max(0, prefix_cache_blocks)
-        self._prefix_cache: "OrderedDict[Tuple[int, ...], Tuple[jax.Array, ...]]" = (
-            OrderedDict()
-        )
-        # Publish gate: a block is copied out of the cache only once its
-        # content key has been SEEN before (second occurrence onward). A
-        # shared system/schema prefix repeats across requests, so it gets
-        # published on request 2 and hit from request 3 on; one-off prompts
-        # (every block unique) pay zero slice dispatches — publishing every
-        # block of every prompt was a measured per-admission cost on the
-        # serving path with nothing to ever reuse it.
+        # Publish gate: a block's pages are shared with the cache only
+        # once its content key has been SEEN before (second occurrence
+        # onward). A shared system/schema prefix repeats across requests,
+        # so it gets published on request 2 and hit from request 3 on;
+        # one-off prompts (every block unique) take no references and
+        # never crowd the LRU.
         self._prefix_seen: "OrderedDict[Tuple[int, ...], None]" = OrderedDict()
         self._prefix_hits = 0
         self._prefix_blocks_reused = 0
@@ -1338,15 +1303,6 @@ class ContinuousBatchingScheduler:
         # the next harvest ({rid, digest, reused, prefilled} per admitted
         # request that went through the prefix-match path).
         self._round_prefix: List[Dict[str, object]] = []
-        # Contiguous block bytes (one cache entry's device footprint),
-        # filled lazily from the first published entry.
-        self._prefix_block_bytes = 0
-        # Contiguous mode materializes prefix blocks by device copy; paged
-        # mode shares pool pages by refcount instead and never needs the
-        # slice/restore copies.
-        self._slice_block_fn, self._restore_block_fn = (
-            (None, None) if self._paged else self._build_block_ops()
-        )
 
         # Recent per-request service time (EWMA of completed requests'
         # submit→retire wall): the backpressure estimate behind
@@ -1507,44 +1463,6 @@ class ContinuousBatchingScheduler:
             )
 
         return park_slot, ready_slot, retire_slot, resume_slot
-
-    def _build_block_ops(self):
-        """Jitted device-to-device prefix-block copy ops.
-
-        slice:   each cache array [L, B, K, S(, H)] -> block [L, 1, K,
-                 pblock(, H)] (values and, under kv_quant, their scales)
-        restore: write the blocks back into a slot row at a block-aligned
-                 start.
-        Both are pure data movement — no compute — so a cache hit costs HBM
-        copies instead of a transformer forward."""
-        L, K, H = self.cfg.num_layers, self.cfg.num_kv_heads, self.cfg.head_dim
-        pb = self._pblock
-        nc = len(self._cache)
-
-        def _sizes(arr):
-            return (L, 1, K, pb, H) if arr.ndim == 5 else (L, 1, K, pb)
-
-        def _idx(arr, slot, start):
-            return ((0, slot, 0, start, 0) if arr.ndim == 5
-                    else (0, slot, 0, start))
-
-        @jax.jit
-        def slice_block(*args):
-            cache, (slot, start) = args[:nc], args[nc:]
-            return tuple(
-                lax.dynamic_slice(c, _idx(c, slot, start), _sizes(c))
-                for c in cache
-            )
-
-        @partial(jax.jit, donate_argnums=tuple(range(nc)))
-        def restore_block(*args):
-            cache, blocks, (slot, start) = args[:nc], args[nc:2 * nc], args[2 * nc:]
-            return tuple(
-                lax.dynamic_update_slice(c, b, _idx(c, slot, start))
-                for c, b in zip(cache, blocks)
-            )
-
-        return slice_block, restore_block
 
     def _build_page_ops(self):
         """Jitted paged-KV bookkeeping ops (async scatters, ~bytes of
@@ -2182,10 +2100,9 @@ class ContinuousBatchingScheduler:
             if pre is not None:
                 rec["prefill_mfu"] = pre["mfu"]
                 rec["prefill_hbm_util"] = pre["hbm_util"]
-        if self._paged:
-            rec["kv_pages"] = self._page_alloc.pages_in_use
-            rec["kv_pages_free"] = self._page_alloc.pages_free
-            rec["kv_pressure"] = self._page_alloc.withheld
+        rec["kv_pages"] = self._page_alloc.pages_in_use
+        rec["kv_pages_free"] = self._page_alloc.pages_free
+        rec["kv_pressure"] = self._page_alloc.withheld
         self._host_columns(rec)
         self.flight.record(**rec)
         self._round_admitted = []
@@ -2218,16 +2135,14 @@ class ContinuousBatchingScheduler:
         }
 
     @property
-    def page_stats(self) -> Optional[Dict[str, int]]:
-        """Paged-KV observability (None when contiguous): pool occupancy
-        and sharing counters — `zero_copy_shares` rising with prefix hits
-        while `cow_copies` stays at boundary-only counts is the
+    def page_stats(self) -> Dict[str, int]:
+        """Page-pool observability: pool occupancy and sharing counters —
+        `zero_copy_shares` rising with prefix hits while `cow_copies`
+        stays at boundary-only counts is the
         "sharing, not copying" proof the bench artifact records; a leaked
         page shows up as pages_in_use that never drains. The pressure
         block (preemptions/evictions/spilled/withheld + watermarks) is
         the graceful-degradation dashboard."""
-        if not self._paged:
-            return None
         out = self._page_alloc.stats()
         out["pages_per_slot"] = self._pages_per_slot
         out["page_waits"] = self._page_wait_events
@@ -2271,12 +2186,10 @@ class ContinuousBatchingScheduler:
         einsum path or the interpreter cannot pass for a device run."""
         from ..ops.pallas.dispatch import resolve_interpret
 
-        write = "none"
-        if self._paged:
-            # models/llama.forward: the write kernel needs the pallas
-            # impl and no mesh (GSPMD partitions the XLA scatter).
-            write = ("pallas" if self._decode_impl == "pallas"
-                     and self.mesh is None else "xla")
+        # models/llama.forward: the write kernel needs the pallas impl
+        # and no mesh (GSPMD partitions the XLA scatter).
+        write = ("pallas" if self._decode_impl == "pallas"
+                 and self.mesh is None else "xla")
         return {
             "pallas": "interpreted" if resolve_interpret(None)
             else "compiled",
@@ -2488,18 +2401,16 @@ class ContinuousBatchingScheduler:
         quant, dtype = self.kv_quant, self._dtype
         nc = len(self._cache)
         spec = bool(self._spec_draft)
-        paged = self._paged
-        if paged:
-            ps, np_tab = self._page_size, self._pages_per_slot
-            num_pages = self._page_alloc.num_pages
+        ps, np_tab = self._page_size, self._pages_per_slot
+        num_pages = self._page_alloc.num_pages
 
         # Speculative mode appends the on-device draft history as one more
         # donated arg: the chunk's tokens scatter into hist rows at the
         # same positions their K/V land at (drafting needs the prompt text,
         # and it is already on device for the forward anyway).
-        # Paged mode appends the device page tables LAST (non-donated:
-        # tables are tiny and in-flight rounds must keep reading the
-        # version they were issued with).
+        # The device page tables come LAST (non-donated: tables are tiny
+        # and in-flight rounds must keep reading the version they were
+        # issued with).
         donate = tuple(range(1, 1 + nc)) + ((12 + nc,) if spec else ())
 
         @partial(jax.jit, donate_argnums=donate)
@@ -2532,61 +2443,47 @@ class ContinuousBatchingScheduler:
              seeds, cinits, cbudgets) = args[nc:nc + 10]
             g_need = args[nc + 10]
             hist = args[nc + 11] if spec else None
-            if paged:
-                ptab = args[-1]
-                # Per-row page tables: OOB padding slots get an all-sentinel
-                # row (mode="fill"), so BOTH their gather garbage is
-                # causally masked and their scatter-back below drops — a
-                # clamped gather would alias a real slot's pages and the
-                # scatter would corrupt them.
-                tab = jnp.take(
-                    ptab, slots, axis=0, mode="fill", fill_value=num_pages
-                )  # [k, NP]
-                safe = jnp.clip(tab, 0, num_pages - 1)
+            ptab = args[-1]
+            # Per-row page tables: OOB padding slots get an all-sentinel
+            # row (mode="fill"), so BOTH their gather garbage is causally
+            # masked and their scatter-back below drops — a clamped
+            # gather would alias a real slot's pages and the scatter
+            # would corrupt them.
+            tab = jnp.take(
+                ptab, slots, axis=0, mode="fill", fill_value=num_pages
+            )  # [k, NP]
+            safe = jnp.clip(tab, 0, num_pages - 1)
 
-                def rowview(pool):
-                    # [L, P, K, ps(, H)] -> contiguous per-row view
-                    # [L, k, K, NP*ps(, H)] for the chunk forward (the same
-                    # row gather the contiguous path pays via c[:, slots];
-                    # the scale arrays of an int8 pool drop the H axis).
-                    # A lane-packed pool [L, P, K/f, ps, f*H] gives packed
-                    # row views [L, k, K/f, NP*ps, f*H]: forward reads f
-                    # off them, writes the chunk packed, and the window
-                    # scatter below moves rows as they lie.
-                    g = pool[:, safe]  # [L, k, NP, K, ps(, H)]
-                    perm = ((0, 1, 3, 2, 4, 5) if pool.ndim == 5
-                            else (0, 1, 3, 2, 4))
-                    shape = (pool.shape[0], safe.shape[0], pool.shape[2],
-                             np_tab * ps) + (
-                        (pool.shape[4],) if pool.ndim == 5 else ())
-                    return g.transpose(perm).reshape(shape)
+            def rowview(pool):
+                # [L, P, K, ps(, H)] -> per-row view [L, k, K, NP*ps(, H)]
+                # in forward's {"k", "v"} form for the chunk forward (the
+                # scale arrays of an int8 pool drop the H axis). A
+                # lane-packed pool [L, P, K/f, ps, f*H] gives packed row
+                # views [L, k, K/f, NP*ps, f*H]: forward reads f off
+                # them, writes the chunk packed, and the window scatter
+                # below moves rows as they lie.
+                g = pool[:, safe]  # [L, k, NP, K, ps(, H)]
+                perm = ((0, 1, 3, 2, 4, 5) if pool.ndim == 5
+                        else (0, 1, 3, 2, 4))
+                shape = (pool.shape[0], safe.shape[0], pool.shape[2],
+                         np_tab * ps) + (
+                    (pool.shape[4],) if pool.ndim == 5 else ())
+                return g.transpose(perm).reshape(shape)
 
-                if quant:
-                    # int8 pool: dequantize the gathered rows for the
-                    # chunk forward (q8 × per-position scale), exactly
-                    # the contiguous int8 prefill's gather-dequant — the
-                    # scatter-back below requantizes ONLY this chunk's
-                    # window, so every entry quantizes exactly once.
-                    row_cache = {
-                        "k": (rowview(cache[0]).astype(dtype)
-                              * rowview(cache[1])[..., None].astype(dtype)),
-                        "v": (rowview(cache[2]).astype(dtype)
-                              * rowview(cache[3])[..., None].astype(dtype)),
-                    }
-                else:
-                    row_cache = {"k": rowview(cache[0]),
-                                 "v": rowview(cache[1])}
+            if quant:
+                # int8 pool: dequantize the gathered rows for the chunk
+                # forward (q8 × per-position scale) — the scatter-back
+                # below requantizes ONLY this chunk's window, so every
+                # entry quantizes exactly once.
+                row_cache = {
+                    "k": (rowview(cache[0]).astype(dtype)
+                          * rowview(cache[1])[..., None].astype(dtype)),
+                    "v": (rowview(cache[2]).astype(dtype)
+                          * rowview(cache[3])[..., None].astype(dtype)),
+                }
             else:
-                rows = [c[:, slots] for c in cache]  # [L, k, K, S(, H)]
-                if quant:
-                    row_cache = {
-                        "k": (rows[0].astype(dtype)
-                              * rows[1][..., None].astype(dtype)),
-                        "v": (rows[2].astype(dtype)
-                              * rows[3][..., None].astype(dtype)),
-                    }
-                else:
-                    row_cache = {"k": rows[0], "v": rows[1]}
+                row_cache = {"k": rowview(cache[0]),
+                             "v": rowview(cache[1])}
             positions = (
                 starts[:, None] + jnp.arange(t_bucket, dtype=jnp.int32)[None, :]
             )
@@ -2594,77 +2491,44 @@ class ContinuousBatchingScheduler:
                 cfg, params, tokens, positions, row_cache,
                 logit_indices=lengths - 1, attn_impl=impl, mesh=mesh,
             )
-            if paged:
-                # Scatter ONLY this chunk's window through the page
-                # tables: the quant path's windowed-scatter template, with
-                # (page, offset) indices instead of (slot, position) —
-                # other pages of the row may be SHARED prefix pages that
-                # must never be written (the host's ensure-writable sweep
-                # guarantees the window's own pages are exclusive).
-                pos_idx = positions  # [k, t] = starts[:, None] + arange(t)
-                row_ar = jnp.arange(pos_idx.shape[0], dtype=jnp.int32)
-                wk = new["k"][:, row_ar[:, None], :, pos_idx]  # [k,t,L,K,H]
-                wv = new["v"][:, row_ar[:, None], :, pos_idx]
-                page_idx = pos_idx // ps
-                pages = jnp.take_along_axis(
-                    tab, jnp.clip(page_idx, 0, np_tab - 1), axis=1
-                )  # [k, t]; sentinel rows/entries drop their writes
-                # Positions past the virtual row (a resumed prompt's final
-                # chunk bucket can overhang it) must DROP, not clip: the
-                # clipped lookup would alias the row's LAST mapped page
-                # and overwrite real KV at matching offsets.
-                pages = jnp.where(page_idx < np_tab, pages,
-                                  jnp.int32(num_pages))
-                offs = pos_idx % ps
-                if quant:
-                    # int8 pool: requantize the chunk's window (values +
-                    # per-position scales) and scatter both through the
-                    # table — windowed, so earlier chunks' entries never
-                    # round-trip int8→bf16→int8 (the same
-                    # exactly-once-quantized contract as the contiguous
-                    # int8 path).
-                    from ..ops.quant import quantize_cache
-
-                    wins = _cache_tuple(quantize_cache(wk, wv))
-                    cache = tuple(
-                        c.at[:, pages, :, offs].set(w)
-                        for c, w in zip(cache, wins)
-                    )
-                else:
-                    cache = (
-                        cache[0].at[:, pages, :, offs].set(wk),
-                        cache[1].at[:, pages, :, offs].set(wv),
-                    )
-            elif quant:
+            # Scatter ONLY this chunk's window through the page tables,
+            # by (page, offset): other pages of the row may be SHARED
+            # prefix pages that must never be written (the host's
+            # ensure-writable sweep guarantees the window's own pages are
+            # exclusive). Advanced indices at non-adjacent dims broadcast
+            # to the FRONT: windows come out [k, t, L, K(, H)] — the
+            # layout the scatter expects.
+            pos_idx = positions  # [k, t] = starts[:, None] + arange(t)
+            row_ar = jnp.arange(pos_idx.shape[0], dtype=jnp.int32)
+            wk = new["k"][:, row_ar[:, None], :, pos_idx]  # [k,t,L,K,H]
+            wv = new["v"][:, row_ar[:, None], :, pos_idx]
+            page_idx = pos_idx // ps
+            pages = jnp.take_along_axis(
+                tab, jnp.clip(page_idx, 0, np_tab - 1), axis=1
+            )  # [k, t]; sentinel rows/entries drop their writes
+            # Positions past the virtual row (a resumed prompt's final
+            # chunk bucket can overhang it) must DROP, not clip: the
+            # clipped lookup would alias the row's LAST mapped page and
+            # overwrite real KV at matching offsets.
+            pages = jnp.where(page_idx < np_tab, pages,
+                              jnp.int32(num_pages))
+            offs = pos_idx % ps
+            if quant:
+                # int8 pool: requantize the chunk's window (values +
+                # per-position scales) and scatter both through the table
+                # — windowed, so earlier chunks' entries never round-trip
+                # int8→bf16→int8.
                 from ..ops.quant import quantize_cache
 
-                # Window gather BY THE SAME positions the forward wrote and
-                # the scatter below targets — not a dynamic_slice, whose
-                # clamped *start* would shift the whole window when a
-                # prefix-cache-misaligned final chunk runs past S
-                # (start + t_bucket > S): gather clamps and scatter drops
-                # PER ELEMENT, so every in-bounds position j still maps
-                # new[start+j] -> cache[start+j] and only the past-the-end
-                # tail (whose writes the old full-row scatter also never
-                # materialized) degenerates.
-                pos_idx = positions  # [k, t] = starts[:, None] + arange(t)
-                row_ar = jnp.arange(pos_idx.shape[0], dtype=jnp.int32)
-                # Advanced indices at non-adjacent dims broadcast to the
-                # FRONT: windows come out [k, t, L, K(, H)] — exactly the
-                # layout the scatter below expects.
-                wk = new["k"][:, row_ar[:, None], :, pos_idx]
-                wv = new["v"][:, row_ar[:, None], :, pos_idx]
-                wins = _cache_tuple(quantize_cache(wk, wv))
+                wins = _quant_window_tuple(quantize_cache(wk, wv))
                 cache = tuple(
-                    # OOB padding slots / past-the-end positions drop their
-                    # writes (jax scatter OOB semantics), as before.
-                    c.at[:, slots[:, None], :, pos_idx].set(w)
+                    c.at[:, pages, :, offs].set(w)
                     for c, w in zip(cache, wins)
                 )
             else:
-                cache = tuple(
-                    c.at[:, slots].set(n)
-                    for c, n in zip(cache, (new["k"], new["v"]))
+                cache = (
+                    cache[0].at[:, pages, :, offs].set(wk),
+                    cache[1].at[:, pages, :, offs].set(wv),
                 )
             keys = jax.vmap(
                 lambda s: jax.random.fold_in(jax.random.key(s), 0)
@@ -2692,17 +2556,6 @@ class ContinuousBatchingScheduler:
         mesh, split_weights = self.mesh, self._split_decode_weights
         pad_id = cfg.pad_id
         nc = len(self._cache)
-        paged = self._paged
-
-        def cache_in(cache, ptab):
-            if paged:
-                return _paged_cache_dict(cache, ptab)
-            return _cache_dict(cache)
-
-        def cache_out(new_cache):
-            if paged:
-                return _paged_cache_tuple(new_cache)
-            return _cache_tuple(new_cache)
 
         @partial(jax.jit,
                  donate_argnums=tuple(range(1, 3 + nc))
@@ -2711,7 +2564,7 @@ class ContinuousBatchingScheduler:
             cache = args[:nc]
             (cur, pos, active, temps, topps, topks, seeds,
              counts, cstates, crem, g_next, g_need) = args[nc:nc + 12]
-            ptab = args[nc + 12] if paged else None
+            ptab = args[nc + 12]
             # Per-layer slices outside the chunk scan: decode-matmul layout
             # conversions run once per round, not per token (split_blocks)
             # — where the device has room for the copies it costs.
@@ -2722,11 +2575,12 @@ class ContinuousBatchingScheduler:
                 cache, cur, pos, cstates, crem = carry
                 logits, new_cache = forward(
                     cfg, params, cur[:, None], pos[:, None],
-                    cache_in(cache, ptab), attn_impl=impl, mesh=mesh,
+                    _paged_cache_dict(cache, ptab), attn_impl=impl,
+                    mesh=mesh,
                     # Parked slots (decoding garbage at the park position)
                     # stream ZERO KV blocks; live slots stream only up to
                     # their own position — without this every decode step
-                    # pays S_max bandwidth per slot (pallas/paged impls).
+                    # pays S_max bandwidth per slot.
                     kv_lens=jnp.where(active, pos + 1, 0),
                 )
                 # Grammar masking: ONE table gather + compare per step, no
@@ -2752,7 +2606,8 @@ class ContinuousBatchingScheduler:
                 cstates = jnp.where(active, g_next[cstates, nxt], cstates)
                 crem = jnp.where(active, crem - 1, crem)
                 pos = jnp.where(active, pos + 1, pos)
-                return (cache_out(new_cache), nxt, pos, cstates, crem), nxt
+                return (_paged_cache_tuple(new_cache), nxt, pos, cstates,
+                        crem), nxt
 
             (cache, cur, pos, cstates, crem), toks = lax.scan(
                 step, (cache, cur, pos, cstates, crem), jnp.arange(chunk)
@@ -2851,7 +2706,6 @@ class ContinuousBatchingScheduler:
         d1 = D + 1
         pad_id = cfg.pad_id
         nc = len(self._cache)
-        paged = self._paged
 
         @partial(jax.jit,
                  donate_argnums=tuple(range(1, nc + 5))
@@ -2860,7 +2714,7 @@ class ContinuousBatchingScheduler:
             cache = args[:nc]
             (hist, hlen, cur, pos, active, temps, topps, topks, seeds,
              counts, cstates, crem, g_next, g_need) = args[nc:nc + 14]
-            ptab = args[nc + 14] if paged else None
+            ptab = args[nc + 14]
             params = split_blocks(params)
             drafts = ngram_draft(hist, hlen, D, ngram)           # [S, D]
             verify = jnp.concatenate([cur[:, None], drafts], 1)  # [S, D+1]
@@ -2868,8 +2722,7 @@ class ContinuousBatchingScheduler:
             vpos = pos[:, None] + jd
             logits, new_cache = forward(
                 cfg, params, verify, vpos,
-                (_paged_cache_dict(cache, ptab) if paged
-                 else _cache_dict(cache)),
+                _paged_cache_dict(cache, ptab),
                 attn_impl="xla", mesh=mesh,
             )
             # Per-position grammar masking: pstates[:, j] is the slot's
@@ -2962,10 +2815,8 @@ class ContinuousBatchingScheduler:
             # reads only the row's own history — so (seed, request)
             # reproduces the same tokens under any batch mix.
             counts = counts + jnp.where(active & ~greedy, 1, 0)
-            out_cache = (_paged_cache_tuple(new_cache) if paged
-                         else _cache_tuple(new_cache))
-            return (*out_cache, hist, hlen, cur, pos, counts,
-                    cstates, crem, emitted, n_emit)
+            return (*_paged_cache_tuple(new_cache), hist, hlen, cur, pos,
+                    counts, cstates, crem, emitted, n_emit)
 
         return spec_decode
 
@@ -3306,8 +3157,7 @@ class ContinuousBatchingScheduler:
         ]
         if self._spec_draft:
             args.append(self._hist)
-        if self._paged:
-            args.append(self._ptab)
+        args.append(self._ptab)
         return args
 
     def _warm_prefill(self, t: int, kb: int) -> None:
@@ -3366,18 +3216,17 @@ class ContinuousBatchingScheduler:
                          jnp.int32),
                 jnp.int32(0),
             )
-        if self._paged:
-            # Table-row scatter at the OOB slot (dropped) and a page-0
-            # self-copy (content no-op): compiles the paged bookkeeping
-            # ops so the first admission doesn't block the loop on them.
-            self._ptab = self._ptab_row_fn(
-                self._ptab, oob,
-                jnp.full((self._pages_per_slot,),
-                         self._page_alloc.num_pages, jnp.int32),
-            )
-            self._cache = self._copy_page_fn(
-                *self._cache, jnp.int32(0), jnp.int32(0)
-            )
+        # Table-row scatter at the OOB slot (dropped) and a page-0
+        # self-copy (content no-op): compiles the page bookkeeping ops so
+        # the first admission doesn't block the loop on them.
+        self._ptab = self._ptab_row_fn(
+            self._ptab, oob,
+            jnp.full((self._pages_per_slot,),
+                     self._page_alloc.num_pages, jnp.int32),
+        )
+        self._cache = self._copy_page_fn(
+            *self._cache, jnp.int32(0), jnp.int32(0)
+        )
 
     def _decode_warm_args(self) -> tuple:
         """A decode call's arguments after params and cache, every slot
@@ -3388,8 +3237,7 @@ class ContinuousBatchingScheduler:
             *hist, self._cur, self._pos,
             jnp.zeros(self.num_slots, jnp.bool_), self._temps, self._topps,
             self._topks, self._seeds, self._counts, self._cstates,
-            self._crem, t["next"], t["need"],
-            *((self._ptab,) if self._paged else ()),
+            self._crem, t["next"], t["need"], self._ptab,
         )
 
     def _warm_decode(self) -> None:
@@ -3454,14 +3302,13 @@ class ContinuousBatchingScheduler:
         if self._thread is None:
             if self._crash is not None:
                 raise self._crash_error()
-            if self._paged:
-                # Re-sync every device table row from the host mirror: a
-                # previous _close released abandoned slots' pages host-side
-                # only, and a stale row would route the freed slots' parked
-                # writes into pages a future occupant owns. No-op cost on
-                # first start (rows are already the unmapped sentinel).
-                for i in range(self.num_slots):
-                    self._sync_ptab_row(i)
+            # Re-sync every device table row from the host mirror: a
+            # previous _close released abandoned slots' pages host-side
+            # only, and a stale row would route the freed slots' parked
+            # writes into pages a future occupant owns. No-op cost on
+            # first start (rows are already the unmapped sentinel).
+            for i in range(self.num_slots):
+                self._sync_ptab_row(i)
             self._stop_evt.clear()
             with self._submit_lock:
                 self._closed = False
@@ -3764,8 +3611,7 @@ class ContinuousBatchingScheduler:
             out: Dict[str, object] = {
                 "virtual_time": round(self._wfq_vt, 3),
                 "ready": len(self._ready),
-                # Contiguous layouts have no page-wait deque at all.
-                "page_wait": len(getattr(self, "_page_wait", ())),
+                "page_wait": len(self._page_wait),
                 "submitted": dict(self._tenant_submitted),
                 "preempted": dict(self._tenant_preempted),
             }
@@ -3972,15 +3818,10 @@ class ContinuousBatchingScheduler:
         drain into data loss."""
         if req.spilled is not None:
             # A migrated/spilled blob can only restore into a COMPATIBLE
-            # pool: paged, same page size (blob pages are [L, n, K, ps
-            # (, H)] slices of the source pool). The pool's handoff
-            # placement treats this ValueError as "target can't take it"
-            # and tries the next sibling.
-            if not self._paged:
-                raise ValueError(
-                    "cannot requeue a KV-page blob onto a contiguous "
-                    "scheduler"
-                )
+            # pool: same page size (blob pages are [L, n, K, ps(, H)]
+            # slices of the source pool). The pool's handoff placement
+            # treats this ValueError as "target can't take it" and tries
+            # the next sibling.
             if req.spilled[0].shape[3] != self._page_size:
                 raise ValueError(
                     f"handoff blob page size {req.spilled[0].shape[3]} "
@@ -4024,15 +3865,14 @@ class ContinuousBatchingScheduler:
             prev_t = self._stok_ewma
             self._stok_ewma = (stok if prev_t is None
                                else 0.2 * stok + 0.8 * prev_t)
-            if self._paged:
-                # Observed generation length: what overcommit admission
-                # reserves instead of the worst-case budget. Completed
-                # requests only (a cancelled fraction says nothing about
-                # how long requests RUN).
-                g = float(len(req.generated))
-                prev_g = self._gen_ewma
-                self._gen_ewma = (g if prev_g is None
-                                  else 0.2 * g + 0.8 * prev_g)
+            # Observed generation length: what overcommit admission
+            # reserves instead of the worst-case budget. Completed
+            # requests only (a cancelled fraction says nothing about how
+            # long requests RUN).
+            g = float(len(req.generated))
+            prev_g = self._gen_ewma
+            self._gen_ewma = (g if prev_g is None
+                              else 0.2 * g + 0.8 * prev_g)
 
     # ------------------------------------- prefix-cache telemetry (ISSUE 14)
 
@@ -4073,13 +3913,12 @@ class ContinuousBatchingScheduler:
             }
 
     def _prefix_note_evict(self, key: Tuple[int, ...],
-                           pages: Optional[Tuple[int, ...]] = None) -> None:
+                           pages: Tuple[int, ...]) -> None:
         """Entry left the cache (capacity cap, allocation pressure,
         watermark sweep, or COW un-publish): count it, drop its registry
         metadata, remember the key on the churn ghost, and release the
         allocator's per-page resident-prefix accounting."""
-        if pages is not None:
-            self._page_alloc.prefix_drop(list(pages))
+        self._page_alloc.prefix_drop(list(pages))
         with self._submit_lock:
             self._prefix_evictions += 1
             self._prefix_meta.pop(key, None)
@@ -4224,15 +4063,14 @@ class ContinuousBatchingScheduler:
         requests the match path came up empty for (`hit_rate` =
         hits/(hits+misses)), total blocks and TOKENS reused (each block
         is a skipped pblock-token prefill), entries evicted, and the
-        current LRU size (paged mode: entries are zero-copy page
-        references; page_stats carries the sharing counters). The counter
+        current LRU size (entries are zero-copy page references;
+        page_stats carries the sharing counters). The counter
         group is copied under the scheduler lock in ONE acquisition so a
         /metrics scrape or bench's pre/post delta bracketing never
         observes a torn (hits, blocks_reused) pair."""
         return {
             **self._prefix_stats_from(self._prefix_snapshot()),
-            "cached_blocks": (len(self._prefix_pages) if self._paged
-                              else len(self._prefix_cache)),
+            "cached_blocks": len(self._prefix_pages),
         }
 
     @property
@@ -4241,9 +4079,9 @@ class ContinuousBatchingScheduler:
         group plus churn, the live hit-rate EWMA, the priced value of the
         hits (analytic prefill FLOPs/seconds saved —
         utils/perfmodel.prefill_saved), and what the cache currently
-        HOLDS (entries / tokens / device bytes; paged residency comes
-        from the allocator's unique-page accounting, so chained entries
-        are not double-counted). None when the cache is off
+        HOLDS (entries / tokens / device bytes; residency comes from the
+        allocator's unique-page accounting, so chained entries are not
+        double-counted). None when the cache is off
         (prefix_cache_blocks=0 — including speculative schedulers, which
         disable reuse by design). The whole block derives from ONE locked
         snapshot, so no field pairs across a concurrent admission."""
@@ -4251,29 +4089,22 @@ class ContinuousBatchingScheduler:
             return None
         snap = self._prefix_snapshot()
         st = self._prefix_stats_from(snap)
-        st["cached_blocks"] = (len(self._prefix_pages) if self._paged
-                               else len(self._prefix_cache))
+        st["cached_blocks"] = len(self._prefix_pages)
         reinserts = snap["reinserts"]
         flops = float(snap["flops_saved"])
         secs = float(snap["s_saved"])
         ewma = snap["hit_ewma"]
         entries = int(snap["resident_entries"])
         # Residency counts what the cache HOLDS, deduped: chained entries
-        # overlap on their leading pages, so paged tokens/bytes come from
-        # the allocator's unique-page accounting; a contiguous entry
-        # holds exactly ONE pblock-token block regardless of its chain
-        # key's length (summing per-entry chain lengths would overstate
-        # residency ~2x on deep chains).
-        if self._paged:
-            resident_pages = self._page_alloc.prefix_resident_pages
-            tokens = resident_pages * self._page_size
-            resident_bytes = resident_pages * page_bytes(
-                self.cfg, self._page_size, self._dtype.itemsize,
-                self.kv_quant,
-            )
-        else:
-            tokens = entries * self._pblock
-            resident_bytes = entries * self._prefix_block_bytes
+        # overlap on their leading pages, so tokens/bytes come from the
+        # allocator's unique-page accounting (summing per-entry chain
+        # lengths would overstate residency ~2x on deep chains).
+        resident_pages = self._page_alloc.prefix_resident_pages
+        tokens = resident_pages * self._page_size
+        resident_bytes = resident_pages * page_bytes(
+            self.cfg, self._page_size, self._dtype.itemsize,
+            self.kv_quant,
+        )
         return {
             "replica": self.flight.replica,
             **st,
@@ -4304,8 +4135,8 @@ class ContinuousBatchingScheduler:
     def prefix_registry(self, top_k: Optional[int] = None
                         ) -> Dict[str, object]:
         """The /debug/prefixcache payload for this replica: top-K
-        resident entries by token mass (digest, token length, pages/
-        blocks + device bytes held, live share refcount, hit count,
+        resident entries by token mass (digest, token length, pages +
+        device bytes held, live share refcount, hit count,
         insert/last-hit round), the reuse-distance histogram over the
         bounded admission ring, and the eviction-churn counters. Bounded
         by `top_k` (default LSOT_PREFIX_TOPK) so a huge cache never turns
@@ -4322,34 +4153,29 @@ class ContinuousBatchingScheduler:
             rd = dict(self._prefix_rd_hist)
             metas = []
             for key, m in self._prefix_meta.items():
-                pages = self._prefix_pages.get(key) if self._paged else None
+                pages = self._prefix_pages.get(key)
                 shares = (self._page_alloc.refcount(pages[-1])
                           if pages else None)
                 metas.append((m, pages, shares))
         entries: List[Dict[str, object]] = []
         for m, pages, shares in metas:
-            e: Dict[str, object] = {
+            if pages is None:
+                continue  # evicted between its meta pop and page pop
+            entries.append({
                 "digest": m["digest"],
                 "tokens": m["tokens"],
                 "hits": m["hits"],
                 "insert_round": m["insert_round"],
                 "last_hit_round": m["last_hit_round"],
-            }
-            if self._paged:
-                if pages is None:
-                    continue  # evicted between its meta pop and page pop
-                e["pages"] = len(pages)
-                e["bytes"] = len(pages) * page_bytes(
+                "pages": len(pages),
+                "bytes": len(pages) * page_bytes(
                     self.cfg, self._page_size, self._dtype.itemsize,
                     self.kv_quant,
-                )
+                ),
                 # How many owners the chain's DEEPEST page had at the
                 # snapshot (1 = resident but unmapped by any slot).
-                e["shares"] = shares
-            else:
-                e["blocks"] = 1
-                e["bytes"] = self._prefix_block_bytes
-            entries.append(e)
+                "shares": shares,
+            })
         entries.sort(key=lambda e: (int(e["tokens"]), int(e["hits"])),
                      reverse=True)
         return {
@@ -4413,15 +4239,33 @@ class ContinuousBatchingScheduler:
                           fingerprint=str(getattr(compiled, "fingerprint",
                                                   ""))[:16])
 
-    def _admit_paged(self, slot: int, req: _Request) -> bool:
-        """Paged admission: allocate the request's page envelope and map
-        any cached prefix ZERO-COPY (shared pages by refcount; one-page
-        copy-on-write only when the matched prefix ends mid-page).
-        Returns False — with no side effects — when the pool cannot fund
-        the envelope right now (the loop parks the request in _page_wait
-        until retirements free pages; all-or-nothing, so partial holders
-        can never deadlock each other)."""
+    def _admit(self, slot: int, req: _Request) -> bool:
+        """Reserve `slot` for `req`: allocate its page envelope, map any
+        cached prefix ZERO-COPY (shared pages by refcount; one-page
+        copy-on-write only when the matched prefix ends mid-page) and
+        queue the prompt for chunked prefill. Returns False — with no
+        side effects — when the pool cannot fund the envelope right now
+        (the loop parks the request in _page_wait until retirements free
+        pages; all-or-nothing, so partial holders can never deadlock each
+        other)."""
+        if req.cancelled:  # cancelled while queued: never occupy a slot
+            self._observe_terminal(req)
+            req.future.set_result(req.generated)
+            return True
+        if req.past_deadline():
+            # Expired while queued: fail fast with the typed error before
+            # ever occupying a slot — under overload, prefilling work whose
+            # caller already gave up only steals device time from requests
+            # that can still make their deadlines. Terminal bookkeeping
+            # still runs: the trace gets its queue-wait span (the one span
+            # that explains a 504-from-queue) and the flight record lists
+            # the rid as retired.
+            resilience.inc("deadline_expired")
+            self._observe_terminal(req, error="DeadlineExceeded")
+            req.future.set_exception(req.deadline_error())
+            return True
         ps, pb = self._page_size, self._pblock
+        s_virt = self._pages_per_slot * ps
         ids = req.full_ids  # prompt + committed tokens after a preemption
         plen = len(ids)
         n = 0
@@ -4437,10 +4281,11 @@ class ContinuousBatchingScheduler:
             while n < max_blocks and \
                     req.ns + tuple(ids[: (n + 1) * pb]) in self._prefix_pages:
                 n += 1
-            # Same chunk-envelope cap as the contiguous path: a reuse
-            # offset shifts every chunk start, and the final chunk's
-            # bucket must still land inside the virtual row.
-            s_virt = self._pages_per_slot * ps
+            # Cap reuse so the chunk envelope stays inside the virtual
+            # row: a block-aligned (not bucket-aligned) reuse offset
+            # shifts every chunk start, and the final chunk's bucket
+            # (which can exceed the tokens left) must still land inside
+            # it. n=0 restores the un-reused geometry.
             while n and self._chunk_end(n * pb, plen) > s_virt:
                 n -= 1
         reuse = n * pb
@@ -4457,7 +4302,6 @@ class ContinuousBatchingScheduler:
         # table row. Fresh admissions never hit the clamp (submit's bound
         # keeps their envelope inside the row), so exact-envelope
         # accounting is untouched.
-        s_virt = self._pages_per_slot * ps
         need_end = min(s_virt, max(
             self._chunk_end(reuse, plen),
             bucket_len(plen, self.prompt_bucket)
@@ -4490,9 +4334,8 @@ class ContinuousBatchingScheduler:
             return False
         if boundary_src is not None:
             # Copy-on-write at the non-page-aligned boundary: ONE page
-            # copy (vs the contiguous path's whole-prefix gather-copy);
-            # prefill resumes mid-page inside the private copy while the
-            # cache entry keeps the original.
+            # copy; prefill resumes mid-page inside the private copy
+            # while the cache entry keeps the original.
             self._cache = self._copy_page_fn(
                 *self._cache, jnp.int32(fresh[0]), jnp.int32(boundary_src)
             )
@@ -4516,32 +4359,6 @@ class ContinuousBatchingScheduler:
             # the request and the match (counters move inside, under the
             # scheduler lock — ISSUE 14).
             self._prefix_note_admission(req, ids, reuse, n)
-        return True
-
-    def _admit(self, slot: int, req: _Request) -> bool:
-        """Reserve `slot` and queue the prompt for chunked prefill, reusing
-        any cached prefix first (zero-copy page sharing in paged mode,
-        device-to-device block copy in contiguous mode). Returns False —
-        side-effect free — only in paged mode when the page pool cannot
-        hold the request yet."""
-        if req.cancelled:  # cancelled while queued: never occupy a slot
-            self._observe_terminal(req)
-            req.future.set_result(req.generated)
-            return True
-        if req.past_deadline():
-            # Expired while queued: fail fast with the typed error before
-            # ever occupying a slot — under overload, prefilling work whose
-            # caller already gave up only steals device time from requests
-            # that can still make their deadlines. Terminal bookkeeping
-            # still runs: the trace gets its queue-wait span (the one span
-            # that explains a 504-from-queue) and the flight record lists
-            # the rid as retired.
-            resilience.inc("deadline_expired")
-            self._observe_terminal(req, error="DeadlineExceeded")
-            req.future.set_exception(req.deadline_error())
-            return True
-        if self._paged and not self._admit_paged(slot, req):
-            return False
         if not req.admitted_at:
             # Resumes keep their ORIGINAL admission stamp: the queue-wait
             # span/histogram measure submit → first slot, not decode time
@@ -4561,46 +4378,11 @@ class ContinuousBatchingScheduler:
         self._cur, self._pos, self._cstates, self._crem = self._park_fn(
             self._cur, self._pos, self._cstates, self._crem, jnp.int32(slot)
         )
-        if self._paged and req.spilled is not None:
+        if req.spilled is not None:
             # Spill resume: restore the host page copies and arm the slot
             # directly — no re-prefill, no first-token sample.
             self._restore_spilled(slot, req)
             return True
-        if self._prefix_cache_blocks and not self._paged:
-            pb = self._pblock
-            # At least one prompt token must go through real prefill: the
-            # final chunk's logit samples the first output token.
-            max_blocks = (len(req.ids) - 1) // pb
-            n = 0
-            while n < max_blocks:
-                # Tenant-namespaced key (req.ns, ISSUE 18): () unlabeled.
-                if req.ns + tuple(req.ids[: (n + 1) * pb]) \
-                        not in self._prefix_cache:
-                    break
-                n += 1
-            # Cap reuse so the chunk envelope stays inside the cache: the
-            # un-reused chunking ends at bucket_len(P) <= max_seq-1, but a
-            # block-aligned (not bucket-aligned) reuse offset R shifts every
-            # chunk start, and the final chunk (whose BUCKET can exceed the
-            # tokens left) can then end past the cache. forward's cache
-            # write is a dynamic_update_slice whose clamped START would
-            # silently shift the whole chunk's KV — so shrink the reuse
-            # until the exact envelope fits (n=0 restores the proven-safe
-            # un-reused geometry).
-            s_cache = self._cache[0].shape[3]
-            while n and self._chunk_end(n * pb, len(req.ids)) > s_cache:
-                n -= 1
-            for j in range(n):
-                key = req.ns + tuple(req.ids[: (j + 1) * pb])
-                blocks = self._prefix_cache[key]
-                self._prefix_cache.move_to_end(key)  # LRU touch
-                self._cache = self._restore_block_fn(
-                    *self._cache, *blocks, jnp.int32(slot),
-                    jnp.int32(j * pb),
-                )
-            if n:
-                req.prefilled = n * pb
-            self._prefix_note_admission(req, req.ids, n * pb, n)
         self._prefill_q.append((slot, req))
         return True
 
@@ -4661,13 +4443,12 @@ class ContinuousBatchingScheduler:
         kb = next(b for b in self._kbuckets if b >= len(group))
         if (t, kb) not in self._prefill_fns:
             self._prefill_fns[(t, kb)] = self._build_prefill(t, kb)
-        if self._paged:
-            # Copy-on-write sweep over each chunk's write window: a page
-            # the publisher shared with the prefix cache last chunk must
-            # not be written in place this chunk (only non-page-aligned
-            # block boundaries ever trigger it).
-            for slot, req in group:
-                self._ensure_writable(slot, req.prefilled, req.prefilled + t)
+        # Copy-on-write sweep over each chunk's write window: a page the
+        # publisher shared with the prefix cache last chunk must not be
+        # written in place this chunk (only non-page-aligned block
+        # boundaries ever trigger it).
+        for slot, req in group:
+            self._ensure_writable(slot, req.prefilled, req.prefilled + t)
 
         tokens, lengths, slots, starts = [], [], [], []
         temps, topps, topks, seeds, chunk_lens = [], [], [], [], []
@@ -4725,8 +4506,7 @@ class ContinuousBatchingScheduler:
         ]
         if self._spec_draft:
             call_args.append(self._hist)
-        if self._paged:
-            call_args.append(self._ptab)
+        call_args.append(self._ptab)
         out = self._prefill_fns[(t, kb)](self.params, *self._cache, *call_args)
         # Roofline ledger: bank this chunk batch's analytic work; the
         # next harvested round attributes the pile over the measured
@@ -4752,10 +4532,7 @@ class ContinuousBatchingScheduler:
             req.prefilled += chunk_lens[i]
             full = req.full_ids
             if self._prefix_cache_blocks:
-                if self._paged:
-                    self._publish_blocks_paged(slot, req, chunk_start)
-                else:
-                    self._publish_blocks(slot, req, chunk_start)
+                self._publish_pages(slot, req, chunk_start)
             if req.prefilled < len(full):
                 self._prefill_q.append((slot, req))
                 continue
@@ -4787,12 +4564,11 @@ class ContinuousBatchingScheduler:
             # accounts for.
             req.ready = True
             req.ready_at = time.perf_counter()
-            if self._paged:
-                # Decode writes [len(ids), page_end): the final chunk's
-                # publish may have shared the page holding the prompt
-                # tail — COW it before the slot goes decode-eligible, so
-                # decode never writes a shared page in place.
-                self._ensure_writable(slot, len(req.ids), req.page_end)
+            # Decode writes [len(ids), page_end): the final chunk's
+            # publish may have shared the page holding the prompt tail —
+            # COW it before the slot goes decode-eligible, so decode
+            # never writes a shared page in place.
+            self._ensure_writable(slot, len(req.ids), req.page_end)
             tok = toks[i : i + 1]
             cinit = (req.constraint.init_state if req.constraint is not None
                      else 0)
@@ -4835,42 +4611,15 @@ class ContinuousBatchingScheduler:
         are harvested in the order they were issued, one count each."""
         return self.heartbeat.rounds + len(self._pending) + 1
 
-    def _publish_blocks(self, slot: int, req: _Request, chunk_start: int) -> None:
-        """Publish the chunk's completed prefix blocks (chunk_start is always
-        block-aligned: reuse stops on block boundaries and every non-final
-        chunk is a bucket = multiple of pblock)."""
-        pb = self._pblock
-        for b0 in range(chunk_start // pb, req.prefilled // pb):
-            key = req.ns + tuple(req.ids[: (b0 + 1) * pb])
-            if key in self._prefix_cache:
-                self._prefix_cache.move_to_end(key)
-                continue
-            if key not in self._prefix_seen:
-                # First sighting: remember the content, copy nothing.
-                self._prefix_seen[key] = None
-                while len(self._prefix_seen) > 4 * self._prefix_cache_blocks:
-                    self._prefix_seen.popitem(last=False)
-                continue
-            entry = self._slice_block_fn(
-                *self._cache, jnp.int32(slot), jnp.int32(b0 * pb)
-            )
-            self._prefix_cache[key] = entry
-            if not self._prefix_block_bytes:
-                # One block's device footprint (constant per scheduler):
-                # the registry's contiguous resident-bytes unit.
-                self._prefix_block_bytes = sum(int(b.nbytes) for b in entry)
-            self._prefix_note_publish(key)
-            while len(self._prefix_cache) > self._prefix_cache_blocks:
-                old_key, _ = self._prefix_cache.popitem(last=False)
-                self._prefix_note_evict(old_key)
-
-    def _publish_blocks_paged(self, slot: int, req: _Request,
-                              chunk_start: int) -> None:
-        """Paged publish: an entry is a REFERENCE to the publisher's pages
-        (refcount++), not a copy — zero data movement, same publish gate
-        and hash-chain content keys as the contiguous path. The publisher
-        itself COWs before its next write into a page it just shared
-        (_ensure_writable), so entry content is immutable from here on."""
+    def _publish_pages(self, slot: int, req: _Request,
+                       chunk_start: int) -> None:
+        """Publish the chunk's completed prefix blocks (chunk_start is
+        always block-aligned: reuse stops on block boundaries and every
+        non-final chunk is a bucket = multiple of pblock). An entry is a
+        REFERENCE to the publisher's pages (refcount++), not a copy —
+        zero data movement. The publisher itself COWs before its next
+        write into a page it just shared (_ensure_writable), so entry
+        content is immutable from here on."""
         pb, ps = self._pblock, self._page_size
         ids = req.full_ids
         for b0 in range(chunk_start // pb, req.prefilled // pb):
@@ -4931,14 +4680,14 @@ class ContinuousBatchingScheduler:
             for i in range(self.num_slots)
         ]
         nc = len(self._cache)
-        extra = (self._ptab,) if self._paged else ()
         if self._spec_draft:
             t = self._ctables
             out = self._decode_fn(
                 self.params, *self._cache, self._hist, self._hlen,
                 self._cur, self._pos, jnp.asarray(active), self._temps,
                 self._topps, self._topks, self._seeds, self._counts,
-                self._cstates, self._crem, t["next"], t["need"], *extra,
+                self._cstates, self._crem, t["next"], t["need"],
+                self._ptab,
             )
             self._cache = out[:nc]
             (self._hist, self._hlen, self._cur, self._pos, self._counts,
@@ -4949,7 +4698,7 @@ class ContinuousBatchingScheduler:
                 self.params, *self._cache, self._cur, self._pos,
                 jnp.asarray(active), self._temps, self._topps, self._topks,
                 self._seeds, self._counts, self._cstates, self._crem,
-                t["next"], t["need"], *extra,
+                t["next"], t["need"], self._ptab,
             )
             self._cache = out[:nc]
             (self._cur, self._pos, self._counts, self._cstates, self._crem,
@@ -5001,7 +4750,7 @@ class ContinuousBatchingScheduler:
                 self._build_mixed_spec(t) if self._spec_draft
                 else self._build_mixed(t)
             )
-        # COW sweep over each chunk's write window (ragged implies paged).
+        # COW sweep over each chunk's write window.
         for slot, req in group:
             self._ensure_writable(slot, req.prefilled, req.prefilled + t)
 
@@ -5103,7 +4852,7 @@ class ContinuousBatchingScheduler:
             req.prefilled += chunk_lens[slot]
             full = req.full_ids
             if self._prefix_cache_blocks:
-                self._publish_blocks_paged(slot, req, chunk_start)
+                self._publish_pages(slot, req, chunk_start)
             if req.prefilled < len(full):
                 self._prefill_q.append((slot, req))
                 continue
@@ -5204,14 +4953,11 @@ class ContinuousBatchingScheduler:
             self._temps, self._topps, self._topks, self._cstates,
             jnp.int32(slot)
         )
-        if self._paged:
-            # In-flight overshoot rounds still write through the page-table
-            # version they were issued with; device program order puts
-            # those writes BEFORE any new occupant's prefill of the freed
-            # pages, so the garbage is overwritten before it can become
-            # visible (the same invariant the contiguous layout relies
-            # on for its per-row overshoot writes).
-            self._free_slot_pages(slot)
+        # In-flight overshoot rounds still write through the page-table
+        # version they were issued with; device program order puts those
+        # writes BEFORE any new occupant's prefill of the freed pages, so
+        # the garbage is overwritten before it can become visible.
+        self._free_slot_pages(slot)
 
     def _append_first(self, slot: int, req: _Request, first: int,
                       epoch: Optional[int] = None) -> int:
@@ -5439,13 +5185,12 @@ class ContinuousBatchingScheduler:
                         f"live ({len(req.generated)} of {req.max_new} "
                         f"tokens generated before the lane wedged)"
                     ))
-        if self._paged:
-            # Overcommit's safety valve: retirements above just freed
-            # pages; extend every live slot's mapping past the committed
-            # frontier + overshoot BEFORE the next round can write
-            # through an unmapped entry. Allocation failure preempts here
-            # (never silently drops KV).
-            self._topup_pages()
+        # Overcommit's safety valve: retirements above just freed pages;
+        # extend every live slot's mapping past the committed frontier +
+        # overshoot BEFORE the next round can write through an unmapped
+        # entry. Allocation failure preempts here (never silently drops
+        # KV).
+        self._topup_pages()
         self.heartbeat.round_done()
         # Flight-recorder round record (the postmortem black box): what
         # this round DID — occupancy at issue, admission/retirement churn
@@ -5542,15 +5287,14 @@ class ContinuousBatchingScheduler:
         if pre is not None:
             rec["prefill_mfu"] = pre["mfu"]
             rec["prefill_hbm_util"] = pre["hbm_util"]
-        if self._paged:
-            # Page-pool occupancy per round: the flight-recorder column a
-            # leaked page shows up in (pages_in_use that never drains
-            # while occupancy does). kv_pressure is the injected withheld
-            # reserve (kv:pressure chaos site) — the column a preemption
-            # storm postmortem reads next to the preempt/resume events.
-            rec["kv_pages"] = self._page_alloc.pages_in_use
-            rec["kv_pages_free"] = self._page_alloc.pages_free
-            rec["kv_pressure"] = self._page_alloc.withheld
+        # Page-pool occupancy per round: the flight-recorder column a
+        # leaked page shows up in (pages_in_use that never drains while
+        # occupancy does). kv_pressure is the injected withheld reserve
+        # (kv:pressure chaos site) — the column a preemption storm
+        # postmortem reads next to the preempt/resume events.
+        rec["kv_pages"] = self._page_alloc.pages_in_use
+        rec["kv_pages_free"] = self._page_alloc.pages_free
+        rec["kv_pressure"] = self._page_alloc.withheld
         if self._mig_pages:
             # Handoff columns (ISSUE 13 satellite): pages imported since
             # the last record and the decode-slot wait they carried —
@@ -5610,15 +5354,14 @@ class ContinuousBatchingScheduler:
         for req in self._constraint_wait:  # waiting on a grammar swap
             req.future.set_exception(exc)
         self._constraint_wait.clear()
-        if self._paged:
-            for req in self._page_wait:  # waiting on pool pages
-                req.future.set_exception(exc)
-            self._page_wait.clear()
+        for req in self._page_wait:  # waiting on pool pages
+            req.future.set_exception(exc)
+        self._page_wait.clear()
         for i, req in enumerate(self._slot_req):
             if req is not None:
                 req.future.set_exception(exc)
                 self._slot_req[i] = None
-                if self._paged and self._slot_pages[i]:
+                if self._slot_pages[i]:
                     # Host-side release only — no device work on a possibly
                     # wedged path. The device table rows go stale; start()
                     # re-syncs them before the loop serves again.
@@ -5645,7 +5388,7 @@ class ContinuousBatchingScheduler:
         return bool(
             self._prefill_q or self._pending or self._constraint_wait
             or self._handoff or self._handoff_pending
-            or (self._paged and self._page_wait)
+            or self._page_wait
             or any(r is not None for r in self._slot_req)
             or not self._queue.empty()
             or self._ready
@@ -5671,18 +5414,16 @@ class ContinuousBatchingScheduler:
         # iterations stamp busy=False every <=50ms (the queue.get
         # timeout below), so an idle loop never looks wedged.
         self.heartbeat.stamp(busy=self._busy_now())
-        if self._paged:
-            # Pressure-relief upkeep, every iteration (cheap int
-            # math when nothing is happening): sample the
-            # kv:pressure chaos site, evict prefix pages down to the
-            # high watermark when free pages dip under the low one,
-            # and fail page-starved waiters whose deadline burned
-            # (they would otherwise wait forever while slots stay
-            # busy).
-            with self._stages.stage("sched.upkeep"):
-                self._sample_pressure()
-                self._watermark_sweep()
-                self._sweep_page_wait()
+        # Pressure-relief upkeep, every iteration (cheap int math when
+        # nothing is happening): sample the kv:pressure chaos site, evict
+        # prefix pages down to the high watermark when free pages dip
+        # under the low one, and fail page-starved waiters whose deadline
+        # burned (they would otherwise wait forever while slots stay
+        # busy).
+        with self._stages.stage("sched.upkeep"):
+            self._sample_pressure()
+            self._watermark_sweep()
+            self._sweep_page_wait()
         # Admit pending requests into every free slot, then issue one
         # prompt chunk and one decode round — all asynchronously — and
         # harvest the oldest round once the pipeline is `_harvest_lag`
@@ -5707,7 +5448,7 @@ class ContinuousBatchingScheduler:
                     req = wait.popleft()
                     self._install_constraint(req.constraint)
                 else:
-                    if self._paged and self._page_wait:
+                    if self._page_wait:
                         # Page-starved requests re-admit ahead of the
                         # queue the moment retirements free pages — FIFO
                         # with QoS off, WFQ order (victims first) with it
@@ -5739,7 +5480,7 @@ class ContinuousBatchingScheduler:
                             continue
                         self._install_constraint(c)
                 if not self._admit(self._free_slots()[0], req):
-                    # Paged: the pool cannot hold this request's envelope
+                    # The pool cannot hold this request's envelope
                     # until live slots retire — park it at the FRONT of
                     # the page-wait line (admission order preserved) and
                     # stop admitting; decode/harvest below keep the pipe
@@ -5790,7 +5531,7 @@ class ContinuousBatchingScheduler:
             self._harvest_firsts()
             if self._prefill_q or self._constraint_wait or any(
                 r is not None for r in self._slot_req
-            ) or (self._paged and self._page_wait) or self._ready:
+            ) or self._page_wait or self._ready:
                 return  # harvests freed work — go admit/issue again
             try:
                 with self._stages.stage("sched.idle"):
@@ -5807,7 +5548,7 @@ class ContinuousBatchingScheduler:
                     if c is not None and not self._grammar_matches(c):
                         self._install_constraint(c)
                     if not self._admit(self._free_slots()[0], req):
-                        # Paged + fully idle: can only mean the pool
+                        # Fully idle: can only mean the pool
                         # itself is smaller than one request envelope
                         # after eviction — park it like the loop does.
                         self._page_wait.appendleft(req)
@@ -6187,9 +5928,9 @@ class SchedulerPool:
 
     @property
     def page_stats(self) -> Optional[Dict[str, int]]:
-        """Summed paged-KV pool stats across replicas (None when no
-        replica is paged) — each replica owns an independent pool, so
-        totals add."""
+        """Summed page-pool stats across replicas (None when no replica
+        reports one: duck-typed stand-ins) — each replica owns an
+        independent pool, so totals add."""
         per = [s.page_stats for s in self.schedulers
                if getattr(s, "page_stats", None)]
         if not per:
@@ -6821,13 +6562,9 @@ class SchedulerPool:
                     break
             if ref is None:
                 return None  # all-remote fleet: nothing to disagree with
-            r_paged = bool(getattr(s, "_paged", False))
-            l_paged = bool(getattr(ref, "_paged", False))
-            if r_paged != l_paged:
-                return (f"paged={r_paged} vs fleet paged={l_paged}")
             r_ps = int(getattr(s, "_page_size", 0) or 0)
             l_ps = int(getattr(ref, "_page_size", 0) or 0)
-            if r_paged and r_ps and l_ps and r_ps != l_ps:
+            if r_ps and l_ps and r_ps != l_ps:
                 return f"page_size={r_ps} vs fleet page_size={l_ps}"
             want = str(getattr(s, "model_id", "") or "")
             have = {str(self._model_id(other) or "")
@@ -8029,7 +7766,7 @@ class SchedulerBackend:
         spec = self.scheduler.speculation_stats
         if spec is not None:
             out["speculation"] = spec
-        # Paged-KV pool occupancy + sharing counters (kv_layout="paged"):
+        # Page-pool occupancy + sharing counters:
         # pages_total/pages_free/pages_shared become Prometheus gauges via
         # the nested-serving-stats renderer (utils/prometheus.py), so a
         # leaked page is a flat-lining pages_free on a dashboard.
@@ -8139,7 +7876,7 @@ class SchedulerBackend:
         prompt_bucket: int = 128,
         stop_ids: Optional[Sequence[int]] = None,
         kv_quant: Optional[str] = None,
-        kv_layout: str = "contiguous",
+        kv_layout: str = "paged",
         kv_page_size: Optional[int] = None,
         kv_pages: Optional[int] = None,
         kv_hbm_budget_bytes: Optional[int] = None,

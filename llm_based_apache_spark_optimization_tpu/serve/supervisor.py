@@ -463,8 +463,8 @@ class SupervisedScheduler:
 
     @property
     def page_stats(self):
-        """Paged-KV pool stats passthrough (None for contiguous inner
-        schedulers) — the /metrics kv_pages gauges survive supervision."""
+        """Page-pool stats passthrough — the /metrics kv_pages gauges
+        survive supervision."""
         return getattr(self._inner, "page_stats", None)
 
     @property

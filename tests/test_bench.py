@@ -202,7 +202,7 @@ def test_obs_overhead_measured_and_under_budget():
 def test_paged_accounting_reconciles_no_silent_cap():
     """ISSUE-7 satellite: the bench's paged-vs-contiguous accounting must
     RECONCILE — pages used by the admitted mix never exceed the pool, the
-    ratio is exactly slots_paged/slots_contiguous, every per-request page
+    ratio is exactly slots_paged/slots_rows, every per-request page
     count re-derives from the same sizing functions the scheduler
     allocates with, and admission stopped exactly when the next request
     would not fit (no silent cap)."""
@@ -227,7 +227,7 @@ def test_paged_accounting_reconciles_no_silent_cap():
         (BENCH_1B, 4, 1664, 128, [1408], 64, 128),
     ):
         acct = bench._paged_accounting(
-            cfg, slots_contiguous=slots, max_seq=max_seq, max_new=max_new,
+            cfg, slots_rows=slots, max_seq=max_seq, max_new=max_new,
             overshoot=16, mix_lens=mix, page_size=ps, prompt_bucket=pb,
         )
         # Budget is the contiguous layout's own footprint; pool derives
@@ -259,7 +259,7 @@ def test_paged_accounting_reconciles_no_silent_cap():
     # error, never counted as admitted concurrency.
     with pytest.raises(ValueError, match="unservable"):
         bench._paged_accounting(
-            BENCH_1B, slots_contiguous=4, max_seq=1664, max_new=128,
+            BENCH_1B, slots_rows=4, max_seq=1664, max_new=128,
             overshoot=16, mix_lens=[1536], page_size=64, prompt_bucket=128,
         )
 
@@ -511,7 +511,7 @@ def test_paged_accounting_int8_strictly_more_slots():
         (TINY, 4, 100, 8, [32, 16], 16, 8),
         (BENCH_1B, 8, 1664, 128, [1024, 256], 64, 128),
     ):
-        kw = dict(slots_contiguous=slots, max_seq=max_seq,
+        kw = dict(slots_rows=slots, max_seq=max_seq,
                   max_new=max_new, overshoot=16, mix_lens=mix,
                   page_size=ps, prompt_bucket=pb)
         a = bench._paged_accounting(cfg, **kw)

@@ -49,7 +49,6 @@ def make_sched(cfg, params, role="mixed", **kw):
     kw.setdefault("decode_chunk", 4)
     kw.setdefault("prompt_bucket", 8)
     kw.setdefault("stop_ids", (-1,))
-    kw.setdefault("kv_layout", "paged")
     kw.setdefault("kv_page_size", 8)
     return ContinuousBatchingScheduler(cfg, params, phase_role=role, **kw)
 
@@ -76,11 +75,13 @@ def test_phase_role_validation(tiny_model_module):
     cfg, params = tiny_model_module
     with pytest.raises(ValueError, match="phase_role"):
         ContinuousBatchingScheduler(cfg, params, phase_role="draft")
-    with pytest.raises(ValueError, match="paged"):
+    # The removed layout is refused by name, whatever the role.
+    with pytest.raises(ValueError, match="contiguous KV layout was removed"):
         ContinuousBatchingScheduler(cfg, params, phase_role="prefill",
                                     kv_layout="contiguous")
-    # mixed composes with either layout (the default path untouched).
-    ContinuousBatchingScheduler(cfg, params, phase_role="mixed")
+    # Every role builds on the one layout, with no layout argument.
+    for role in ("mixed", "prefill", "decode"):
+        ContinuousBatchingScheduler(cfg, params, phase_role=role)
 
 
 # --------------------------------------------- wire format: export/import

@@ -2,9 +2,6 @@
 
 import pytest  # noqa: F401
 
-import json
-from pathlib import Path
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -113,32 +110,85 @@ def test_generate_fn_cache_reuse(tiny_model):
     assert f1 is f2
 
 
-def test_golden_decode_pinned_tokens(tiny_model):
-    """Regression pin: greedy decode from fixed weights/prompt must produce
-    the exact same tokens forever (SURVEY.md §4 golden-decode tests). If an
-    intentional numerics change (new kernel, dtype policy) breaks this,
-    verify the change on real weights and re-pin.
+#: How far below the float64 reference's best logit a greedy token's
+#: logit may lie. The engine's float32 logits at TINY are within ~1e-5 of
+#: the reference's; a near-tie inside this band may break either way from
+#: one JAX build to the next, anything wider is a wrong program.
+GOLDEN_LOGIT_TOL = 1e-3
 
-    Provenance (re-pinned at ISSUE 15, carried failing since the seed):
-    the original pin ([190, 182, ...]) was generated in the seed author's
-    environment and NEVER passed in this container (ROADMAP: "seed tests
-    failing"). Bisect evidence: the seed COMMIT's own code (24a3760, the
-    commit that added the pin) run in this environment reproduces today's
-    output [61, ...] bit for bit — so no in-repo change drifted the
-    numerics; the committed value encoded a foreign jax build's RNG/XLA
-    bit-stream. Current pin is this environment's jax 0.4.37 / CPU / f32
-    output, stable across runs."""
+
+def _reference_last_logits(cfg, params, ids):
+    """Plain float64 numpy Llama forward over the WHOLE sequence — no
+    cache, no buckets, no scan, none of models/llama.py — returning the
+    last position's logits. Only the rope frequency table (a function of
+    the config alone) comes from the package."""
+    from llm_based_apache_spark_optimization_tpu.ops.rope import _inv_freq
+
+    def f64(x):
+        return np.asarray(x, np.float64)
+
+    def rms(x, w):
+        return x / np.sqrt((x * x).mean(-1, keepdims=True)
+                           + cfg.norm_eps) * f64(w)
+
+    nh, kh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    t = len(ids)
+    ang = np.arange(t)[:, None] * f64(
+        _inv_freq(hd, cfg.rope_theta, cfg.rope_scaling))[None, :]
+    cos, sin = np.cos(ang)[:, None, :], np.sin(ang)[:, None, :]
+
+    def rope(x):  # [t, heads, hd], rotate-half convention
+        x1, x2 = x[..., : hd // 2], x[..., hd // 2:]
+        return np.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+    blocks = params["blocks"]
+    x = f64(params["embed"])[np.asarray(ids)]
+    causal = np.tril(np.ones((t, t), bool))
+    for l in range(cfg.num_layers):
+        h = rms(x, blocks["ln_attn"][l])
+        q = rope((h @ f64(blocks["wq"][l])).reshape(t, nh, hd))
+        k = rope((h @ f64(blocks["wk"][l])).reshape(t, kh, hd))
+        v = (h @ f64(blocks["wv"][l])).reshape(t, kh, hd)
+        out = np.empty((t, nh, hd))
+        for head in range(nh):
+            kv = head // (nh // kh)
+            sc = q[:, head] @ k[:, kv].T * hd ** -0.5
+            sc = np.where(causal, sc, -np.inf)
+            pr = np.exp(sc - sc.max(-1, keepdims=True))
+            out[:, head] = pr / pr.sum(-1, keepdims=True) @ v[:, kv]
+        x = x + out.reshape(t, nh * hd) @ f64(blocks["wo"][l])
+        h = rms(x, blocks["ln_mlp"][l])
+        g = h @ f64(blocks["wg"][l])
+        x = x + (g / (1.0 + np.exp(-g)) * (h @ f64(blocks["wu"][l]))) \
+            @ f64(blocks["wd"][l])
+    unembed = params["embed"] if cfg.tie_embeddings else params["lm_head"]
+    return rms(x[-1], params["final_norm"]) @ f64(unembed).T
+
+
+def test_golden_decode_pinned_tokens(tiny_model):
+    """Greedy decode is held to the float64 reference, not to one JAX
+    build's bit-stream (the pinned token list this test used to carry
+    read [190, 182, ...] on one build and [61, ...] on another with the
+    same code — `init_params` draws different weights — and so failed in
+    every driver run since the seed). Teacher-forced along the engine's
+    own tokens: at every step the engine's token is the reference's
+    argmax, or lies within GOLDEN_LOGIT_TOL of it. An intentional
+    numerics change (new kernel, dtype policy) that breaks this needs a
+    reason for the wider gap, written next to the tolerance it raises."""
     cfg, params = tiny_model
+    prompt = [1, 17, 93, 5]
     eng = InferenceEngine(cfg, params, stop_ids=(-1,), prompt_bucket=8)
-    out = eng.generate([[1, 17, 93, 5]], max_new_tokens=8)[0]
-    golden_path = Path(__file__).parent / "golden" / "tiny_greedy.json"
-    if not golden_path.exists():
-        golden_path.parent.mkdir(exist_ok=True)
-        golden_path.write_text(json.dumps(out))
-    golden = json.loads(golden_path.read_text())
-    assert out == golden, (
-        f"greedy decode drifted from pinned golden: {out} != {golden}"
-    )
+    out = eng.generate([prompt], max_new_tokens=8)[0]
+    assert len(out) == 8
+    seq = list(prompt)
+    for step, tok in enumerate(out):
+        ref = _reference_last_logits(cfg, params, seq)
+        gap = float(ref.max() - ref[tok])
+        assert gap <= GOLDEN_LOGIT_TOL, (
+            f"step {step}: token {tok} lies {gap:.3g} below the float64 "
+            f"reference's best ({int(ref.argmax())}) after {seq}"
+        )
+        seq.append(tok)
 
 
 @pytest.mark.slow

@@ -3,8 +3,9 @@ kernel parity, engine-loop parity, and zero-copy prefix sharing through the
 real scheduler.
 
 The acceptance bar is TOKEN-IDENTICAL greedy output paged-vs-contiguous —
-through the engines' one-XLA-program loops and through the continuous-
-batching scheduler on mixed constrained/speculative batches — plus
+through the engines' one-XLA-program loops, and the continuous-batching
+scheduler (which serves the page pool only) against the engine's
+contiguous greedy decode on mixed constrained/speculative batches — plus
 allocator stats that prove prefix hits SHARE pages (refcounts) instead of
 copying them, with copy-on-write firing only at non-page-aligned
 boundaries and never leaking a page.
@@ -295,23 +296,26 @@ def test_engine_paged_rejects_bad_combos(tiny):
 # -------------------------------------------------- scheduler-level parity --
 
 
-def make_pair(cfg, params, **kw):
-    kw.setdefault("num_slots", 2)
-    kw.setdefault("decode_chunk", 4)
-    kw.setdefault("prompt_bucket", 8)
-    kw.setdefault("stop_ids", (-1,))
-    contiguous = ContinuousBatchingScheduler(cfg, params, **kw)
-    paged = ContinuousBatchingScheduler(
-        cfg, params, kv_layout="paged", kv_page_size=16, **kw
-    )
-    return contiguous, paged
+def contiguous_greedy(cfg, params, reqs, stop_ids, **kw):
+    """The parity reference: engine/generate.py's one-program greedy
+    decode on the CONTIGUOUS cache, one request at a time. `reqs` rows
+    are (ids, constraint, max_new)."""
+    eng = InferenceEngine(cfg, params, stop_ids=stop_ids, prompt_bucket=8,
+                          **kw)
+    outs = [eng.generate([ids], max_new_tokens=mn, constraint=c)[0]
+            for ids, c, mn in reqs]
+    # The scheduler strips the stop id; a grammar-closed engine row keeps it.
+    return [o[:-1] if o and o[-1] in stop_ids else o for o in outs]
 
 
 def test_scheduler_paged_greedy_parity(tiny):
     cfg, params = tiny
-    contiguous, paged = make_pair(cfg, params)
-    with contiguous:
-        golden = contiguous.generate(PROMPTS * 2, max_new_tokens=6)
+    golden = contiguous_greedy(
+        cfg, params, [(p, None, 6) for p in PROMPTS * 2], (-1,))
+    paged = ContinuousBatchingScheduler(
+        cfg, params, num_slots=2, decode_chunk=4, prompt_bucket=8,
+        stop_ids=(-1,), kv_page_size=16,
+    )
     with paged:
         out = paged.generate(PROMPTS * 2, max_new_tokens=6)
     assert out == golden
@@ -349,7 +353,7 @@ def test_scheduler_paged_mixed_constrained_speculative_parity(tiny):
                     for ids, c, mn in reqs]
             return [f.result(timeout=300) for f in futs]
 
-    assert run(kv_layout="paged", kv_page_size=16) == run()
+    assert run(kv_page_size=16) == contiguous_greedy(cfg, params, reqs, (2,))
 
 
 def test_scheduler_paged_prefix_sharing_zero_copy(tiny):
@@ -431,15 +435,100 @@ def test_scheduler_paged_page_pressure_waits_and_completes(tiny):
 
 
 def test_scheduler_paged_rejects_bad_combos(tiny):
-    """Bogus layouts still fail loudly; int8 + paged (ISSUE 11) is a
-    supported configuration and must construct."""
+    """The removed layout is refused by name, bogus ones with it; the
+    int8 pool (ISSUE 11) is a supported configuration and must
+    construct."""
     cfg, params = tiny
-    with pytest.raises(ValueError, match="kv_layout"):
-        ContinuousBatchingScheduler(cfg, params, kv_layout="bogus")
+    for layout in ("contiguous", "bogus"):
+        with pytest.raises(ValueError, match="contiguous KV layout was "
+                                             "removed"):
+            ContinuousBatchingScheduler(cfg, params, kv_layout=layout)
     s = ContinuousBatchingScheduler(
-        cfg, params, kv_quant="int8", kv_layout="paged", num_slots=2,
+        cfg, params, kv_quant="int8", num_slots=2,
     )
     assert s.page_stats["kv_quant"] == "int8"
+
+
+def _tiny_backend(tiny, **kw):
+    """SchedulerBackend.from_loader (what the app's --scheduler path
+    builds) over TINY, at a size whose warm-up is two programs."""
+    from llm_based_apache_spark_optimization_tpu.serve.scheduler import (
+        SchedulerBackend,
+    )
+    from llm_based_apache_spark_optimization_tpu.tokenizer import (
+        ByteTokenizer,
+    )
+
+    return SchedulerBackend.from_loader(
+        lambda mesh: tiny, ByteTokenizer(), name="tiny", num_slots=2,
+        prompt_bucket=16, decode_chunk=4, **kw)
+
+
+def _app_parser():
+    from llm_based_apache_spark_optimization_tpu.app.__main__ import (
+        build_parser,
+    )
+
+    return build_parser()
+
+
+def _worker_parser():
+    from llm_based_apache_spark_optimization_tpu.serve.remote import (
+        build_parser,
+    )
+
+    return build_parser()
+
+
+@pytest.mark.parametrize("entry", ["scheduler", "loader", "app", "worker"])
+def test_one_layout_default_and_refusal(tiny, entry, capsys):
+    """Every entry point that still takes a layout defaults to the page
+    pool and refuses any other value by name."""
+    cfg, params = tiny
+    gone = "contiguous KV layout was removed"
+    if entry in ("app", "worker"):
+        parser = _app_parser() if entry == "app" else _worker_parser()
+        assert parser.parse_args([]).kv_layout == "paged"
+        assert parser.parse_args(["--kv-layout", "paged"]).kv_layout == \
+            "paged"
+        for layout in ("contiguous", "sideways"):
+            with pytest.raises(SystemExit):
+                parser.parse_args(["--kv-layout", layout])
+            assert gone in capsys.readouterr().err
+        return
+    build = ((lambda **kw: ContinuousBatchingScheduler(
+                  cfg, params, num_slots=2, **kw))
+             if entry == "scheduler" else
+             (lambda **kw: _tiny_backend(tiny, **kw).scheduler))
+    for layout in ("contiguous", "sideways"):
+        with pytest.raises(ValueError, match=gone):
+            build(kv_layout=layout)
+    sched = build()
+    try:
+        assert sched.page_stats["pages_total"] == \
+            sched.num_slots * sched.page_stats["pages_per_slot"]
+        assert sched.perf.kv_layout == "paged"
+    finally:
+        sched.shutdown()
+
+
+def test_layout_flag_changes_no_program(tiny):
+    """A server built with no layout argument and one built from
+    `--kv-layout paged` lower the same decode program."""
+    def decode_text(argv):
+        args = _app_parser().parse_args(argv)
+        kw = {"kv_layout": args.kv_layout} if "--kv-layout" in argv else {}
+        sched = _tiny_backend(tiny, **kw).scheduler
+        try:
+            return sched._decode_fn.lower(
+                sched.params, *sched._cache, *sched._decode_warm_args()
+            ).as_text()
+        finally:
+            sched.shutdown()
+
+    plain = decode_text(["--scheduler"])
+    assert "func.func" in plain  # a real module came back
+    assert decode_text(["--scheduler", "--kv-layout", "paged"]) == plain
 
 
 # ------------------------------------------------------- observability ----
@@ -885,7 +974,7 @@ def test_resume_envelope_clamped_to_slot_row(tiny):
     )
     req.generated = list(range(3, 26))
     req.resume_pref = len(req.generated)
-    assert s._admit_paged(0, req)
+    assert s._admit(0, req)
     assert len(s._slot_pages[0]) <= s._pages_per_slot
     assert req.page_end <= s._pages_per_slot * 8
     s._free_slot_pages(0)
@@ -1423,8 +1512,9 @@ def test_engine_paged_int8_tracks_bf16_and_matches_contiguous_int8(tiny):
 def test_scheduler_paged_int8_parity_mixed_constrained_speculative(tiny):
     """Acceptance: greedy paged-int8 scheduler output matches paged-bf16
     within the documented tolerance on MIXED constrained/speculative
-    batches — and matches contiguous-int8 exactly (same quantize math
-    through all three programs: prefill, decode, spec-decode)."""
+    batches — and matches the engine's contiguous-int8 greedy decode
+    exactly (same quantize math through all three programs: prefill,
+    decode, spec-decode)."""
     from llm_based_apache_spark_optimization_tpu.constrain import (
         get_constraint,
     )
@@ -1452,9 +1542,9 @@ def test_scheduler_paged_int8_parity_mixed_constrained_speculative(tiny):
                     for ids, c, mn in reqs]
             return [f.result(timeout=300) for f in futs]
 
-    bf16 = run(kv_layout="paged", kv_page_size=16)
-    q8 = run(kv_layout="paged", kv_page_size=16, kv_quant="int8")
-    q8c = run(kv_quant="int8")
+    bf16 = run(kv_page_size=16)
+    q8 = run(kv_page_size=16, kv_quant="int8")
+    q8c = contiguous_greedy(cfg, params, reqs, (2,), kv_quant="int8")
     assert q8 == q8c  # layout-independent quantize math, token-identical
     # Tolerance vs bf16: same-length-or-stop outputs, mostly agreeing
     # tokens (constrained rows stay inside the grammar either way).

@@ -191,7 +191,7 @@ def test_tp_sharded_paged_parity_engine_and_scheduler(tiny_model):
     def sched(mesh_):
         with ContinuousBatchingScheduler(
             cfg, params, num_slots=2, decode_chunk=4, prompt_bucket=8,
-            stop_ids=(-1,), kv_layout="paged", kv_page_size=16, mesh=mesh_,
+            stop_ids=(-1,), kv_page_size=16, mesh=mesh_,
         ) as s:
             return s.generate(prompts, max_new_tokens=6)
 

@@ -289,7 +289,7 @@ def test_preempted_request_trace_has_parked_span(tiny_model_module):
     cfg, params = tiny_model_module
     prompts = [[1, 5, 9], [1, 7], [1, 3, 4, 8, 10], [1, 11, 12, 13]]
     sched = make_sched(
-        cfg, params, num_slots=2, kv_layout="paged", kv_page_size=8,
+        cfg, params, num_slots=2, kv_page_size=8,
         kv_pages=9, kv_overcommit=0.25, max_seq=64, prompt_bucket=8,
     )
     traces = [RequestTrace(f"req-{i}") for i in range(len(prompts))]

@@ -7,8 +7,8 @@ Tier-1 contracts pinned here:
 - RECONCILIATION: per-request `tokens_reused` attribution (flight-record
   `prefix_reuse` rows) sums EXACTLY to the scheduler's locked counter
   group (`reused_tokens` == pblock × `blocks_reused`) across a mixed
-  shared-prefix batch — and, in paged mode with page-aligned blocks, to
-  the allocator's `zero_copy_shares` delta (hits share pages, never copy
+  shared-prefix batch — and, with page-aligned blocks, to the
+  allocator's `zero_copy_shares` delta (hits share pages, never copy
   them).
 - EVICTION CHURN: capacity-cap evictions are counted, and a key that
   comes back through publish while still on the evicted ghost counts as
@@ -45,6 +45,9 @@ def make_sched(cfg, params, **kw):
     kw.setdefault("num_slots", 2)
     kw.setdefault("decode_chunk", 4)
     kw.setdefault("prompt_bucket", 8)  # pblock = 8
+    # Page size == pblock: every cached block is exactly one page, so
+    # blocks, pages and page bytes reconcile one to one.
+    kw.setdefault("kv_page_size", 8)
     kw.setdefault("stop_ids", (-1,))
     return ContinuousBatchingScheduler(cfg, params, **kw)
 
@@ -63,15 +66,14 @@ SHARED = list(range(3, 27))  # 24 tokens = 3 pblock-8 blocks
 
 
 def test_reconciliation_paged(tiny_model_module):
-    """Mixed shared-prefix batch, paged, page size == pblock so every
-    reused block is exactly one page-aligned page: per-request flight
+    """Mixed shared-prefix batch, page size == pblock so every reused
+    block is exactly one page-aligned page: per-request flight
     attribution == locked counters == pblock × blocks_reused, and the
     pure-hit wave's zero_copy_shares delta == reused pages."""
     cfg, params = tiny_model_module
     shared_prompts = [[1] + SHARED + [50 + i] for i in range(4)]
     unrelated = [[2] + list(range(60, 84)) + [99]]  # a genuine miss
-    with make_sched(cfg, params, max_seq=64, kv_layout="paged",
-                    kv_page_size=8) as sched:
+    with make_sched(cfg, params, max_seq=64) as sched:
         # Warm phase: request 1 records the prefix, request 2 publishes.
         _drive_sequential(sched, shared_prompts[:2])
         pre = dict(sched.prefix_stats)
@@ -111,30 +113,6 @@ def test_reconciliation_paged(tiny_model_module):
     assert tel["resident_bytes"] > 0
 
 
-def test_reconciliation_contiguous(tiny_model_module):
-    """Same mixed batch on the contiguous block-copy path: attribution
-    rows sum to the locked counters (there is no allocator to reconcile
-    against — blocks are device copies, which is the layout's point)."""
-    cfg, params = tiny_model_module
-    shared_prompts = [[1] + SHARED + [50 + i] for i in range(4)]
-    unrelated = [[2] + list(range(60, 84)) + [99]]
-    with make_sched(cfg, params, max_seq=64) as sched:
-        _drive_sequential(sched, shared_prompts[:2])
-        pre = dict(sched.prefix_stats)
-        pre_rows = len(_prefix_rows(sched))
-        _drive_sequential(sched, shared_prompts[2:] + unrelated)
-        post = dict(sched.prefix_stats)
-        rows = _prefix_rows(sched)[pre_rows:]
-
-    d_reused = post["reused_tokens"] - pre["reused_tokens"]
-    assert d_reused == 8 * (post["blocks_reused"] - pre["blocks_reused"])
-    assert sum(r["reused"] for r in rows) == d_reused == 48
-    assert post["hits"] - pre["hits"] == 2
-    assert post["misses"] - pre["misses"] == 1
-    total = post["hits"] + post["misses"]
-    assert post["hit_rate"] == round(post["hits"] / total, 4)
-
-
 def test_trace_span_carries_reuse_attribution(tiny_model_module):
     """A traced request's sched.prefill span carries prefix_digest /
     tokens_reused / tokens_prefilled (the per-request half of the
@@ -166,8 +144,8 @@ def test_eviction_churn_and_ghost_reinsertion(tiny_model_module):
     def prompt(base, tail):
         return [1] + list(range(base, base + 24)) + [tail]
 
-    with make_sched(cfg, params, max_seq=64, kv_layout="paged",
-                    kv_page_size=8, prefix_cache_blocks=2) as sched:
+    with make_sched(cfg, params, max_seq=64,
+                    prefix_cache_blocks=2) as sched:
         for base in (100, 200, 300):
             _drive_sequential(sched, [prompt(base, 90), prompt(base, 91)])
         st = sched.prefix_stats
@@ -210,6 +188,15 @@ def test_registry_reuse_distance_and_topk(tiny_model_module):
     assert len(reg["entries"]) == 3   # the 3-block chain
     # Entries are sorted by token mass; digests only, never token ids.
     assert [e["tokens"] for e in reg["entries"]] == [24, 16, 8]
+    # Each entry holds its chain's pages (one per block here), priced at
+    # the pool's page bytes.
+    from llm_based_apache_spark_optimization_tpu.engine.paged_kv import (
+        page_bytes,
+    )
+
+    assert [e["pages"] for e in reg["entries"]] == [3, 2, 1]
+    assert [e["bytes"] for e in reg["entries"]] == [
+        n * page_bytes(cfg, 8, 4, None) for n in (3, 2, 1)]
     assert all(isinstance(e["digest"], str) for e in reg["entries"])
     assert len(reg1["entries"]) == 1
     assert reg1["hits"] == reg["hits"]

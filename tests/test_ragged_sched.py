@@ -48,7 +48,6 @@ def make_sched(cfg, params, **kw):
     kw.setdefault("decode_chunk", 4)
     kw.setdefault("prompt_bucket", 8)
     kw.setdefault("stop_ids", (-1,))
-    kw.setdefault("kv_layout", "paged")
     kw.setdefault("kv_page_size", 16)
     return ContinuousBatchingScheduler(cfg, params, **kw)
 
@@ -58,10 +57,14 @@ def make_sched(cfg, params, **kw):
 
 def test_ragged_requires_paged_mixed(tiny):
     cfg, params = tiny
-    with pytest.raises(ValueError, match="paged"):
+    # The removed layout is refused by name before ragged is looked at;
+    # with no layout argument ragged rounds build on the page pool.
+    with pytest.raises(ValueError, match="contiguous KV layout was removed"):
         ContinuousBatchingScheduler(
-            cfg, params, num_slots=2, ragged=True
+            cfg, params, num_slots=2, ragged=True, kv_layout="contiguous"
         )
+    assert ContinuousBatchingScheduler(
+        cfg, params, num_slots=2, ragged=True)._ragged
     with pytest.raises(ValueError, match="mixed"):
         make_sched(cfg, params, ragged=True, phase_role="prefill")
 
@@ -71,9 +74,9 @@ def test_ragged_env_knob(tiny, monkeypatch):
     monkeypatch.setenv("LSOT_RAGGED", "1")
     with make_sched(cfg, params) as s:
         assert s._ragged
-    # Contiguous layout: the env knob silently stays off (explicit
+    # A phase-split replica: the env knob silently stays off (explicit
     # ragged=True raises instead — tested above).
-    with ContinuousBatchingScheduler(cfg, params, num_slots=2) as s:
+    with make_sched(cfg, params, phase_role="decode") as s:
         assert not s._ragged
     monkeypatch.delenv("LSOT_RAGGED")
     with make_sched(cfg, params) as s:
@@ -160,7 +163,7 @@ def test_ragged_constrained_spec_parity(tiny):
         p = init_params(cfg, jax.random.key(0), dtype=jnp.float32)
         with ContinuousBatchingScheduler(
             cfg, p, num_slots=3, decode_chunk=4, prompt_bucket=8,
-            stop_ids=(2,), speculative_draft=3, kv_layout="paged",
+            stop_ids=(2,), speculative_draft=3,
             kv_page_size=16, ragged=ragged,
         ) as s:
             futs = [s.submit(ids, max_new_tokens=mn, constraint=c)

@@ -51,7 +51,7 @@ def tiny_paged_parts():
 def _mk(cfg, params, role):
     return ContinuousBatchingScheduler(
         cfg, params, num_slots=2, decode_chunk=4, prompt_bucket=8,
-        stop_ids=(2,), max_seq=96, kv_layout="paged", kv_page_size=8,
+        stop_ids=(2,), max_seq=96, kv_page_size=8,
         phase_role=role,
     )
 

@@ -1024,8 +1024,7 @@ def test_sweep_page_wait_fails_expired_in_deadline_order(
 
     monkeypatch.setenv("LSOT_QOS", "1")
     cfg, params = tiny_model_module
-    sched = make_sched(cfg, params, kv_layout="paged", kv_page_size=8,
-                       kv_pages=16)
+    sched = make_sched(cfg, params, kv_page_size=8, kv_pages=16)
     now = _time.monotonic()
     # Parked in WFQ/service order: the light tenant's waiter expired a
     # full second LATER than the heavy tenant's sitting behind it.
@@ -1076,7 +1075,6 @@ def _paged64(cfg, params, monkeypatch=None, **kw):
     `monkeypatch` — the unpacked reference path."""
     from llm_based_apache_spark_optimization_tpu.engine import paged_kv
 
-    kw.setdefault("kv_layout", "paged")
     kw.setdefault("kv_page_size", 16)
     if monkeypatch is None:
         sched = make_sched(cfg, params, **kw)
