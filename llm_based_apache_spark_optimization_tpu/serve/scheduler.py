@@ -52,8 +52,12 @@ Design (slot-based continuous batching, TPU/XLA-shaped):
   only copy is one page, copy-on-write, where a matched prefix ends
   mid-page. Content keys mean no invalidation is ever needed, and
   positions line up because a shared prefix occupies the same absolute
-  positions [0, n) in every request. `prefix_cache_blocks` caps the LRU
-  (0 disables); entries are evicted first when the pool runs short.
+  positions [0, n) in every request. By default the cache keeps every
+  published block for as long as the pool's spare pages fund it (its
+  bound in blocks is the pool's: pages × page size / block); an integer
+  `prefix_cache_blocks` caps it and 0 disables it. Entries are evicted
+  first when the pool runs short, a chain from its tail: the least
+  recently used leaf goes, so no entry outlives the blocks before it.
 - Tensor parallelism: pass a mesh with dp=1 — request parallelism comes from
   slots (the batch axis stays unsharded because slots are dynamically
   indexed), TP shards heads/MLP exactly as in engine/generate.py.
@@ -661,7 +665,7 @@ class ContinuousBatchingScheduler:
         prompt_bucket: int = 128,
         stop_ids: Optional[Sequence[int]] = None,
         mesh=None,
-        prefix_cache_blocks: int = 64,
+        prefix_cache_blocks: Optional[int] = None,
         kv_quant: Optional[str] = None,
         speculative_draft: int = 0,
         spec_ngram: int = 3,
@@ -938,6 +942,11 @@ class ContinuousBatchingScheduler:
         self._prefix_pages: "OrderedDict[Tuple[int, ...], Tuple[int, ...]]" = (
             OrderedDict()
         )
+        # Cached children of each cached entry (a child is the same key
+        # one block longer). Only an entry with none is evicted, so a
+        # chain goes from its tail and no entry outlives its parent: the
+        # admission walk could never reach it, yet it would hold pages.
+        self._prefix_kids: Dict[Tuple[int, ...], int] = {}
         # Requests admitted to a slot but waiting for pool pages
         # (admission is all-or-nothing so partial holders can't
         # deadlock); FIFO ahead of the main queue.
@@ -1237,7 +1246,19 @@ class ContinuousBatchingScheduler:
         # always land on block boundaries. `_prefix_pages` (above) is the
         # LRU of content-keyed page references.
         self._pblock = self._buckets[0]
-        self._prefix_cache_blocks = max(0, prefix_cache_blocks)
+        # Capacity in blocks. By default it is what the whole pool holds:
+        # every published block stays while spare pages fund it, and
+        # _alloc_pages / _watermark_sweep take entries back before a live
+        # request waits for pages. An explicit int caps it; 0 disables it.
+        self._prefix_cache_blocks = (
+            self._page_alloc.num_pages * self._page_size // self._pblock
+            if prefix_cache_blocks is None else max(0, prefix_cache_blocks))
+        # The key maps beside the cache (`_prefix_seen`, the evicted-key
+        # ghost, the digest memo) keep at most this many keys each, every
+        # key a tuple of at most max_seq token ids after the tenant salt,
+        # so unique prompts leave host memory bounded: at Mistral's pool
+        # (448 pages of 64 tokens, blocks of 16) 7,168 keys a map.
+        self._prefix_key_bound = 4 * self._prefix_cache_blocks
         # Publish gate: a block's pages are shared with the cache only
         # once its content key has been SEEN before (second occurrence
         # onward). A shared system/schema prefix repeats across requests,
@@ -1530,11 +1551,31 @@ class ContinuousBatchingScheduler:
         """All-or-nothing page grab, evicting LRU prefix-cache entries
         under pressure: cached prefixes are a perf win funded by SPARE
         pages, never a reason to make a live request wait."""
-        while not self._page_alloc.can_alloc(n) and self._prefix_pages:
-            key, pages = self._prefix_pages.popitem(last=False)
-            self._prefix_note_evict(key, pages=pages)
-            self._page_alloc.release(list(pages))
+        while not self._page_alloc.can_alloc(n) and \
+                self._prefix_evict_tail():
+            pass
         return self._page_alloc.alloc(n)
+
+    def _prefix_drop(self, key: Tuple[int, ...]) -> None:
+        """Take one entry out of the prefix cache and release its pages."""
+        pages = self._prefix_pages.pop(key)
+        del self._prefix_kids[key]
+        parent = key[: -self._pblock]
+        if parent in self._prefix_kids:
+            self._prefix_kids[parent] -= 1
+        self._prefix_note_evict(key, pages=pages)
+        self._page_alloc.release(list(pages))
+
+    def _prefix_evict_tail(self, keep: Optional[Tuple[int, ...]] = None
+                           ) -> bool:
+        """Evict the least recently used leaf, which is the deepest block
+        of the least recently used chain, passing over `keep` (the parent
+        of a block about to be published). False when nothing can go."""
+        for key in self._prefix_pages:
+            if not self._prefix_kids[key] and key != keep:
+                self._prefix_drop(key)
+                return True
+        return False
 
     def _free_slot_pages(self, slot: int) -> None:
         """Retirement: drop the slot's page references (pages still held
@@ -1549,11 +1590,15 @@ class ContinuousBatchingScheduler:
         """Drop every prefix-cache entry referencing `page` (the
         copy-on-write fallback when the pool has no free page for the
         copy: un-publishing makes the page exclusive again, so the write
-        can proceed in place without ever touching shared content)."""
-        for key in [k for k, v in self._prefix_pages.items() if page in v]:
-            pages = self._prefix_pages.pop(key)
-            self._prefix_note_evict(key, pages=pages)
-            self._page_alloc.release(list(pages))
+        can proceed in place without ever touching shared content).
+        Every entry that extends one of them goes too, deepest first: a
+        block published past a copy-on-write boundary holds its own copy
+        of the page, and must not outlive the blocks before it."""
+        held = [k for k, v in self._prefix_pages.items() if page in v]
+        doomed = [k for k in self._prefix_pages
+                  if any(k[: len(h)] == h for h in held)]
+        for key in sorted(doomed, key=len, reverse=True):
+            self._prefix_drop(key)
 
     def _ensure_writable(self, slot: int, start_tok: int, end_tok: int) -> None:
         """Copy-on-write sweep before writing cache positions
@@ -1647,11 +1692,8 @@ class ContinuousBatchingScheduler:
                 self._page_alloc.pages_available >= self._wm_low_pages:
             return
         evicted = 0
-        while self._prefix_pages and \
-                self._page_alloc.pages_available < self._wm_high_pages:
-            key, pages = self._prefix_pages.popitem(last=False)
-            self._prefix_note_evict(key, pages=pages)
-            self._page_alloc.release(list(pages))
+        while self._page_alloc.pages_available < self._wm_high_pages and \
+                self._prefix_evict_tail():
             evicted += 1
         if evicted:
             self._page_alloc.note_evictions(evicted)
@@ -2102,6 +2144,7 @@ class ContinuousBatchingScheduler:
                 rec["prefill_hbm_util"] = pre["hbm_util"]
         rec["kv_pages"] = self._page_alloc.pages_in_use
         rec["kv_pages_free"] = self._page_alloc.pages_free
+        rec["kv_prefix_pages"] = self._page_alloc.prefix_resident_pages
         rec["kv_pressure"] = self._page_alloc.withheld
         self._host_columns(rec)
         self.flight.record(**rec)
@@ -3884,8 +3927,7 @@ class ContinuousBatchingScheduler:
         if d is None:
             d = prefix_digest(key)
             memo[key] = d
-            bound = 4 * max(self._prefix_topk,
-                            self._prefix_cache_blocks or 1)
+            bound = max(4 * self._prefix_topk, self._prefix_key_bound, 4)
             while len(memo) > bound:
                 memo.popitem(last=False)
         else:
@@ -3924,7 +3966,7 @@ class ContinuousBatchingScheduler:
             self._prefix_meta.pop(key, None)
             self._prefix_evicted_ghost[key] = None
             while len(self._prefix_evicted_ghost) > \
-                    4 * self._prefix_cache_blocks:
+                    self._prefix_key_bound:
                 self._prefix_evicted_ghost.popitem(last=False)
 
     def _prefix_note_admission(self, req: _Request, ids: Sequence[int],
@@ -4071,6 +4113,7 @@ class ContinuousBatchingScheduler:
         return {
             **self._prefix_stats_from(self._prefix_snapshot()),
             "cached_blocks": len(self._prefix_pages),
+            "capacity": self._prefix_cache_blocks,  # the bound in force
         }
 
     @property
@@ -4350,7 +4393,10 @@ class ContinuousBatchingScheduler:
         req.page_end = need_end
         if reuse:
             req.prefilled = reuse
-            for j in range(n):  # LRU touch along the matched chain
+            # LRU touch along the matched chain, tail first: the scan for
+            # a leaf to evict (_prefix_evict_tail) then meets a chain's
+            # tail before its head.
+            for j in reversed(range(n)):
                 key = req.ns + tuple(ids[: (j + 1) * pb])
                 if key in self._prefix_pages:
                     self._prefix_pages.move_to_end(key)
@@ -4622,16 +4668,33 @@ class ContinuousBatchingScheduler:
         content is immutable from here on."""
         pb, ps = self._pblock, self._page_size
         ids = req.full_ids
-        for b0 in range(chunk_start // pb, req.prefilled // pb):
+        first = chunk_start // pb
+        # A block is published only under a cached parent (or as a chain's
+        # head): an entry the admission walk cannot reach would hold its
+        # pages for nothing.
+        parent = req.ns + tuple(ids[: first * pb]) if first else None
+        linked = parent is None or parent in self._prefix_pages
+        for b0 in range(first, req.prefilled // pb):
             key = req.ns + tuple(ids[: (b0 + 1) * pb])
             if key in self._prefix_pages:
                 self._prefix_pages.move_to_end(key)
+                parent, linked = key, True
                 continue
-            if key not in self._prefix_seen:
+            seen = key in self._prefix_seen
+            if seen:
+                self._prefix_seen.move_to_end(key)
+            else:
                 # First sighting: remember the content, share nothing.
                 self._prefix_seen[key] = None
-                while len(self._prefix_seen) > 4 * self._prefix_cache_blocks:
+                while len(self._prefix_seen) > self._prefix_key_bound:
                     self._prefix_seen.popitem(last=False)
+            # Under the cap, a leaf of another chain (or of this chain's
+            # other branches) makes room; the parent never does.
+            while seen and linked and \
+                    len(self._prefix_pages) >= self._prefix_cache_blocks:
+                linked = self._prefix_evict_tail(keep=parent)
+            if not (seen and linked):
+                parent, linked = key, False
                 continue
             covered = (b0 + 1) * pb
             pages = tuple(
@@ -4640,11 +4703,11 @@ class ContinuousBatchingScheduler:
             self._page_alloc.share(list(pages))
             self._page_alloc.prefix_hold(list(pages))
             self._prefix_pages[key] = pages
+            self._prefix_kids[key] = 0
+            if parent is not None:
+                self._prefix_kids[parent] += 1
             self._prefix_note_publish(key)
-            while len(self._prefix_pages) > self._prefix_cache_blocks:
-                old_key, old = self._prefix_pages.popitem(last=False)
-                self._prefix_note_evict(old_key, pages=old)
-                self._page_alloc.release(list(old))
+            parent = key
 
     def _issue_decode(self, span) -> None:
         """Dispatch one decode round asynchronously: state chains on device,
@@ -5294,6 +5357,7 @@ class ContinuousBatchingScheduler:
         # postmortem reads next to the preempt/resume events.
         rec["kv_pages"] = self._page_alloc.pages_in_use
         rec["kv_pages_free"] = self._page_alloc.pages_free
+        rec["kv_prefix_pages"] = self._page_alloc.prefix_resident_pages
         rec["kv_pressure"] = self._page_alloc.withheld
         if self._mig_pages:
             # Handoff columns (ISSUE 13 satellite): pages imported since
@@ -7556,6 +7620,7 @@ class SchedulerPool:
         out: Dict[str, object] = {
             "hits": 0, "misses": 0, "blocks_reused": 0,
             "reused_tokens": 0, "evictions": 0, "cached_blocks": 0,
+            "capacity": 0,
         }
         for s in self.schedulers:
             st = getattr(s, "prefix_stats", None)

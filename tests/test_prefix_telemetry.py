@@ -16,6 +16,12 @@ Tier-1 contracts pinned here:
 - ROUTING FEED: `replica_loads()` exposes each replica's resident digest
   set + hit-rate EWMA, and `SchedulerPool.prefix_affinity(digests)`
   scores the replica that actually holds a request's schema prefix.
+- CAPACITY: by default the cache holds what the page pool can fund, so a
+  schema longer than 64 blocks is kept whole; an explicit cap still caps
+  it and 0 disables it. Ten interleaved schemas are each remembered from
+  their first sighting to their second. Under page pressure the cache
+  gives pages back from the tail of its least recently used chain, and
+  never leaves an entry whose parent block is gone.
 
 All on TINY / CPU f32, greedy, sequential submits (the publish gate is
 order-sensitive: seen on request 1, published on 2, hit from 3 on).
@@ -296,3 +302,131 @@ def test_digest_stability():
     chain = prefix_chain_digests(ids, 16)
     assert chain == [prefix_digest(ids[:16]), prefix_digest(ids[:32])]
     assert prefix_chain_digests(ids[:16], 16) == []  # needs > one block
+
+
+# --------------------------------------------------------------- capacity
+
+
+@pytest.fixture(scope="module")
+def long_model_module():
+    """TINY with room for prompts of more than 64 blocks of 16 tokens."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from llm_based_apache_spark_optimization_tpu.models import TINY, init_params
+
+    cfg = dataclasses.replace(TINY, max_seq_len=1280)
+    return cfg, init_params(cfg, jax.random.key(0), dtype=jnp.float32)
+
+
+def _block_sched(cfg, params, **kw):
+    kw.setdefault("num_slots", 2)
+    kw.setdefault("decode_chunk", 4)
+    kw.setdefault("prompt_bucket", 128)  # pblock = 16, as served
+    kw.setdefault("stop_ids", (-1,))
+    return ContinuousBatchingScheduler(cfg, params, **kw)
+
+
+def _reused(sched, prompt):
+    """Prompt tokens the cache supplied to one request."""
+    before = sched.prefix_stats["reused_tokens"]
+    sched.generate([prompt], max_new_tokens=2)
+    return sched.prefix_stats["reused_tokens"] - before
+
+
+def _orphans(sched):
+    """Cached entries whose parent block is not cached: the admission walk
+    can never reach them, yet they hold pages."""
+    pb = sched._pblock
+    return [k for k in sched._prefix_pages
+            if len(k) > pb and k[:-pb] not in sched._prefix_pages]
+
+
+def _schema(blocks, salt=0):
+    """`blocks` whole blocks of 16 tokens, told apart by `salt` from the
+    second token on."""
+    return [1] + [3 + (salt * 31 + i * 7) % 300 for i in range(blocks * 16 - 1)]
+
+
+@pytest.mark.parametrize("cap, blocks, kept", [
+    (None, 69, 69),  # default: the pool's bound keeps a 69-block schema whole
+    (64, 69, 64),    # the old constant: the chain stops at its 64th block
+    (3, 5, 3),       # an explicit small cap still caps the cache
+    (0, 5, 0),       # 0 disables it
+])
+def test_capacity_bounds_a_schema_chain(long_model_module, cap, blocks, kept):
+    """A schema is seen on its first request, published on its second and
+    reused on its third, as far as the capacity in force allows; the chain
+    kept is always its head, and `/metrics` reports the bound and the pages
+    held."""
+    cfg, params = long_model_module
+    schema = _schema(blocks)
+    with _block_sched(cfg, params, prefix_cache_blocks=cap) as sched:
+        reused = [_reused(sched, schema + [40 + i]) for i in range(3)]
+        st = sched.prefix_stats
+        pool = sched._page_alloc
+        bound = pool.num_pages * pool.page_size // 16 if cap is None else cap
+        assert reused == [0, 0, 16 * kept]
+        assert st["capacity"] == bound
+        assert st["cached_blocks"] == kept and st["evictions"] == 0
+        assert sched.page_stats["prefix_resident_pages"] == -(
+            -16 * kept // pool.page_size)
+        assert not _orphans(sched)
+        if kept:
+            recs = [r for r in sched.flight.snapshot()
+                    if "kv_prefix_pages" in r]
+            assert recs and max(r["kv_prefix_pages"] for r in recs) > 0
+        else:
+            assert sched.prefix_telemetry is None
+        pool.check()
+
+
+def test_ten_interleaved_schemas_each_seen_until_their_second(
+        long_model_module):
+    """Ten 32-block schemas asked in turn: 320 first-sighting keys, more
+    than the 256 the publish gate kept when it was sized from 64 blocks.
+    Each is still remembered when its second request comes, so every third
+    request reuses its whole schema."""
+    cfg, params = long_model_module
+    schemas = [_schema(32, salt) for salt in range(10)]
+    with _block_sched(cfg, params, kv_pages=96) as sched:
+        reused = [[_reused(sched, s + [40 + turn]) for s in schemas]
+                  for turn in range(3)]
+        assert sched.prefix_stats["evictions"] == 0
+        sched._page_alloc.check()
+    assert reused[0] == reused[1] == [0] * 10
+    assert reused[2] == [512] * 10
+
+
+def test_page_pressure_evicts_the_lru_chain_from_its_tail(long_model_module):
+    """An admission the free pages cannot fund takes pages back from the
+    cache before it waits: leaves of the least recently used chain go
+    first, one page each here (page = block), so that chain keeps its
+    head, the newer chain is untouched, and no entry outlives its
+    parent."""
+    cfg, params = long_model_module
+    a = _schema(10, salt=1)   # older chain: 10 blocks, 10 pages
+    c = _schema(5, salt=2)    # newer chain: 5 blocks
+    b = _schema(9, salt=3)    # unrelated, needs more than the free pages
+    with _block_sched(cfg, params, prompt_bucket=64, max_seq=256,
+                      kv_page_size=16, kv_pages=24) as sched:
+        for p in (a, a, c, c):
+            _reused(sched, p + [300])
+        free = sched.page_stats["pages_free"]
+        assert sched.prefix_stats["cached_blocks"] == 15
+        _reused(sched, b + [301])
+        pages = sched.page_stats
+        st = sched.prefix_stats
+        kept_a = [k for k in sched._prefix_pages if k == tuple(a[:len(k)])]
+        kept_c = [k for k in sched._prefix_pages if k == tuple(c[:len(k)])]
+        assert pages["page_waits"] == 0 and pages["preemptions"] == 0
+        assert 0 < st["evictions"] < 10
+        assert pages["pages_free"] - free == st["evictions"]
+        assert len(kept_c) == 5
+        assert sorted(map(len, kept_a)) == [
+            16 * (i + 1) for i in range(10 - st["evictions"])]
+        assert not _orphans(sched)
+        assert _reused(sched, a + [302]) == 16 * len(kept_a)
+        sched._page_alloc.check()

@@ -331,6 +331,7 @@ def test_prefix_cache_disabled(tiny_model_module):
     assert sched.prefix_stats == {
         "hits": 0, "misses": 0, "hit_rate": 0.0, "blocks_reused": 0,
         "reused_tokens": 0, "evictions": 0, "cached_blocks": 0,
+        "capacity": 0,
     }
     assert sched.prefix_telemetry is None
 
